@@ -239,7 +239,9 @@ def main(argv: list[str] | None = None) -> int:
     wk.add_argument("-dir", default="/tmp/seaweedfs_tpu_worker")
     wk.add_argument("-capabilities", default="erasure_coding,vacuum")
     wk.add_argument("-backend", default="",
-                    help="EC codec backend: jax|cpu (default: auto)")
+                    help="EC codec backend: jax|native|cpu (default: "
+                         "the faster of the device and the host "
+                         "codec on this machine)")
 
     wd = sub.add_parser("webdav", help="WebDAV gateway attached to a "
                         "running filer (server/webdav_server.go)")
@@ -899,6 +901,17 @@ def main(argv: list[str] | None = None) -> int:
         handlers = []
         caps = args.capabilities.split(",")
         if "erasure_coding" in caps or "ec" in caps:
+            if args.backend in ("", "jax"):
+                # the one role that owns the accelerator: claim it
+                # before registering, so a missing chip stops the
+                # worker here instead of failing its first job, and
+                # backend init happens outside any job's liveness
+                # window
+                from .storage.erasure_coding import ec_context
+                dev = ec_context.own_device()
+                print(f"worker owns {dev['platform']} {dev['kind']} "
+                      f"x{dev['count']}, compile cache "
+                      f"{ec_context.compile_cache_dir()}")
             handlers.append(EcEncodeHandler(
                 backend=args.backend or None))
         if "erasure_coding" in caps or "ec" in caps or \

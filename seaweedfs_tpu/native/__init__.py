@@ -3,12 +3,14 @@
 The reference's native layer is Go-calling-SIMD-assembly + Rust
 (SURVEY §2.6); ours is C++ compiled at first use (g++ is in the image;
 pybind11 is not, so bindings go through ctypes).  The build artifact is
-cached next to the sources keyed on source mtime.
+cached next to the sources, keyed on a hash of what went into it and of
+the CPU it was built for.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -21,6 +23,29 @@ _lib = None
 _tried = False
 
 
+def _host_cpu_flags() -> str:
+    """This host's CPU feature flags: `-march=native` bakes them into
+    the artefact, so they are part of what identifies it."""
+    try:
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if line.startswith(("flags", "Features")):
+                    return line.split(":", 1)[1].strip()
+    except OSError:
+        pass
+    return ""
+
+
+def _build_key(src_path: str, deps, flags: "list[str]") -> str:
+    h = hashlib.sha256()
+    for path in [src_path, *deps]:
+        with open(path, "rb") as f:
+            h.update(f.read())
+        h.update(b"\0")
+    h.update(" ".join(flags).encode())
+    h.update(b"\0")
+    h.update(_host_cpu_flags().encode())
+    return h.hexdigest()
 
 
 def _build_if_stale(src_path: str, out_path: str,
@@ -28,35 +53,41 @@ def _build_if_stale(src_path: str, out_path: str,
                     shared: bool = True,
                     try_march_native: bool = False,
                     deps: "list[str] | None" = None) -> "str | None":
-    """Shared mtime-keyed g++ build (one implementation for all the
-    native artifacts): makedirs, staleness check (source + any listed
-    header deps), per-pid scratch so concurrent builders never publish
-    half-written output, atomic publish.  None when the toolchain is
-    unavailable."""
+    """Shared g++ build (one implementation for all the native
+    artifacts).  The artefact is keyed on a hash of source + header
+    deps + flags + this host's CPU flags, recorded beside it in
+    `<out>.key`: an artefact that is stale, or was carried over from
+    another machine (a `-march=native` build may not run on this
+    CPU), is rebuilt, never loaded.  Per-pid scratch so concurrent
+    builders never publish half-written output, atomic publish.  None
+    when the toolchain is unavailable."""
     try:
         os.makedirs(os.path.dirname(out_path), exist_ok=True)
-        newest = os.path.getmtime(src_path)
-        for dep in deps or ():
-            try:
-                newest = max(newest, os.path.getmtime(dep))
-            except OSError:
-                pass
-        if os.path.exists(out_path) and \
-                os.path.getmtime(out_path) >= newest:
-            return out_path
-        tmp = f"{out_path}.{os.getpid()}.tmp"
         base = ["g++", "-O2", "-std=c++17"]
         if shared:
             base += ["-shared", "-fPIC", "-pthread"]
+        base += extra_flags or []
         attempts = ([["-march=native"], []] if try_march_native
                     else [[]])
+        key = _build_key(src_path, deps or (),
+                         base + [m for a in attempts for m in a])
+        key_path = out_path + ".key"
+        try:
+            with open(key_path) as f:
+                if f.read() == key and os.path.exists(out_path):
+                    return out_path
+        except OSError:
+            pass
+        tmp = f"{out_path}.{os.getpid()}.tmp"
         for march in attempts:
             try:
                 subprocess.run(
-                    base + march + (extra_flags or []) +
-                    [src_path, "-o", tmp],
+                    base + march + [src_path, "-o", tmp],
                     check=True, capture_output=True, timeout=120)
                 os.replace(tmp, out_path)
+                with open(tmp, "w") as f:
+                    f.write(key)
+                os.replace(tmp, key_path)
                 return out_path
             except (OSError, subprocess.SubprocessError):
                 continue

@@ -76,20 +76,19 @@ def gf_apply_matrix_pallas_words(tables_flat: jax.Array, data32: jax.Array,
     return out.reshape(r, w)
 
 
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def expand_tables(mat: np.ndarray) -> np.ndarray:
     """[R, K] uint8 coding matrix -> flat [R*K*8] uint32 bit tables."""
     return gf256.MUL_BY_POW2[np.asarray(mat, dtype=np.uint8)].astype(
         np.uint32).reshape(-1)
 
 
-def gf_apply_matrix_pallas(mat, data) -> jax.Array:
+def gf_apply_matrix_pallas(mat, data, interpret: bool = False
+                           ) -> jax.Array:
     """Byte-in/byte-out wrapper over the Pallas kernel (for tests and
     small inputs; bulk callers use gf_apply_matrix_pallas_words with
-    host-packed uint32 buffers).
+    host-packed uint32 buffers).  `interpret` is the caller's explicit
+    choice — CPU tests pass True; the wrapper never picks it, so a
+    missing chip fails instead of silently interpreting.
 
     mat: [R, K] uint8; data: [K, B] uint8 numpy -> [R, B] uint8."""
     from . import rs_jax
@@ -105,6 +104,6 @@ def gf_apply_matrix_pallas(mat, data) -> jax.Array:
     data32 = rs_jax.pack_words(flat, multiple=TILE_WORDS * 4)
     out32 = gf_apply_matrix_pallas_words(
         jnp.asarray(expand_tables(mat)), jnp.asarray(data32),
-        interpret=_use_interpret())
+        interpret=interpret)
     out = rs_jax.unpack_words(np.asarray(out32), b)
     return jnp.asarray(out).reshape((r,) + batch_shape)

@@ -28,38 +28,8 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-try:
-    from jax import shard_map  # jax >= 0.8
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
-
-
-def _shard_map(f, mesh, in_specs, out_specs,
-               check_replication: "bool | None" = None):
-    """Version shim over the jax shard_map API skew: the entry point
-    moved (jax.experimental.shard_map -> jax.shard_map, handled by
-    the import above) and the replication-check kwarg was renamed
-    (check_rep in jax <= 0.4.x -> check_vma).  Callers say what they
-    mean once; the shim speaks whichever dialect this jax does."""
-    kw = {"mesh": mesh, "in_specs": in_specs, "out_specs": out_specs}
-    if check_replication is None:
-        return shard_map(f, **kw)
-    try:
-        return shard_map(f, check_vma=check_replication, **kw)
-    except TypeError:  # older jax: the kwarg is check_rep
-        return shard_map(f, check_rep=check_replication, **kw)
-
-
-def _axis_size(axis_name: str) -> int:
-    """jax.lax.axis_size only exists in newer jax; psum over the
-    Python constant 1 constant-folds to a static int on every version
-    this shim spans."""
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return jax.lax.psum(1, axis_name)
 
 from ..ops import rs_matrix
 from ..ops.rs_jax import _packed_xor_network, expand_tables_u32
@@ -72,7 +42,7 @@ def _ring_xor(x: jax.Array, axis_name: str) -> jax.Array:
     s-1 hops, each overlapping neighbor transfers on ICI; bit-exact in any
     order because XOR is associative and commutative.
     """
-    s = _axis_size(axis_name)
+    s = jax.lax.axis_size(axis_name)
     if s == 1:
         return x
     perm = [(j, (j + 1) % s) for j in range(s)]
@@ -93,8 +63,8 @@ def _apply_tables_local(mat_local: jax.Array, data32: jax.Array) -> jax.Array:
 def _encode_shard_map(mesh):
     """Per-mesh encode shard_map (traceable, un-jitted): parity rows
     tensor-parallel over "shard", columns data-parallel over "stripe"."""
-    return _shard_map(
-        _apply_tables_local, mesh,
+    return shard_map(
+        _apply_tables_local, mesh=mesh,
         in_specs=(P(SHARD_AXIS, None), P(None, STRIPE_AXIS)),
         out_specs=P(SHARD_AXIS, STRIPE_AXIS))
 
@@ -102,12 +72,12 @@ def _encode_shard_map(mesh):
 @functools.lru_cache(maxsize=32)
 def _reconstruct_shard_map(mesh):
     """Per-mesh distributed-reconstruction shard_map (ring XOR-reduce)."""
-    return _shard_map(
-        _reconstruct_local, mesh,
+    return shard_map(
+        _reconstruct_local, mesh=mesh,
         in_specs=(P(None, SHARD_AXIS), P(SHARD_AXIS, STRIPE_AXIS)),
         # the ring XOR leaves every shard-axis device with the full sum;
         # replication can't be statically inferred through ppermute
-        out_specs=P(None, STRIPE_AXIS), check_replication=False)
+        out_specs=P(None, STRIPE_AXIS), check_vma=False)
 
 
 @functools.lru_cache(maxsize=32)
@@ -162,8 +132,8 @@ def _apply_tables_batch_local(mat_local: jax.Array, batch32: jax.Array
 
 @functools.lru_cache(maxsize=32)
 def _encode_batch_fn(mesh):
-    return jax.jit(_shard_map(
-        _apply_tables_batch_local, mesh,
+    return jax.jit(shard_map(
+        _apply_tables_batch_local, mesh=mesh,
         in_specs=(P(SHARD_AXIS, None), P(STRIPE_AXIS, None, None)),
         out_specs=P(STRIPE_AXIS, SHARD_AXIS, None)))
 

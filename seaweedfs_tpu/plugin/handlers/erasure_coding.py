@@ -8,9 +8,9 @@ worker/tasks/erasure_coding/ec_task.go:59):
     markVolumeReadonly        (:261)
     copyVolumeFilesToWorker   (:300)  <- bulk .dat/.idx pull
     generateEcShardsLocally   (:426)  <- THE TPU HOT PATH: the worker
-                                         owns the accelerator; encode
-                                         runs on the JAX kernels when a
-                                         TPU is present
+                                         owns the accelerator
+                                         (ec_context.own_device); the
+                                         result names where it ran
     distributeEcShards        (:532)  -> ReceiveFile pushes to targets
     mountEcShards             (shard_distribution.go:209)
     deleteOriginalVolume      (:547)
@@ -19,11 +19,12 @@ worker/tasks/erasure_coding/ec_task.go:59):
 from __future__ import annotations
 
 import os
+import time
 
 from ...operation import master_json
 from ...server.httpd import http_download, http_json, http_upload
 from ...storage.erasure_coding import ECContext
-from ...storage.erasure_coding import ec_decoder, ec_encoder
+from ...storage.erasure_coding import ec_context, ec_decoder, ec_encoder
 from ...storage.erasure_coding.ec_context import to_ext
 from ...topology import iter_volume_list_volumes
 from ..worker import JobHandler
@@ -45,7 +46,7 @@ class EcEncodeHandler(JobHandler):
         self.collection_filter = collection_filter
         self.data_shards = data_shards
         self.parity_shards = parity_shards
-        self.backend = backend  # None -> auto (jax on TPU)
+        self.backend = backend  # None -> ec_context.default_backend()
         # "worker": pull the volume here, encode on this worker's
         # accelerator, distribute (the TPU hot path).  "scatter": drive
         # the SOURCE server's scatter-encode — placement-first, shard
@@ -237,7 +238,7 @@ class EcEncodeHandler(JobHandler):
             self._cleanup_local(base, ctx)
         self._delete_originals(urls, vid)
         return (f"volume {vid}: {ctx} shards encoded on worker "
-                f"({ctx.backend}) and distributed to "
+                f"({_ran_on(ctx)}) and distributed to "
                 f"{sum(1 for s in placement.values() if s)} servers")
 
     def execute_scatter(self, worker, job_id: str,
@@ -277,14 +278,21 @@ class EcEncodeHandler(JobHandler):
         self._pull_volume(worker, vid, collection, source, base)
         worker.report_progress(job_id, 0.3, "copied volume files")
 
-        # 3. encode locally (:426) — TPU kernels when present
+        # 3. encode locally (:426) — on the device this worker owns
         dat_size = os.path.getsize(base + ".dat")
         version = _read_dat_version(base)
         ec_encoder.write_sorted_file_from_idx(base)
-        ec_encoder.write_ec_files(base, ctx)
+        from ... import tracing
+        with tracing.span("ec.encode", role="worker") as sp:
+            ec_encoder.write_ec_files(
+                base, ctx, progress=_encode_progress(worker, job_id))
+            # the result names where it ran, so /maintenance/job and
+            # the job's trace can tell a TPU encode from anything else
+            for key, val in _device_report(ctx).items():
+                sp.set(key, val)
         ec_encoder.save_ec_volume_info(base, ctx, dat_size, version)
         worker.report_progress(
-            job_id, 0.6, f"encoded {ctx.total} shards ({ctx.backend})")
+            job_id, 0.6, f"encoded {ctx.total} shards ({_ran_on(ctx)})")
 
         # consistency check (:638 verifyDatIdxConsistency analog):
         # decode geometry must reproduce the source size
@@ -370,7 +378,7 @@ class EcEncodeHandler(JobHandler):
                         f"volume {vid}: ecx entries exceed dat size")
             worker.report_progress(
                 job_id, 0.6,
-                f"batch-encoded {n} volumes ({ctx.backend})")
+                f"batch-encoded {n} volumes ({_ran_on(ctx)})")
 
             for i, vid in enumerate(vids):
                 self._distribute_and_mount(worker, vid, collection,
@@ -387,7 +395,7 @@ class EcEncodeHandler(JobHandler):
         for vid in vids:
             self._delete_originals(vol_urls[vid], vid)
         return (f"batch of {n} volumes {ctx} encoded over the "
-                f"mesh ({ctx.backend}) and distributed")
+                f"mesh ({_ran_on(ctx)}) and distributed")
 
 
 class EcRebuildHandler(JobHandler):
@@ -508,6 +516,50 @@ class EcRebuildHandler(JobHandler):
                 f"{rebuilder}, rebalanced {moved} (streamed "
                 f"{tele.get('bytesFetchedTotal', 0) >> 20}MB @ "
                 f"{tele.get('volumeGbps', 0)} GB/s volume-rate)")
+
+
+def _ran_on(ctx: ECContext) -> str:
+    """"jax on tpu TPU v5 lite x1" / "native on host" — the platform
+    and device_kind a job message must name."""
+    w = ec_context.where(ctx.backend)
+    if "kind" not in w:
+        return f"{w['backend']} on {w['platform']}"
+    return (f"{w['backend']} on {w['platform']} {w['kind']} "
+            f"x{w['count']}")
+
+
+def _device_report(ctx: ECContext) -> dict:
+    """Span attributes of one encode: where the codec ran and, on the
+    device path, this process's staging and compile ledgers and each
+    device's peak memory (a mesh that left a chip empty shows 0)."""
+    out = {"codec": ec_context.where(ctx.backend)}
+    if ctx.backend == "jax":
+        from ... import profiling
+        from ...ops import staging
+        out["staging"] = staging.snapshot()
+        out["compile"] = ec_context.compile_ledger()
+        out["devicePeakBytes"] = {
+            label: ms.get("peak_bytes_in_use", 0) for label, ms in
+            profiling.sample_device_memory().items()}
+    return out
+
+
+def _encode_progress(worker, job_id: str, every: float = 2.0):
+    """write_ec_files progress -> job progress 0.3..0.6, at most one
+    report per `every` seconds.  The worker cannot poll while it
+    executes, so these reports are its liveness across device init,
+    cold compiles and the encode itself (admin WORKER_DEAD_AFTER)."""
+    last = 0.0
+
+    def report(done: int, total: int) -> None:
+        nonlocal last
+        now = time.monotonic()
+        if now - last >= every or done >= total:
+            last = now
+            worker.report_progress(
+                job_id, 0.3 + 0.3 * done / max(total, 1),
+                f"encoding {done >> 20}/{total >> 20} MiB")
+    return report
 
 
 def _read_dat_version(base: str) -> int:

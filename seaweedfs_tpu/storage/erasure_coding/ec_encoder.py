@@ -49,11 +49,13 @@ def write_sorted_file_from_idx(base_file_name: str, ext: str = ".ecx"
 
 # --- encode -------------------------------------------------------------
 
-def write_ec_files(base_file_name: str, ctx: ECContext | None = None
-                   ) -> None:
-    """ec_encoder.go:61 WriteEcFiles / :67 WriteEcFilesWithContext."""
+def write_ec_files(base_file_name: str, ctx: ECContext | None = None,
+                   progress=None) -> None:
+    """ec_encoder.go:61 WriteEcFiles / :67 WriteEcFilesWithContext.
+    `progress(done_bytes, total_bytes)` is called from the write stage
+    as volume bytes land in the shard files."""
     ctx = ctx or ECContext()
-    _generate_ec_files(base_file_name, ctx)
+    _generate_ec_files(base_file_name, ctx, progress=progress)
 
 
 def _next_pow2(n: int) -> int:
@@ -323,7 +325,7 @@ def _staged_run(work, read_item, compute, write_item) -> None:
 
 def _generate_ec_files(base_file_name: str, ctx: ECContext,
                        sinks: "list | None" = None,
-                       stats=None) -> None:
+                       stats=None, progress=None) -> None:
     """Staged encode: .dat batches -> GF parity -> d+p shard streams.
 
     `sinks` (shard_sink.ShardSink, one per shard id) parameterizes the
@@ -395,7 +397,14 @@ def _generate_ec_files(base_file_name: str, ctx: ECContext,
             return lazy(buf)  # async dispatch; writer materializes
         return np.ascontiguousarray(np.asarray(codec.parity(buf)))
 
+    written = 0  # volume bytes whose d+p shard slices reached the sinks
+
+    def note(done):
+        if progress is not None:
+            progress(min(done, dat_size), dat_size)
+
     def write_item(payload, parity):
+        nonlocal written
         buf, real = payload
         for i in range(d):
             sinks[i].write(buf[i, :real].data)
@@ -412,14 +421,21 @@ def _generate_ec_files(base_file_name: str, ctx: ECContext,
                     continue  # device-shape padding beyond `real`
                 for j in range(ctx.total - d):
                     sinks[d + j].write(chunk[j, :n].data)
-            return
-        if hasattr(parity, "materialize"):
-            # legacy one-shot lazy handle (windowing disabled, or a
-            # single-device batch inside one window): accepts_lazy
-            # means _staged_run no longer materializes for us
-            parity = parity.materialize()
-        for j in range(ctx.total - d):
-            sinks[d + j].write(parity[j, :real].data)
+                # per window, not per launch: a 64MB launch behind a
+                # cold compile is the longest silence a job has, and
+                # the admin presumes a silent worker dead
+                note(written + d * (w0 + n))
+        else:
+            if hasattr(parity, "materialize"):
+                # legacy one-shot lazy handle (windowing disabled, or
+                # a single-device batch inside one window):
+                # accepts_lazy means _staged_run no longer
+                # materializes for us
+                parity = parity.materialize()
+            for j in range(ctx.total - d):
+                sinks[d + j].write(parity[j, :real].data)
+        written += d * real
+        note(written)
 
     write_item.accepts_lazy = True
 

@@ -85,6 +85,10 @@ def test_ec_detection_and_execution_via_worker(harness):
     vid = int(next(iter(blobs)).split(",")[0])
     time.sleep(0.5)  # heartbeat refresh so detection sees the size
 
+    progress = []
+    report = worker.report_progress
+    worker.report_progress = lambda job_id, frac, message="": (
+        progress.append((frac, message)), report(job_id, frac, message))
     r = http_json("POST", f"{admin.url}/maintenance/trigger_detection",
                   {})
     assert worker.worker_id in r["asked"]
@@ -93,6 +97,24 @@ def test_ec_detection_and_execution_via_worker(harness):
     assert ec_jobs, jobs
     assert ec_jobs[0]["status"] == "done", ec_jobs[0]
     assert "distributed" in ec_jobs[0]["message"]
+    # the result names where the codec ran (platform + device_kind),
+    # in the message and in the job's trace
+    assert "(jax on cpu cpu x8)" in ec_jobs[0]["message"]
+    job = http_json("GET", f"{admin.url}/maintenance/job"
+                           f"?id={ec_jobs[0]['jobId']}")
+    spans = http_json(
+        "GET", f"{admin.url}/debug/traces?request_id="
+        f"{job['requestId'] or 'job-' + job['jobId']}")["spans"]
+    enc = next(s for s in spans if s["name"] == "ec.encode")["attrs"]
+    assert enc["codec"] == {"backend": "jax", "platform": "cpu",
+                            "kind": "cpu", "count": 8}
+    assert {"h2d_gbps", "d2h_gbps", "overlap_fraction"} <= \
+        set(enc["staging"])
+    # liveness across the encode: progress is reported from INSIDE it
+    # (the worker cannot poll while it executes, and the admin reaps a
+    # worker that stays silent), between "copied" 0.3 and "encoded" 0.6
+    inside = [f for f, m in progress if m.startswith("encoding ")]
+    assert inside and all(0.3 <= f <= 0.6 for f in inside), progress
 
     time.sleep(0.5)
     # volume is now EC: shards spread, original gone

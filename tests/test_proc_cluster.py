@@ -244,6 +244,32 @@ def test_config_matrix_write_read(tmp_path, profile):
         c.stop()
 
 
+def test_volume_role_mounts_ec_volume_without_importing_jax(cluster):
+    """One process owns the accelerator (ec_context.own_device), and a
+    volume server is not it: encoding, mounting and reading an EC
+    volume in a spawned `volume` role resolves ECContext() to the host
+    engine without importing jax — on a chip machine an import alone
+    would load libtpu and race the worker for the device."""
+    from seaweedfs_tpu.shell import CommandEnv, run_command
+    blob = b"ec in a role that owns no chip " * 4000
+    fid = operation.submit(cluster.master, blob, collection="noxla")
+    vid = int(fid.split(",")[0])
+    env = CommandEnv(cluster.master)
+    run_command(env, "lock")
+    try:
+        out = run_command(
+            env, f"ec.encode -volumeId={vid} -collection=noxla")
+    finally:
+        run_command(env, "unlock")
+    assert f"volume {vid}" in out, out
+    assert operation.read(cluster.master, fid) == blob
+    for name, proc in cluster.procs.items():
+        with open(f"/proc/{proc.popen.pid}/maps") as f:
+            maps = f.read()
+        assert "jaxlib" not in maps and "libtpu" not in maps, \
+            f"{name} loaded jax"
+
+
 def test_no_lock_order_cycles_under_traffic(cluster):
     """The cluster fixture runs every role under the lockgraph race
     detector (devtools/lockgraph.py); after the write/read/kill9

@@ -1,4 +1,6 @@
-"""Pallas kernel bit-identity vs the numpy twin (interpret mode on CPU)."""
+"""Pallas kernel bit-identity vs the numpy twin.  Tier-1 runs on the CPU,
+so every call asks for interpret mode explicitly; the compiled kernel is
+checked on the chip by chip_smoke.py."""
 
 import numpy as np
 import pytest
@@ -13,7 +15,7 @@ def test_pallas_parity_bit_identical(d, p):
     rng = np.random.default_rng(d * 10 + p)
     mat = rs_matrix.parity_matrix(d, p)
     data = rng.integers(0, 256, size=(d, TILE_WORDS * 4), dtype=np.uint8)
-    got = np.asarray(gf_apply_matrix_pallas(mat, data))
+    got = np.asarray(gf_apply_matrix_pallas(mat, data, interpret=True))
     want = gf256.gf_apply_matrix(mat, data)
     assert np.array_equal(got, want)
 
@@ -22,7 +24,7 @@ def test_pallas_unaligned_length_padding():
     rng = np.random.default_rng(3)
     mat = rs_matrix.parity_matrix(4, 2)
     data = rng.integers(0, 256, size=(4, 12345), dtype=np.uint8)
-    got = np.asarray(gf_apply_matrix_pallas(mat, data))
+    got = np.asarray(gf_apply_matrix_pallas(mat, data, interpret=True))
     want = gf256.gf_apply_matrix(mat, data)
     assert got.shape == (2, 12345)
     assert np.array_equal(got, want)
@@ -33,7 +35,7 @@ def test_pallas_decode_matrix_apply():
     rng = np.random.default_rng(4)
     mat = rng.integers(0, 256, size=(3, 5)).astype(np.uint8)
     data = rng.integers(0, 256, size=(5, 4096), dtype=np.uint8)
-    got = np.asarray(gf_apply_matrix_pallas(mat, data))
+    got = np.asarray(gf_apply_matrix_pallas(mat, data, interpret=True))
     want = gf256.gf_apply_matrix(mat, data)
     assert np.array_equal(got, want)
 
