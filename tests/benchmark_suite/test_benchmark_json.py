@@ -1,0 +1,202 @@
+"""BENCHMARK.json is well-formed, every file it names is found by name,
+and a new cell, configuration or per-layer metric is files plus entries."""
+
+import json
+import os
+import re
+import shutil
+
+import pytest
+
+from benchmark import run
+
+ROOT = run.REPO
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+
+
+@pytest.fixture(scope="module")
+def spec():
+    return run.load_spec()
+
+
+def test_top_level_keys(spec):
+    assert set(spec) == {"command", "paths", "run_seconds", "configs",
+                         "workloads", "end_to_end", "per_layer"}
+    assert 1 <= spec["run_seconds"] <= 51
+    assert isinstance(spec["run_seconds"], int)
+    assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) < 64 << 10
+    for word in spec["command"]:
+        assert 1 <= len(word) <= 200 and not word.startswith("/")
+        assert ".." not in word.split("/")
+    for p in spec["paths"]:
+        assert re.fullmatch(r"[A-Za-z0-9_.\-/]{1,200}", p)
+        assert os.path.isdir(os.path.join(ROOT, p))
+
+
+def _line(text):
+    return 1 <= len(text) <= 200 and "\n" not in text and "\t" not in text
+
+
+def test_names_units_and_lines(spec):
+    seen = {"configs": set(), "workloads": set(), "metrics": set()}
+    for c in spec["configs"]:
+        assert set(c) == {"name", "source", "file", "reduced", "why"}
+        assert NAME.match(c["name"]) and c["name"] not in seen["configs"]
+        seen["configs"].add(c["name"])
+        assert _line(c["source"]) and _line(c["why"])
+        assert len(c["reduced"]) <= 16
+        assert all(NAME.match(k) for k in c["reduced"])
+        assert not any(k.endswith(("_dim", "_rank")) for k in c["reduced"])
+    for w in spec["workloads"]:
+        assert set(w) == {"name", "config", "traffic", "chips", "why"}
+        assert NAME.match(w["name"]) and NAME.match(w["traffic"])
+        assert w["name"] not in seen["workloads"]
+        seen["workloads"].add(w["name"])
+        assert w["config"] in seen["configs"]
+        assert w["chips"] in (1, 4) and _line(w["why"])
+    pairs = [(w["config"], w["traffic"]) for w in spec["workloads"]]
+    assert len(pairs) == len(set(pairs))
+    four = sum(w["chips"] == 4 for w in spec["workloads"])
+    assert four <= max(1, len(spec["workloads"]) // 2)
+    for m in spec["end_to_end"] + spec["per_layer"]:
+        assert NAME.match(m["name"]) and m["name"] not in seen["metrics"]
+        seen["metrics"].add(m["name"])
+        assert UNIT.match(m["unit"])
+        assert m["better"] in ("lower", "higher")
+        assert m["source"] in SOURCES
+        for w in m.get("workloads", []):
+            assert w in seen["workloads"]
+    for m in spec["end_to_end"]:
+        assert set(m) - {"workloads"} == {"name", "unit", "better",
+                                          "bound", "source"}
+        assert m["source"] in ("host_clock", "device_trace")
+        assert 0.01 <= m["bound"] <= 0.25
+    for m in spec["per_layer"]:
+        assert set(m) - {"workloads"} == {"name", "unit", "better",
+                                          "source", "layer", "moves"}
+        assert _line(m["layer"])
+    assert "setup_s" in {m["name"] for m in spec["end_to_end"]}
+    assert {c["name"] for c in spec["configs"]} == \
+        {w["config"] for w in spec["workloads"]}
+
+
+def test_every_cell_reports_enough(spec):
+    for w in spec["workloads"]:
+        e2e = {m["name"] for m in run.metrics_of(spec, "end_to_end",
+                                                 w["name"])}
+        assert "setup_s" in e2e and len(e2e) >= 2
+        layer = run.metrics_of(spec, "per_layer", w["name"])
+        assert layer
+        for m in layer:      # a per-layer metric's cells report what it moves
+            assert m["moves"] in e2e, (m["name"], w["name"])
+
+
+def test_per_layer_moves_one_metric_its_cells_report(spec):
+    e2e = {m["name"]: m for m in spec["end_to_end"]}
+    cells = [w["name"] for w in spec["workloads"]]
+    for m in spec["per_layer"]:
+        assert m["moves"] in e2e
+        for w in m.get("workloads", []):
+            assert w in e2e[m["moves"]].get("workloads", cells)
+
+
+def test_every_file_found_by_name(spec):
+    allowed = re.compile(r"^[A-Za-z0-9_.\-/]+$")
+    files = set()
+    for c in spec["configs"]:
+        assert c["file"].startswith(tuple(p + "/" for p in spec["paths"]))
+        assert c["file"] not in files
+        files.add(c["file"])
+        with open(os.path.join(ROOT, c["file"])) as f:
+            cfg = json.load(f)
+        assert cfg["reduced"] == c["reduced"]
+        for key in ("source", "assumed", "guarantees", "chip_map",
+                    "reference", "data_shards", "parity_shards"):
+            assert key in cfg, (c["name"], key)
+    for w in spec["workloads"]:
+        got = run.cell_files(spec, w["name"])
+        assert got["cfg"]["name"] == w["config"]
+        assert "reads" in got["traffic"] and "jobs" in got["traffic"]
+    for m in spec["per_layer"]:
+        assert callable(run.metric_reader(
+            os.path.join(ROOT, "benchmark"), m["name"]))
+    for p in spec["paths"]:
+        for d, _dirs, names in os.walk(os.path.join(ROOT, p)):
+            if "__pycache__" in d:
+                continue
+            for n in names:
+                rel = os.path.relpath(os.path.join(d, n), ROOT)
+                assert allowed.match(rel), rel
+
+
+def test_a_new_cell_config_and_metric_are_files_and_entries(tmp_path, spec):
+    """Add a dummy configuration, traffic mix, cell and per-layer
+    metric to a copy: only new files and new entries, and the harness
+    finds each by its name."""
+    shutil.copytree(os.path.join(ROOT, "benchmark"), tmp_path / "benchmark",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    new = json.loads(json.dumps(spec))
+    cfg = json.load(open(tmp_path / "benchmark/configs/ec6_3_serve.json"))
+    cfg["name"] = "dummy_cfg"
+    (tmp_path / "benchmark/configs/dummy_cfg.json").write_text(
+        json.dumps(cfg))
+    (tmp_path / "benchmark/traffic/dummy_mix.json").write_text(json.dumps(
+        {"what": "reads alone", "jobs": None, "verify": {},
+         "reads": {"processes": 1, "threads_per_process": 4,
+                   "keys": "uniform", "timeout_s": 30.0}}))
+    (tmp_path / "benchmark/metrics/dummy_metric.py").write_text(
+        "def read(ctx):\n    return ctx['reads']['late_max_ms']\n")
+    new["configs"].append({"name": "dummy_cfg", "source": "none",
+                           "file": "benchmark/configs/dummy_cfg.json",
+                           "reduced": ["read_objects"], "why": "test"})
+    new["workloads"].append({"name": "dummy_cfg.dummy_mix",
+                             "config": "dummy_cfg", "traffic": "dummy_mix",
+                             "chips": 1, "why": "test"})
+    held = json.load(open(tmp_path / "benchmark/held_cells.json"))
+    for m in held["end_to_end"]:       # the read metrics come with it
+        new["end_to_end"].append(dict(m, workloads=["dummy_cfg.dummy_mix"]))
+    new["per_layer"].append({
+        "name": "dummy_metric", "unit": "ms", "better": "lower",
+        "source": "host_clock", "layer": "benchmark generator",
+        "moves": "read_p99_ms", "workloads": ["dummy_cfg.dummy_mix"]})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(new))
+
+    got_spec = run.load_spec(str(tmp_path))
+    got = run.cell_files(got_spec, "dummy_cfg.dummy_mix", str(tmp_path))
+    assert got["cfg"]["name"] == "dummy_cfg"
+    assert got["traffic"]["what"] == "reads alone"
+    assert {m["name"] for m in run.metrics_of(
+        got_spec, "end_to_end", "dummy_cfg.dummy_mix")} == {
+        "read_rps", "read_p50_ms", "read_p99_ms", "setup_s"}
+    layer = run.metrics_of(got_spec, "per_layer", "dummy_cfg.dummy_mix")
+    assert [m["name"] for m in layer] == ["dummy_metric"]
+    read = run.metric_reader(got["bench_dir"], "dummy_metric")
+    assert read({"reads": {"late_max_ms": 1.5}}) == 1.5
+
+
+def test_held_cells_are_whole_and_stand_outside_the_benchmark(spec):
+    """What benchmark/held_cells.json keeps is well-formed as it
+    stands, collides with nothing, and is found only when asked for."""
+    with open(os.path.join(ROOT, "benchmark", "held_cells.json")) as f:
+        held = json.load(f)
+    both = run.load_spec(held=True)
+    for key in ("configs", "workloads", "end_to_end", "per_layer"):
+        names = [e["name"] for e in both[key]]
+        assert len(names) == len(set(names))
+        assert len(both[key]) == len(spec[key]) + len(held[key])
+        assert not {e["name"] for e in held[key]} & \
+            {e["name"] for e in spec[key]}
+    assert set(held["why_held"]) == {w["name"] for w in held["workloads"]}
+    test_names_units_and_lines(both)
+    test_every_cell_reports_enough(both)
+    test_per_layer_moves_one_metric_its_cells_report(both)
+    for w in held["workloads"]:
+        got = run.cell_files(both, w["name"])
+        assert got["cfg"]["name"] == w["config"]
+        with pytest.raises(run.BenchFailure):
+            run.cell_files(spec, w["name"])
+    for m in held["per_layer"]:
+        assert callable(run.metric_reader(
+            os.path.join(ROOT, "benchmark"), m["name"]))
