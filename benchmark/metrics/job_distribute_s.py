@@ -1,0 +1,10 @@
+"""Seconds of the `ec.distribute` span (placement, every push, every
+mount), mean over the window's jobs: the program's own span, where
+`job_copy_share` cuts the phase out of the progress messages."""
+
+from benchmark import job_trace
+
+
+def read(ctx):
+    d = job_trace.named(ctx, "ec.distribute")
+    return job_trace.seconds(d) / len(d) if d else None

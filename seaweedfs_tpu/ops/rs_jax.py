@@ -196,7 +196,7 @@ class _PendingParity:
         staging window the pipeline's writer thread actually waits on
         (it includes any remaining kernel time — the only fence this
         backend offers is the host-side fetch), and dispatch->fetch
-        is the per-launch kernel wall `cluster.top` shows as
+        is the per-launch window `cluster.top` shows as
         device_kernel_last_ms."""
         import time as _time
         from .. import profiling
@@ -207,8 +207,7 @@ class _PendingParity:
         profiling.device_note("d2h", host.nbytes, fetch)
         if self._dispatched_at:
             profiling.kernel_note(
-                "gf_apply_matrix", t0 + fetch - self._dispatched_at,
-                host.nbytes)
+                "gf_apply_matrix", t0 + fetch - self._dispatched_at)
         return out
 
 
@@ -243,7 +242,9 @@ class ReedSolomonJax:
         data = self._check(data, self.data_shards)
         return gf_apply_matrix(self._parity_rows, data)
 
-    def parity_lazy(self, data) -> "_PendingParity":
+    def parity_lazy(self, data,
+                    payload_bytes: "int | None" = None
+                    ) -> "_PendingParity":
         """Dispatch the parity launch WITHOUT waiting for the result.
 
         Returns a handle whose .materialize() blocks on the device and
@@ -265,6 +266,10 @@ class ReedSolomonJax:
         its shard sink while later windows are still in flight.
         SEAWEEDFS_TPU_H2D_WINDOW_MB=0 restores the one-shot
         device_put.
+
+        `payload_bytes`: how many of `data`'s bytes the caller counts
+        as its own (the encoder pads a short launch up to a compiled
+        shape); the staging ledger keeps them beside what it sent.
         """
         data = self._check(data, self.data_shards)
         b = data.shape[1]
@@ -273,7 +278,7 @@ class ReedSolomonJax:
             from . import staging
             return staging.WindowedLaunch(
                 self._parity_rows, flat, gf_apply_matrix_words,
-                self.parity_shards, b)
+                self.parity_shards, b, payload_bytes=payload_bytes)
         dev = _staged_h2d(flat)
         t_dispatch = time.perf_counter()
         out32 = gf_apply_matrix_words(self._parity_rows, dev)

@@ -45,7 +45,14 @@ Telemetry: per-window ``device_note``/``kernel_note`` (profiling.py)
 plus a per-launch overlap fraction — 0 when the three planes ran
 serially, 1 when the wall equals the slowest single plane — surfaced
 as the ``device_h2d_overlap_fraction`` gauge (cluster.top) and a
-process-wide aggregate snapshot() the bench JSON records.
+process-wide aggregate snapshot() the benchmark takes deltas of.
+Per window, two trace spans under the span that was current when the
+launch began (tracing.py, one batch when the launch ends):
+``stage.h2d`` (pack + put + fence) and ``stage.d2h`` (the fetch).  The
+ledger splits the h2d seconds into the host pack and the rest, counts
+payload beside padded bytes, and says which side of the hand-off
+waited: the stager on a slot (the consumer is slower) or the consumer
+on a ready window (the stager is slower).
 """
 
 from __future__ import annotations
@@ -172,12 +179,18 @@ class StagingStats:
 
     __slots__ = ("windows", "h2d_bytes", "h2d_seconds", "d2h_bytes",
                  "d2h_seconds", "start", "end", "overlap_fraction",
-                 "overlap_numer", "overlap_denom")
+                 "overlap_numer", "overlap_denom", "payload_bytes",
+                 "pack_seconds", "slot_wait_seconds",
+                 "ready_wait_seconds")
 
     def __init__(self):
         self.windows = 0
-        self.h2d_bytes = 0
-        self.h2d_seconds = 0.0
+        self.h2d_bytes = 0         # what was sent, padding included
+        self.payload_bytes = 0     # the part of it that was asked for
+        self.h2d_seconds = 0.0     # pack + put + fence
+        self.pack_seconds = 0.0    # the host np.copyto alone
+        self.slot_wait_seconds = 0.0    # stager blocked on a slot
+        self.ready_wait_seconds = 0.0   # consumer blocked on a window
         self.d2h_bytes = 0
         self.d2h_seconds = 0.0
         self.start = 0.0
@@ -208,7 +221,9 @@ class StagingStats:
 _agg_lock = threading.Lock()
 _agg = {"launches": 0, "windows": 0, "h2d_bytes": 0,
         "h2d_seconds": 0.0, "d2h_bytes": 0, "d2h_seconds": 0.0,
-        "overlap_numer": 0.0, "overlap_denom": 0.0}
+        "overlap_numer": 0.0, "overlap_denom": 0.0,
+        "payload_bytes": 0, "pack_seconds": 0.0,
+        "slot_wait_seconds": 0.0, "ready_wait_seconds": 0.0}
 
 
 def reset_aggregate() -> None:
@@ -222,13 +237,9 @@ def _note_launch(s: StagingStats) -> None:
     overlap numer/denom come from finish() — one definition)."""
     with _agg_lock:
         _agg["launches"] += 1
-        _agg["windows"] += s.windows
-        _agg["h2d_bytes"] += s.h2d_bytes
-        _agg["h2d_seconds"] += s.h2d_seconds
-        _agg["d2h_bytes"] += s.d2h_bytes
-        _agg["d2h_seconds"] += s.d2h_seconds
-        _agg["overlap_numer"] += s.overlap_numer
-        _agg["overlap_denom"] += s.overlap_denom
+        for key in _agg:
+            if key != "launches":
+                _agg[key] += getattr(s, key)
 
 
 def snapshot() -> dict:
@@ -277,6 +288,9 @@ class _Stager:
         self.errors: "list[BaseException]" = []
         self.stats = StagingStats()
         self.stats.start = time.perf_counter()
+        # [(wall start, seconds, bytes, pack seconds)] per window, for
+        # the launch's stage.h2d spans
+        self.h2d_windows: "list[tuple]" = []
 
     def run(self, plan) -> None:
         import jax
@@ -285,12 +299,17 @@ class _Stager:
         k = self.flat.shape[0]
         try:
             for (w0, n, npad) in plan:
+                t_wait = time.perf_counter()
                 while not self.slots.acquire(timeout=0.2):
                     if self.stop.is_set():
                         raise _StagingError()
+                self.stats.slot_wait_seconds += \
+                    time.perf_counter() - t_wait
                 buf = _take_buf((k, npad))
+                wall0 = time.time()
                 t0 = time.perf_counter()
                 np.copyto(buf[:, :n], self.flat[:, w0:w0 + n])
+                t_pack = time.perf_counter() - t0
                 # pad columns (mesh divisibility) are left dirty on
                 # purpose: the GF apply is column-independent and the
                 # consumer slices them off, so stale pool bytes can
@@ -303,6 +322,8 @@ class _Stager:
                 self.stats.windows += 1
                 self.stats.h2d_bytes += buf.nbytes
                 self.stats.h2d_seconds += dt
+                self.stats.pack_seconds += t_pack
+                self.h2d_windows.append((wall0, dt, buf.nbytes, t_pack))
                 profiling.device_note("h2d", buf.nbytes, dt)
                 t_dispatch = time.perf_counter()
                 out = self.kernel(self.mat, dev)
@@ -331,14 +352,20 @@ class WindowedLaunch:
     """
 
     def __init__(self, mat, flat32: np.ndarray, kernel, out_rows: int,
-                 nbytes: int, op: str = "encode"):
+                 nbytes: int, op: str = "encode",
+                 payload_bytes: "int | None" = None):
         import weakref
+
+        from .. import tracing
         batch_sh, repl_sh, ndev = encode_shardings()
         k, w = flat32.shape
         self._rows = out_rows
         self._nbytes = nbytes
         self._op = op  # telemetry label: "encode" vs "rebuild"
         self._consumed = False
+        # the launch's spans hang under the span current NOW, on the
+        # caller's thread: the stager and the consumer run elsewhere
+        self._trace_ctx = tracing.current_ids()
         if repl_sh is not None:
             # the constant matrix must be REPLICATED across the mesh:
             # a single-device-committed mat + a mesh-sharded window
@@ -346,6 +373,12 @@ class WindowedLaunch:
             import jax
             mat = jax.device_put(np.asarray(mat), repl_sh)
         self._s = _Stager(mat, flat32, kernel, batch_sh)
+        # payload: what the caller says of the batch is volume bytes
+        # (the encoder pads a short launch up to a compiled shape and
+        # knows how much of it it read); without that, the batch less
+        # its word and mesh padding
+        self._s.stats.payload_bytes = k * nbytes \
+            if payload_bytes is None else payload_bytes
         # dropped-handle backstop: stop the stager when the handle is
         # collected (the thread itself only references the _Stager)
         weakref.finalize(self, self._s.stop.set)
@@ -368,12 +401,17 @@ class WindowedLaunch:
             raise RuntimeError("WindowedLaunch consumed twice")
         self._consumed = True
         s = self._s
+        d2h_windows = []
         try:
             while True:
+                t_wait = time.perf_counter()
                 item = s.ready.get()
+                s.stats.ready_wait_seconds += \
+                    time.perf_counter() - t_wait
                 if item is None:
                     break
                 w0, n, out, buf, t_dispatch = item
+                wall0 = time.time()
                 t0 = time.perf_counter()
                 host = np.asarray(out)  # the backend's only fence:
                 # waits out any kernel remainder + the d2h transfer
@@ -382,10 +420,10 @@ class WindowedLaunch:
                 s.slots.release()
                 s.stats.d2h_bytes += host.nbytes
                 s.stats.d2h_seconds += dt
+                d2h_windows.append((wall0, dt, host.nbytes))
                 profiling.device_note("d2h", host.nbytes, dt)
                 profiling.kernel_note("gf_apply_matrix",
-                                      t0 + dt - t_dispatch,
-                                      host.nbytes)
+                                      t0 + dt - t_dispatch)
                 byte0 = 4 * w0
                 real = min(self._nbytes - byte0, 4 * n)
                 yield byte0, host.view(np.uint8).reshape(
@@ -399,6 +437,24 @@ class WindowedLaunch:
             _note_launch(s.stats)
         finally:
             s.stop.set()
+            self._emit_spans(d2h_windows)
+
+    def _emit_spans(self, d2h_windows: list) -> None:
+        """One batch for the whole launch, under the caller's span:
+        a window's start is the wall clock read at the event, so the
+        spans lie on the clock a device trace is tied to."""
+        from .. import tracing
+        ctx = self._trace_ctx
+        if ctx is None:
+            return      # nobody is tracing this launch
+        base = {"trace_id": ctx[0], "parent": ctx[1], "role": ctx[2]}
+        tracing.emit_span_batch(
+            [dict(base, name="stage.h2d", start=t, duration=dt,
+                  attrs={"bytes": nb, "packSeconds": round(pack, 6)})
+             for t, dt, nb, pack in self._s.h2d_windows] +
+            [dict(base, name="stage.d2h", start=t, duration=dt,
+                  attrs={"bytes": nb})
+             for t, dt, nb in d2h_windows])
 
     def materialize(self) -> np.ndarray:
         """Drain every window into one [rows, nbytes] uint8 array."""

@@ -140,11 +140,15 @@ class AdminServer:
         r = self.http.route
         r("GET", "/maintenance/config", self._get_config)
         r("POST", "/maintenance/config", self._set_config)
-        r("GET", "/maintenance/job", self._job_detail)
+        # status and poll chatter is quiet (HttpServer.route): a client
+        # waiting on a job polls these many times a second, and the
+        # ring must still hold the job's trace when it asks for it
+        r("GET", "/maintenance/job", self._job_detail, quiet=True)
         r("POST", "/worker/register", self._register)     # WorkerHello
-        r("POST", "/worker/poll", self._poll)             # admin->worker
+        r("POST", "/worker/poll", self._poll, quiet=True)  # admin->worker
         r("POST", "/worker/detection_result", self._detection_result)
-        r("POST", "/worker/progress", self._progress)     # JobProgressUpdate
+        r("POST", "/worker/progress", self._progress,     # JobProgressUpdate
+          quiet=True)
         r("POST", "/worker/complete", self._complete)     # JobCompleted
         r("GET", "/", self._ui)
         # multi-page admin UI (weed/admin/view/app/ pages)

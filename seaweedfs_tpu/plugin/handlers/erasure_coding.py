@@ -21,6 +21,7 @@ from __future__ import annotations
 import os
 import time
 
+from ... import tracing
 from ...operation import master_json
 from ...server.httpd import http_download, http_json, http_upload
 from ...storage.erasure_coding import ECContext
@@ -131,12 +132,22 @@ class EcEncodeHandler(JobHandler):
             raise RuntimeError(f"volume {vid} has no locations")
         return [l["url"] for l in locations]
 
+    # Each step below is one span under the worker's `job:<type>` span
+    # (tracing.py), named the same in the single, batch and rebuild
+    # handlers: ec.mark_readonly, ec.pull, ec.sort_index, ec.encode,
+    # ec.distribute (ec.push per file, ec.mount per target),
+    # ec.delete_source.  The bulk paths forward the trace parent
+    # (httpd._trace_headers), so the volume servers' own spans of a
+    # pull or a push hang under these.
+
     def _mark_readonly(self, urls: list[str], vid: int) -> None:
         # (:261)
-        for url in urls:
-            _must(http_json("POST", f"{url}/admin/set_readonly",
-                            {"volumeId": vid, "readOnly": True}, timeout=30),
-                  f"set readonly on {url}")
+        with tracing.span("ec.mark_readonly", role="worker"):
+            for url in urls:
+                _must(http_json("POST", f"{url}/admin/set_readonly",
+                                {"volumeId": vid, "readOnly": True},
+                                timeout=30),
+                      f"set readonly on {url}")
 
     def _pull_volume(self, worker, vid: int, collection: str,
                      source: str, base: str) -> None:
@@ -146,13 +157,19 @@ class EcEncodeHandler(JobHandler):
         worker RAM (the reference streams CopyFile the same way,
         ec_task.go:300 / volume_server.proto:69)."""
         os.makedirs(worker.work_dir, exist_ok=True)
-        for ext in (".dat", ".idx"):
-            status, _hdrs = http_download(
-                f"{source}/admin/volume_file?volumeId={vid}"
-                f"&collection={collection}&ext={ext}", base + ext, timeout=600)
-            if status != 200:
-                raise RuntimeError(
-                    f"copy {ext} from {source}: {status}")
+        with tracing.span("ec.pull", role="worker") as sp:
+            sp.set("source", source)
+            pulled = 0
+            for ext in (".dat", ".idx"):
+                status, _hdrs = http_download(
+                    f"{source}/admin/volume_file?volumeId={vid}"
+                    f"&collection={collection}&ext={ext}", base + ext,
+                    timeout=600)
+                if status != 200:
+                    raise RuntimeError(
+                        f"copy {ext} from {source}: {status}")
+                pulled += os.path.getsize(base + ext)
+            sp.set("bytes", pulled)
 
     def _unwind_volumes(self, worker, collection: str, ctx: ECContext,
                         vol_urls: "dict[int, list[str]]") -> None:
@@ -193,10 +210,11 @@ class EcEncodeHandler(JobHandler):
 
     def _delete_originals(self, urls: list[str], vid: int) -> None:
         # (:547) — only after every shard is safely mounted
-        for url in urls:
-            _must(http_json("POST", f"{url}/admin/delete_volume",
-                            {"volumeId": vid}, timeout=30),
-                  f"delete original on {url}")
+        with tracing.span("ec.delete_source", role="worker"):
+            for url in urls:
+                _must(http_json("POST", f"{url}/admin/delete_volume",
+                                {"volumeId": vid}, timeout=30),
+                      f"delete original on {url}")
 
     def execute(self, worker, job_id: str, params: dict) -> str:
         if params.get("encodeMode", self.encode_mode) == "scatter":
@@ -281,8 +299,7 @@ class EcEncodeHandler(JobHandler):
         # 3. encode locally (:426) — on the device this worker owns
         dat_size = os.path.getsize(base + ".dat")
         version = _read_dat_version(base)
-        ec_encoder.write_sorted_file_from_idx(base)
-        from ... import tracing
+        _sort_index(base)
         with tracing.span("ec.encode", role="worker") as sp:
             ec_encoder.write_ec_files(
                 base, ctx, progress=_encode_progress(worker, job_id))
@@ -309,28 +326,29 @@ class EcEncodeHandler(JobHandler):
                               ctx: ECContext, base: str) -> dict:
         """Round-robin shard spread over alive servers (:532) + mount
         (shard_distribution.go:209)."""
-        targets = master_json(worker.master, "GET",
-                              "/cluster/status", timeout=30)["dataNodes"]
-        if not targets:
-            raise RuntimeError("no alive volume servers")
-        placement: dict[str, list[int]] = {t: [] for t in targets}
-        for sid in range(ctx.total):
-            placement[targets[sid % len(targets)]].append(sid)
-        for target, sids in placement.items():
-            if not sids:
-                continue
-            for sid in sids:
-                _push_file(target, vid, collection, to_ext(sid),
-                           base + to_ext(sid))
-            for ext in (".ecx", ".vif"):
-                _push_file(target, vid, collection, ext, base + ext)
-        for target, sids in placement.items():
-            if sids:
-                _must(http_json("POST", f"{target}/admin/ec/mount",
-                                {"volumeId": vid,
-                                 "collection": collection,
-                                 "shardIds": sids}, timeout=30),
-                      f"mount shards on {target}")
+        with tracing.span("ec.distribute", role="worker") as sp:
+            targets = master_json(worker.master, "GET", "/cluster/status",
+                                  timeout=30)["dataNodes"]
+            if not targets:
+                raise RuntimeError("no alive volume servers")
+            placement: dict[str, list[int]] = {t: [] for t in targets}
+            for sid in range(ctx.total):
+                placement[targets[sid % len(targets)]].append(sid)
+            pushed = 0
+            for target, sids in placement.items():
+                if not sids:
+                    continue
+                for sid in sids:
+                    pushed += _push_file(target, vid, collection,
+                                         to_ext(sid), base + to_ext(sid))
+                for ext in (".ecx", ".vif"):
+                    pushed += _push_file(target, vid, collection, ext,
+                                         base + ext)
+            sp.set("servers", sum(1 for s in placement.values() if s))
+            sp.set("bytes", pushed)
+            for target, sids in placement.items():
+                if sids:
+                    _mount_shards(target, vid, collection, sids)
         return placement
 
     # -- batch execute: N volumes through ONE mesh launch per step -----
@@ -366,8 +384,10 @@ class EcEncodeHandler(JobHandler):
             # one mesh-batched encode for the whole set: volumes ride
             # the data-parallel stripe axis (parallel/ec_batch.py)
             for vid in vids:
-                ec_encoder.write_sorted_file_from_idx(bases[vid])
-            encode_volume_files_batch([bases[v] for v in vids], ctx)
+                _sort_index(bases[vid])
+            with tracing.span("ec.encode", role="worker") as sp:
+                sp.set("volumes", n)
+                encode_volume_files_batch([bases[v] for v in vids], ctx)
             for vid in vids:
                 base = bases[vid]
                 dat_size = os.path.getsize(base + ".dat")
@@ -492,10 +512,7 @@ class EcRebuildHandler(JobHandler):
             timeout=600.0), f"rebuild on {rebuilder}")
         rebuilt = r.get("rebuiltShardIds", [])
         if rebuilt:
-            _must(http_json("POST", f"{rebuilder}/admin/ec/mount",
-                            {"volumeId": vid, "collection": collection,
-                             "shardIds": rebuilt}, timeout=30),
-                  f"mount rebuilt shards on {rebuilder}")
+            _mount_shards(rebuilder, vid, collection, rebuilt)
         worker.report_progress(job_id, 0.7, f"rebuilt {rebuilt}")
         # re-spread like the shell flow: leaving every rebuilt shard
         # on the max-survivor node would silently break the stripe's
@@ -568,13 +585,42 @@ def _read_dat_version(base: str) -> int:
         return SuperBlock.parse(f.read(8), require_extra=False).version
 
 
+def _sort_index(base: str) -> None:
+    with tracing.span("ec.sort_index", role="worker"):
+        ec_encoder.write_sorted_file_from_idx(base)
+
+
+def _mount_shards(target: str, vid: int, collection: str,
+                  sids: "list[int]") -> None:
+    with tracing.span("ec.mount", role="worker") as sp:
+        sp.set("target", target)
+        _must(http_json("POST", f"{target}/admin/ec/mount",
+                        {"volumeId": vid, "collection": collection,
+                         "shardIds": sids}, timeout=30),
+              f"mount shards on {target}")
+
+
 def _push_file(target: str, vid: int, collection: str, ext: str,
-               path: str) -> None:
+               path: str) -> int:
     """Streamed push (http_upload): shard files are sent from disk with
-    bounded memory (shard_distribution.go:101 target side)."""
-    status, body, _ = http_upload(
-        "POST", f"{target}/admin/receive_file?volumeId={vid}"
-        f"&collection={collection}&ext={ext}", path, timeout=600)
-    if status != 200:
-        raise RuntimeError(f"push {ext} to {target}: {status} "
-                           f"{body[:200]!r}")
+    bounded memory (shard_distribution.go:101 target side).  Returns
+    the bytes sent.  The `ec.push` span carries them and this thread's
+    CPU for the push: against the span's wall and the receiver's own
+    `POST /admin/receive_file` span beneath it, that says whether the
+    sender, the receiver or neither was busy."""
+    with tracing.span("ec.push", role="worker") as sp:
+        size = os.path.getsize(path)
+        sp.set("target", target)
+        sp.set("ext", ext)
+        sp.set("bytes", size)
+        cpu0 = time.thread_time()
+        try:
+            status, body, _ = http_upload(
+                "POST", f"{target}/admin/receive_file?volumeId={vid}"
+                f"&collection={collection}&ext={ext}", path, timeout=600)
+        finally:
+            sp.set("cpuSeconds", round(time.thread_time() - cpu0, 6))
+        if status != 200:
+            raise RuntimeError(f"push {ext} to {target}: {status} "
+                               f"{body[:200]!r}")
+    return size

@@ -35,7 +35,7 @@ questions read from:
    manager: one contextvar read on the hot path.
 
 3. Device telemetry — `device_note` (h2d/d2h staging throughput),
-   `kernel_note` (per-encode kernel wall-ms), and
+   `kernel_note` (per-launch dispatch-to-fetched ms), and
    `sample_device_memory` (jax backend memory stats), all recorded
    into stats.PROCESS so every role's /metrics carries them.  jax is
    only imported inside `sample_device_memory`, guarded — the module
@@ -1418,16 +1418,21 @@ def overlap_note(fraction: float, windows: int,
                   help_text="h2d staging windows launched", op=op)
 
 
-def kernel_note(kernel: str, seconds: float, nbytes: int = 0) -> None:
-    """Record one device kernel dispatch-to-materialize window."""
+def kernel_note(kernel: str, seconds: float) -> None:
+    """Record one device launch's dispatch-to-fetched window: from the
+    kernel's dispatch to its output on the host.  NOT a kernel time —
+    it holds the wait in the hand-off queue and the d2h fetch too (the
+    host-side fetch is the only fence an async backend offers); a
+    kernel's own time comes from a device trace."""
     m = _process_metrics()
     m.histogram_observe("device_kernel_seconds", seconds,
-                        help_text="device kernel wall time per launch",
+                        help_text="dispatch-to-fetched window per "
+                                  "device launch (queue wait and d2h "
+                                  "included; not kernel time)",
                         kernel=kernel)
-    m.gauge_set("device_kernel_last_ms", seconds * 1e3, kernel=kernel)
-    if nbytes:
-        m.counter_add("device_kernel_bytes_total", float(nbytes),
-                      kernel=kernel)
+    m.gauge_set("device_kernel_last_ms", seconds * 1e3,
+                help_text="last device launch's dispatch-to-fetched "
+                          "window", kernel=kernel)
 
 
 def sample_device_memory() -> "dict[str, dict]":

@@ -320,14 +320,15 @@ class AsyncFront:
         # deadline-carrying requests always pay the thread-CPU clock,
         # budget-less ones every Nth — and the k<=0 kill switch
         # gates both (cpu_attr_front)
+        _, parent_span = tracing.parse_traceparent(
+            req.headers.get(tracing.HEADER, ""))
         cpu0 = _time.thread_time() \
-            if _prof.cpu_attr_front(dl is not None) else None
+            if _prof.cpu_attr_front(
+                dl is not None or bool(parent_span)) else None
         verdict = "ok"
         route = outer.routes.get((req.method, req.path))
         if route is None and outer.prefix_routes:
             route = outer._prefix_route(req.method, req.path)
-        _, parent_span = tracing.parse_traceparent(
-            req.headers.get(tracing.HEADER, ""))
         sp = tracing.start_span(
             f"{req.method} {req.path}", role=outer.role,
             parent=parent_span, trace_id=rid)
@@ -399,6 +400,7 @@ class AsyncFront:
                 help_text="requests currently being handled")
         sp = None
         status = 0
+        sent = 0
         qos_release = None
         stream_body = None
         cpu = None
@@ -417,6 +419,7 @@ class AsyncFront:
                 head.append(f"{hk}: {hv}")
             if hasattr(body, "read"):
                 stream_body = body
+                sent = int(extra_headers.get("Content-Length") or 0)
                 # file-like bodies must carry Content-Length in
                 # extra_headers (the threaded front's rule; these
                 # responses are never chunked)
@@ -432,6 +435,7 @@ class AsyncFront:
                         await writer.drain()
                 await writer.drain()
                 return keep
+            sent = len(body)
             if "Content-Length" not in extra_headers:
                 head.append(f"Content-Length: {len(body)}")
             writer.write(("\r\n".join(head) + "\r\n\r\n")
@@ -458,8 +462,7 @@ class AsyncFront:
                     wlog.warning("qos release failed: %s", e,
                                  component="qos")
             if sp is not None:
-                sp.set("status", status)
-                sp.finish()
+                outer.close_server_span(sp, req, status, cpu, sent)
             with outer._inflight_lock:
                 outer._inflight -= 1
                 inflight = outer._inflight

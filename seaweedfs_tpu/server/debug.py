@@ -54,20 +54,21 @@ from .httpd import HttpServer, Request
 
 
 def install_debug_routes(http: HttpServer) -> None:
-    http.route("GET", "/debug/stacks", _stacks)
-    http.route("GET", "/debug/vars", _vars)
-    http.route("GET", "/debug/profile", _profile)
-    http.route("GET", "/debug/traces", _traces)
-    http.route("GET", "/debug/faults", _faults_get)
+    # reads of the debug plane are chatter (HttpServer.route quiet=):
+    # a scrape or a trace.show must not write into the ring it reads
+    for path, fn in (("/debug/stacks", _stacks), ("/debug/vars", _vars),
+                     ("/debug/profile", _profile),
+                     ("/debug/traces", _traces),
+                     ("/debug/faults", _faults_get),
+                     ("/debug/health", _health), ("/debug/qos", _qos_get),
+                     ("/debug/pprof", _pprof_get),
+                     ("/debug/slow", _slow_get),
+                     ("/debug/attribution", _attr_get)):
+        http.route("GET", path, fn, quiet=True)
     http.route("POST", "/debug/faults", _faults_post)
-    http.route("GET", "/debug/health", _health)
-    http.route("GET", "/debug/qos", _qos_get)
     http.route("POST", "/debug/qos", _qos_post)
-    http.route("GET", "/debug/pprof", _pprof_get)
     http.route("POST", "/debug/pprof", _pprof_post)
-    http.route("GET", "/debug/slow", _slow_get)
     http.route("POST", "/debug/slow", _slow_post)
-    http.route("GET", "/debug/attribution", _attr_get)
     http.route("POST", "/debug/attribution", _attr_post)
 
 
@@ -101,7 +102,7 @@ def install_autopilot_routes(http: HttpServer, ap) -> None:
             return 400, {"error": str(e)}
         return 200, ap.snapshot()
 
-    http.route("GET", "/debug/autopilot", _ap_get)
+    http.route("GET", "/debug/autopilot", _ap_get, quiet=True)
     http.route("POST", "/debug/autopilot", _ap_post)
     from .. import profiling
     profiling.maybe_autostart()  # SEAWEEDFS_TPU_PROFILE_HZ boot arming

@@ -306,7 +306,7 @@ class FilerServer:
         # middleware plus filer-specific gauges below
         from ..stats import Metrics
         self.metrics = Metrics("filer")
-        self.http.route("GET", "/metrics", self._metrics)
+        self.http.route("GET", "/metrics", self._metrics, quiet=True)
         self.http.role = "filer"
         self.http.metrics = self.metrics
         from .debug import install_debug_routes

@@ -258,6 +258,7 @@ class HttpServer:
         # request; hot-path dispatch now resolves exact -> prefix ->
         # fallback from tables built once at boot.
         self.prefix_routes: dict[str, list] = {}
+        self.quiet_routes: set[tuple[str, str]] = set()  # route(quiet=)
         self.fallback: Route | None = None
         # optional auth hook (security/guard.go Guard): returns None to
         # continue or a (status, payload) response to short-circuit
@@ -329,23 +330,29 @@ class HttpServer:
                 flight_on = _prof.recorder_enabled()
                 if flight_on:
                     _prof.arm_flight_notes()
+                # server span: trace id = request id, parent from the
+                # caller's X-Trace-Parent (tracing.py); every role's
+                # handler is wrapped by this one middleware.  A
+                # request that carries a trace parent is one somebody
+                # will read the trace of, so it pays the clock too:
+                # its span says how much of its wall was this thread's
+                # CPU (the receiver's half of a bulk push)
+                _, parent_span = tracing.parse_traceparent(
+                    req.headers.get(tracing.HEADER, ""))
                 cpu0 = time.thread_time() \
-                    if _prof.cpu_attr_front(dl is not None) else None
+                    if _prof.cpu_attr_front(
+                        dl is not None or bool(parent_span)) else None
                 verdict = "ok"
                 route = outer.routes.get((req.method, req.path))
                 if route is None and outer.prefix_routes:
                     route = outer._prefix_route(req.method, req.path)
-                # server span: trace id = request id, parent from the
-                # caller's X-Trace-Parent (tracing.py); every role's
-                # handler is wrapped by this one middleware
-                _, parent_span = tracing.parse_traceparent(
-                    req.headers.get(tracing.HEADER, ""))
                 sp = tracing.start_span(
                     f"{req.method} {req.path}", role=outer.role,
                     parent=parent_span, trace_id=rid)
                 if dl is not None:
                     sp.set("deadlineMs", int(dl.remaining() * 1e3))
                 status = 0
+                sent = 0                # response body bytes
                 qos_release = None
                 stream_cleanup = None   # file-like response body
                 with outer._inflight_lock:
@@ -441,6 +448,8 @@ class HttpServer:
                         # (the bulk-data serve path).  Content-Length
                         # must be in extra_headers — these responses
                         # are never chunked.
+                        sent = int(extra_headers.get(
+                            "Content-Length") or 0)
                         self.end_headers()
                         try:
                             if req.method == "HEAD":
@@ -482,6 +491,7 @@ class HttpServer:
                         self.send_header("Content-Length",
                                          str(len(body)))
                     self.end_headers()
+                    sent = len(body)
                     if req.method != "HEAD":
                         self.wfile.write(body)
                 finally:
@@ -499,14 +509,13 @@ class HttpServer:
                             wlog.warning(
                                 "qos release failed: %s", e,
                                 component="qos")
-                    sp.set("status", status)
-                    sp.finish()
                     # this thread's CPU for the whole request —
                     # handler AND response write (the streamed-body
                     # paths run above on this same thread); None when
                     # this request didn't draw the attribution sample
                     cpu = (time.thread_time() - cpu0) \
                         if cpu0 is not None else None
+                    outer.close_server_span(sp, req, status, cpu, sent)
                     with outer._inflight_lock:
                         outer._inflight -= 1
                         inflight = outer._inflight
@@ -680,8 +689,36 @@ class HttpServer:
         self._thread: threading.Thread | None = None
         self._async = None   # asyncio front, when selected (start())
 
-    def route(self, method: str, path: str, fn: Route) -> None:
+    def route(self, method: str, path: str, fn: Route,
+              quiet: bool = False) -> None:
+        """`quiet` marks chatter — status, poll, scrape and heartbeat
+        routes: their server spans are recorded only when they err or
+        are slow (tracing.Span.quiet), so a minute of polling cannot
+        turn the ring over on the trace of the job being polled."""
         self.routes[(method, path)] = fn
+        if quiet:
+            self.quiet_routes.add((method, path))
+        else:
+            self.quiet_routes.discard((method, path))
+
+    def close_server_span(self, sp, req, status: int,
+                          cpu: "float | None", sent: int) -> None:
+        """Close a request's server span — both fronts end here.
+        `bytes` is the request's body where it has one, else the
+        response's (a bulk push or pull is its bytes); `cpuSeconds` is
+        the handler thread's CPU where the request paid the clock."""
+        sp.set("status", status)
+        try:
+            nbytes = int(req.headers.get("Content-Length") or 0) or sent
+        except ValueError:
+            nbytes = sent
+        if nbytes:
+            sp.set("bytes", nbytes)
+        if cpu is not None:
+            sp.set("cpuSeconds", round(cpu, 6))
+        sp.quiet = status < 500 and \
+            (req.method, req.path) in self.quiet_routes
+        sp.finish()
 
     def route_prefix(self, method: str, prefix: str, fn: Route) -> None:
         """Register a handler for every path under `prefix`.  The
@@ -868,6 +905,28 @@ class _RelaySourceError(OSError):
     destination never answered, so no verdict probe is possible."""
 
 
+def _trace_headers(headers: dict) -> dict:
+    """Forward the active request id + trace parent on every internal
+    hop (util/request_id, tracing.py): the receiving server adopts
+    both, so one id traces gateway -> filer -> volume in the logs and
+    the receiver's server span hangs under this caller's span.  The
+    pooled funnel and every bulk path (download, upload, relay,
+    stream) stamp through here — a bulk transfer is where an EC job
+    spends its time, and a trace cut there loses the receiver's half."""
+    from .. import tracing
+    from ..util.request_id import HEADER as _RID_HEADER
+    from ..util.request_id import get_request_id
+    rid = get_request_id()
+    if rid and _RID_HEADER not in headers:
+        headers = dict(headers)
+        headers[_RID_HEADER] = rid
+    tp = tracing.traceparent_header()
+    if tp and tracing.HEADER not in headers:
+        headers = dict(headers)
+        headers[tracing.HEADER] = tp
+    return headers
+
+
 def _fire_fault(site: str, key: str = "") -> "str | None":
     """faults.py hook for the client funnel (late import: httpd is on
     every role's startup path).  Returns the directive for
@@ -897,7 +956,8 @@ def http_download(url: str, dest_path: str,
     timeout = _dl.io_timeout(timeout, site="httpd.download")
     full_url, ctx = _dial(url)
     req = urllib.request.Request(
-        full_url, headers=_dl.stamp_headers(_auth_for(url, headers)))
+        full_url, headers=_dl.stamp_headers(
+            _trace_headers(_auth_for(url, headers))))
     # download into a sibling temp file and os.replace on success: a
     # mid-transfer failure (connection reset at 10GB of a 30GB pull)
     # must never leave a truncated file at dest_path for the store to
@@ -951,7 +1011,8 @@ def http_relay(src_url: str, dst_method: str, dst_url: str,
     full_src, src_ctx = _dial(src_url)
     req = urllib.request.Request(
         full_src,
-        headers=_dl.stamp_headers(_auth_for(src_url, headers)))
+        headers=_dl.stamp_headers(
+            _trace_headers(_auth_for(src_url, headers))))
     try:
         resp = urllib.request.urlopen(req, timeout=timeout,
                                       context=src_ctx)
@@ -973,7 +1034,7 @@ def http_relay(src_url: str, dst_method: str, dst_url: str,
             conn = http.client.HTTPConnection(parsed.netloc,
                                               timeout=timeout)
         up_headers = dict(_dl.stamp_headers(
-            _auth_for(dst_url, headers)))
+            _trace_headers(_auth_for(dst_url, headers))))
         up_headers["Transfer-Encoding"] = "chunked"
         expected = resp.length  # None when the source streams chunked
 
@@ -1075,7 +1136,8 @@ def http_stream_request(method: str, url: str, chunks,
     else:
         conn = http.client.HTTPConnection(parsed.netloc,
                                           timeout=timeout)
-    up_headers = dict(_dl.stamp_headers(_auth_for(url, headers)))
+    up_headers = dict(_dl.stamp_headers(
+        _trace_headers(_auth_for(url, headers))))
     try:
         # manual chunk framing instead of http.client's encode_chunked:
         # that path CONCATENATES header+chunk+trailer into a fresh
@@ -1153,7 +1215,8 @@ def http_upload(method: str, url: str, src_path: str,
     from ..util import deadline as _dl
     timeout = _dl.io_timeout(timeout, site="httpd.upload")
     size = _os.path.getsize(src_path)
-    headers = dict(_dl.stamp_headers(_auth_for(url, headers)))
+    headers = dict(_dl.stamp_headers(
+        _trace_headers(_auth_for(url, headers))))
     headers["Content-Length"] = str(size)
     full_url, ctx = _dial(url)
     with open(src_path, "rb") as f:
@@ -1285,21 +1348,7 @@ def _one_pooled_request(method: str, full_url: str, body,
 
 def _pooled_request(method: str, url: str, body, headers: dict,
                     timeout: float, max_redirects: int = 3):
-    # forward the active request id + trace parent on every internal
-    # hop (util/request_id, tracing.py): the receiving server adopts
-    # both, so one id traces gateway -> filer -> volume in the logs
-    # and the receiver's server span hangs under this caller's span
-    from .. import tracing
-    from ..util.request_id import HEADER as _RID_HEADER
-    from ..util.request_id import get_request_id
-    rid = get_request_id()
-    if rid and _RID_HEADER not in headers:
-        headers = dict(headers)
-        headers[_RID_HEADER] = rid
-    tp = tracing.traceparent_header()
-    if tp and tracing.HEADER not in headers:
-        headers = dict(headers)
-        headers[tracing.HEADER] = tp
+    headers = _trace_headers(headers)
     full_url, ctx = _dial(url)
     # unified failure policy (util/retry): consult the peer's circuit
     # breaker before dialing (a tripped peer fails fast instead of

@@ -64,7 +64,7 @@ class MasterServer:
         self._admin_lock_name = ""
         self.http = HttpServer(host, port)
         r = self.http.route
-        r("POST", "/heartbeat", self._heartbeat)
+        r("POST", "/heartbeat", self._heartbeat, quiet=True)
         r("GET", "/dir/assign", self._assign)
         r("POST", "/dir/assign", self._assign)
         r("GET", "/dir/lookup", self._lookup)
@@ -72,12 +72,12 @@ class MasterServer:
         r("GET", "/dir/status", self._dir_status)
         r("GET", "/vol/list", self._vol_list)
         r("POST", "/vol/grow", self._vol_grow)
-        r("GET", "/cluster/status", self._cluster_status)
+        r("GET", "/cluster/status", self._cluster_status, quiet=True)
         r("POST", "/cluster/raft/config", self._raft_config)
         r("POST", "/cluster/raft/transfer", self._raft_transfer)
         r("POST", "/cluster/lease_admin_token", self._lease_admin)
         r("POST", "/cluster/release_admin_token", self._release_admin)
-        r("GET", "/metrics", self._metrics)
+        r("GET", "/metrics", self._metrics, quiet=True)
         from .debug import install_debug_routes
         install_debug_routes(self.http)  # util/grace/pprof.go analog
         self.http.guard = self._guard

@@ -99,7 +99,7 @@ class VolumeServer:
         r("POST", "/admin/leave", self._leave)
         r("POST", "/admin/vacuum_toggle", self._vacuum_toggle)
         r("POST", "/admin/ec/scrub", self._ec_scrub)
-        r("GET", "/metrics", self._metrics)
+        r("GET", "/metrics", self._metrics, quiet=True)
         from .debug import install_debug_routes
         install_debug_routes(self.http)  # util/grace/pprof.go analog
         self.http.fallback = self._data_path

@@ -144,16 +144,21 @@ class _StageTimer:
             self.last = t1
             self.calls += 1
 
-    def emit(self, name: str, trace_ctx, **attrs) -> None:
+    def emit(self, name: str, trace_ctx, busy: bool = True,
+             **attrs) -> None:
         """Record the stage window as a trace span parented to the
         span active when the rebuild started (`trace_ctx` from
         tracing.current_ids() — stages ran on other threads, so the
-        contextvar cannot be relied on here)."""
+        contextvar cannot be relied on here).  `busy=False` leaves
+        busySeconds out: a codec stage that only DISPATCHES to a
+        device (the lazy launch) is busy for microseconds, and the
+        number would read as a kernel time."""
         if not self.calls:
             return
         from ... import tracing
-        attrs.update(busySeconds=round(self.busy, 6),
-                     calls=self.calls)
+        attrs.update(calls=self.calls)
+        if busy:
+            attrs.update(busySeconds=round(self.busy, 6))
         tracing.emit_span(
             name, self.start_wall, self.last - self.first,
             role=trace_ctx[2] if trace_ctx else "",
@@ -367,12 +372,14 @@ def _generate_ec_files(base_file_name: str, ctx: ECContext,
         # padding) are left dirty on purpose: the GF apply is
         # byte-column-independent and the writer truncates at `real`,
         # so their content can never affect an emitted byte.
+        from_dat = 0    # volume bytes in this batch, no fill, no pad
         if batch <= block_size:
             # chunk WITHIN one (large) row: gather the d strided
             # block slices at batch offset b0
             for i in range(d):
                 dat.seek(row_start + i * block_size + b0)
                 got = dat.readinto(memoryview(buf[i])[:batch])
+                from_dat += got
                 if got < batch:
                     buf[i, got:] = 0
         else:
@@ -384,17 +391,19 @@ def _generate_ec_files(base_file_name: str, ctx: ECContext,
                 for i in range(d):
                     got = dat.readinto(
                         memoryview(buf[i])[base:base + block_size])
+                    from_dat += got
                     if got < block_size:
                         buf[i, base + got:base + block_size] = 0
         real = min(batch, real_rows * block_size)
-        return (buf, real)
+        return (buf, real, from_dat)
 
     lazy = getattr(codec, "parity_lazy", None)
 
     def compute(payload):
-        buf, _real = payload
+        buf, _real, from_dat = payload
         if lazy is not None:
-            return lazy(buf)  # async dispatch; writer materializes
+            # async dispatch; writer materializes
+            return lazy(buf, payload_bytes=from_dat)
         return np.ascontiguousarray(np.asarray(codec.parity(buf)))
 
     written = 0  # volume bytes whose d+p shard slices reached the sinks
@@ -405,7 +414,7 @@ def _generate_ec_files(base_file_name: str, ctx: ECContext,
 
     def write_item(payload, parity):
         nonlocal written
-        buf, real = payload
+        buf, real, _from_dat = payload
         for i in range(d):
             sinks[i].write(buf[i, :real].data)
         if hasattr(parity, "windows"):
@@ -483,7 +492,7 @@ def _generate_ec_files(base_file_name: str, ctx: ECContext,
             by_dest = stats.snapshot()[0]
             read_item.emit("encode.read", trace_ctx,
                            datBytes=dat_size, windows=len(work))
-            compute.emit("encode.codec", trace_ctx,
+            compute.emit("encode.codec", trace_ctx, busy=lazy is None,
                          dataShards=d, parityShards=ctx.total - d,
                          backend=ctx.backend)
             write_item.emit("encode.write", trace_ctx,
@@ -716,7 +725,7 @@ def rebuild_from_sources(base_file_name: str, ctx: ECContext,
             read_item.emit("rebuild.fetch", trace_ctx,
                            bytesBySource=by_source,
                            windows=len(work), sliceBytes=step)
-            compute.emit("rebuild.codec", trace_ctx,
+            compute.emit("rebuild.codec", trace_ctx, busy=lazy is None,
                          missingShards=list(missing),
                          dataShards=ctx.data_shards)
             write_item.emit("rebuild.write", trace_ctx,

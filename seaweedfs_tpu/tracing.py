@@ -25,7 +25,10 @@ Design constraints, in order:
   drops span RECORDING, never id propagation, so a sampled-out parent
   still stitches its children to the same trace;
 - spans slower than `SEAWEEDFS_TPU_SLOW_MS` are written through
-  util/wlog at WARN with their attrs (the slow-request log).
+  util/wlog at WARN with their attrs (the slow-request log);
+- chatter does not evict work: a server span of a route registered
+  quiet (status, poll, scrape, heartbeat) is recorded only when it
+  errs or is slow, so an EC job's trace outlives a minute of polls.
 
 API shapes the SWFS007 lint understands:
 
@@ -117,8 +120,8 @@ class Span:
     no dict allocated until an attr is set."""
 
     __slots__ = ("trace_id", "span_id", "parent_id", "role", "name",
-                 "start", "duration", "attrs", "error", "_token",
-                 "_t0", "_finished")
+                 "start", "duration", "attrs", "error", "quiet",
+                 "_token", "_t0", "_finished")
 
     def __init__(self, name: str, trace_id: str, span_id: str,
                  parent_id: str, role: str):
@@ -131,6 +134,13 @@ class Span:
         self.duration = 0.0
         self.attrs: "dict | None" = None
         self.error = False
+        # a quiet span (status, poll, scrape and heartbeat routes —
+        # HttpServer.route(quiet=True)) still carries its ids to
+        # children and outbound hops, but is recorded only when it
+        # errs or is slower than SEAWEEDFS_TPU_SLOW_MS: a client
+        # polling a job's status must not turn the ring over before
+        # anyone asks for the job's trace
+        self.quiet = False
         self._token = None
         self._t0 = time.perf_counter()
         self._finished = False
@@ -161,6 +171,10 @@ class Span:
             except ValueError:   # finished on a different context
                 pass
             self._token = None
+        if self.quiet and not self.error:
+            threshold = slow_ms()
+            if threshold <= 0 or self.duration * 1e3 < threshold:
+                return
         _record(self.to_dict())
 
     def to_dict(self) -> dict:

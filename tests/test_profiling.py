@@ -207,7 +207,7 @@ def test_stage_timers_disable_knob(monkeypatch):
 
 def test_device_and_kernel_notes_land_in_process_registry():
     profiling.device_note("h2d", 1 << 20, 0.001)
-    profiling.kernel_note("gf_apply_matrix", 0.002, 1 << 20)
+    profiling.kernel_note("gf_apply_matrix", 0.002)
     text = stats.render_process()
     assert 'device_transfer_bytes_total{dir="h2d"}' in text
     assert 'device_kernel_last_ms{kernel="gf_apply_matrix"}' in text
