@@ -602,11 +602,13 @@ def _mount_shards(target: str, vid: int, collection: str,
 
 def _push_file(target: str, vid: int, collection: str, ext: str,
                path: str) -> int:
-    """Streamed push (http_upload): shard files are sent from disk with
-    bounded memory (shard_distribution.go:101 target side).  Returns
-    the bytes sent.  The `ec.push` span carries them and this thread's
-    CPU for the push: against the span's wall and the receiver's own
-    `POST /admin/receive_file` span beneath it, that says whether the
+    """Streamed push (http_upload): shard files go from disk to the
+    socket by sendfile, or under TLS through one 1 MiB buffer
+    (shard_distribution.go:101 target side).  Returns the bytes sent.
+    The `ec.push` span carries them, which of the two it was (`via`,
+    as http_upload reports it) and this thread's CPU for the push:
+    against the span's wall and the receiver's own `POST
+    /admin/receive_file` span beneath it, that says whether the
     sender, the receiver or neither was busy."""
     with tracing.span("ec.push", role="worker") as sp:
         size = os.path.getsize(path)
@@ -615,9 +617,11 @@ def _push_file(target: str, vid: int, collection: str, ext: str,
         sp.set("bytes", size)
         cpu0 = time.thread_time()
         try:
-            status, body, _ = http_upload(
+            sent = http_upload(
                 "POST", f"{target}/admin/receive_file?volumeId={vid}"
                 f"&collection={collection}&ext={ext}", path, timeout=600)
+            sp.set("via", sent.via)
+            status, body, _ = sent
         finally:
             sp.set("cpuSeconds", round(time.thread_time() - cpu0, 6))
         if status != 200:

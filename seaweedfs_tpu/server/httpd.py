@@ -935,6 +935,36 @@ def _fire_fault(site: str, key: str = "") -> "str | None":
     return faults.fire(site, key=key)
 
 
+def _open_conn(full_url: str, ctx, timeout: float):
+    """(http.client connection, request target) for a `_dial`ed url —
+    the bulk client paths drive the connection themselves (chunk
+    framing, sendfile), which urllib does not let them."""
+    import http.client
+    parsed = urllib.parse.urlsplit(full_url)
+    target = parsed.path or "/"
+    if parsed.query:
+        target += "?" + parsed.query
+    if parsed.scheme == "https":
+        return http.client.HTTPSConnection(
+            parsed.netloc, timeout=timeout, context=ctx), target
+    return http.client.HTTPConnection(parsed.netloc,
+                                      timeout=timeout), target
+
+
+def _receiver_verdict(conn) -> "tuple[int, bytes, dict] | None":
+    """After a body send failed: the receiver may have REJECTED the
+    upload mid-body (4xx/5xx + close) — its verdict is the root cause
+    the caller needs, not the sender's broken pipe.  (status, body,
+    headers) when a response is readable, else None and the caller
+    re-raises its send error."""
+    import http.client
+    try:
+        resp = conn.getresponse()
+        return resp.status, resp.read(), dict(resp.headers)
+    except (OSError, http.client.HTTPException):
+        return None
+
+
 def http_download(url: str, dest_path: str,
                   headers: dict | None = None, timeout: float = 60.0,
                   chunk_size: int = 4 << 20) -> tuple[int, dict]:
@@ -1004,8 +1034,6 @@ def http_relay(src_url: str, dst_method: str, dst_url: str,
     source the upload never starts (dst_status 0).  `timeout` is a
     per-socket-operation stall bound (see http_download), deadline-
     derived when the request carries a budget."""
-    import http.client
-
     from ..util import deadline as _dl
     timeout = _dl.io_timeout(timeout, site="httpd.relay")
     full_src, src_ctx = _dial(src_url)
@@ -1023,16 +1051,7 @@ def http_relay(src_url: str, dst_method: str, dst_url: str,
         if resp.status != 200:
             return resp.status, 0, b""
         full_dst, dst_ctx = _dial(dst_url)
-        parsed = urllib.parse.urlsplit(full_dst)
-        target = parsed.path or "/"
-        if parsed.query:
-            target += "?" + parsed.query
-        if parsed.scheme == "https":
-            conn = http.client.HTTPSConnection(
-                parsed.netloc, timeout=timeout, context=dst_ctx)
-        else:
-            conn = http.client.HTTPConnection(parsed.netloc,
-                                              timeout=timeout)
+        conn, target = _open_conn(full_dst, dst_ctx, timeout)
         up_headers = dict(_dl.stamp_headers(
             _trace_headers(_auth_for(dst_url, headers))))
         up_headers["Transfer-Encoding"] = "chunked"
@@ -1089,18 +1108,13 @@ def http_relay(src_url: str, dst_method: str, dst_url: str,
                              headers=up_headers, encode_chunked=True)
             except _RelaySourceError:
                 raise
-            except OSError as send_err:
+            except OSError:
                 # the send socket failed: the DESTINATION may have
-                # rejected the upload mid-body (4xx/5xx + close) —
-                # its verdict, not this broken pipe, is the root
-                # cause; surface it when the response is readable
-                # (http_stream_request's rule)
-                import http.client as _hc
-                try:
-                    r = conn.getresponse()
-                    return 200, r.status, r.read()
-                except (OSError, _hc.HTTPException):
-                    raise send_err from None
+                # spoken first
+                verdict = _receiver_verdict(conn)
+                if verdict is None:
+                    raise
+                return 200, verdict[0], verdict[1]
             r = conn.getresponse()
             return 200, r.status, r.read()
         finally:
@@ -1121,21 +1135,10 @@ def http_stream_request(method: str, url: str, chunks,
     truncated-but-clean upload.  Returns (status, body).  `timeout`
     is a per-socket-operation stall bound (see http_download),
     deadline-derived when the request carries a budget."""
-    import http.client
-
     from ..util import deadline as _dl
     timeout = _dl.io_timeout(timeout, site="httpd.stream")
     full_url, ctx = _dial(url)
-    parsed = urllib.parse.urlsplit(full_url)
-    target = parsed.path or "/"
-    if parsed.query:
-        target += "?" + parsed.query
-    if parsed.scheme == "https":
-        conn = http.client.HTTPSConnection(
-            parsed.netloc, timeout=timeout, context=ctx)
-    else:
-        conn = http.client.HTTPConnection(parsed.netloc,
-                                          timeout=timeout)
+    conn, target = _open_conn(full_url, ctx, timeout)
     up_headers = dict(_dl.stamp_headers(
         _trace_headers(_auth_for(url, headers))))
     try:
@@ -1186,48 +1189,87 @@ def http_stream_request(method: str, url: str, chunks,
             # and let the finally tear the connection down mid-body
             raise
         except OSError:
-            # the receiver may have REJECTED the upload mid-body
-            # (4xx/5xx + close) — its verdict is the root cause the
-            # caller needs, not this broken pipe; surface it if the
-            # response is readable
-            import http.client as _hc
-            try:
-                resp = conn.getresponse()
-                return resp.status, resp.read()
-            except (OSError, _hc.HTTPException):
-                pass
-            raise
+            verdict = _receiver_verdict(conn)
+            if verdict is None:
+                raise
+            return verdict[:2]
         resp = conn.getresponse()
         return resp.status, resp.read()
     finally:
         conn.close()
 
 
+class UploadResult(tuple):
+    """http_upload's (status, body, headers), which also says in `via`
+    how the body went to the socket: "sendfile" or "blocks"."""
+
+    def __new__(cls, status: int, body: bytes, headers: dict,
+                via: str):
+        self = super().__new__(cls, (status, body, headers))
+        self.via = via
+        return self
+
+
+_UPLOAD_BLOCK = 1 << 20
+
+
 def http_upload(method: str, url: str, src_path: str,
                 headers: dict | None = None, timeout: float = 60.0
-                ) -> tuple[int, bytes, dict]:
-    """Send a file as the request body WITHOUT buffering it in memory:
-    Content-Length is set from the file size and http.client streams
-    the file object in blocks (the worker's bulk shard push).
-    `timeout` is a per-socket-operation stall bound (see
-    http_download), deadline-derived when a budget is armed."""
+                ) -> UploadResult:
+    """Send a file as the request body WITHOUT buffering it in memory
+    (the worker's bulk shard push): Content-Length is the open file's
+    size, and the body goes to a plain socket by `socket.sendfile` (the
+    kernel copies) and to a TLS one, where sendfile would fall back to
+    8 KiB sends, through one reused 1 MiB buffer.  A body that ends
+    short of its Content-Length raises; a receiver that answers
+    mid-body and closes gets its status and body returned, not the
+    sender's broken pipe.  `timeout` is a per-socket-operation stall
+    bound (see http_download), deadline-derived when a budget is
+    armed."""
     import os as _os
     from ..util import deadline as _dl
     timeout = _dl.io_timeout(timeout, site="httpd.upload")
-    size = _os.path.getsize(src_path)
-    headers = dict(_dl.stamp_headers(
-        _trace_headers(_auth_for(url, headers))))
-    headers["Content-Length"] = str(size)
+    up_headers = _dl.stamp_headers(
+        _trace_headers(_auth_for(url, headers)))
     full_url, ctx = _dial(url)
-    with open(src_path, "rb") as f:
-        req = urllib.request.Request(full_url, data=f, method=method,
-                                     headers=headers)
-        try:
-            with urllib.request.urlopen(req, timeout=timeout,
-                                        context=ctx) as resp:
-                return resp.status, resp.read(), dict(resp.headers)
-        except urllib.error.HTTPError as e:
-            return e.code, e.read(), dict(e.headers)
+    conn, target = _open_conn(full_url, ctx, timeout)
+    via = "blocks" if full_url.startswith("https") else "sendfile"
+    try:
+        with open(src_path, "rb") as f:
+            size = _os.fstat(f.fileno()).st_size
+            conn.putrequest(method, target, skip_accept_encoding=True)
+            for hk, hv in up_headers.items():
+                conn.putheader(hk, hv)
+            conn.putheader("Content-Length", str(size))
+            conn.endheaders()
+            sent = 0
+            try:
+                if via == "sendfile":
+                    if size:
+                        sent = conn.sock.sendfile(f, 0, size)
+                else:
+                    block = memoryview(bytearray(_UPLOAD_BLOCK))
+                    while sent < size:
+                        n = f.readinto(block[:size - sent])
+                        if not n:
+                            break
+                        conn.sock.sendall(block[:n])
+                        sent += n
+            except TimeoutError:
+                raise           # a stalled receiver has said nothing
+            except OSError:
+                verdict = _receiver_verdict(conn)
+                if verdict is None:
+                    raise
+                return UploadResult(*verdict, via)
+        if sent != size:
+            raise IOError(f"upload {src_path}: file ended at {sent} "
+                          f"of {size} bytes")
+        resp = conn.getresponse()
+        return UploadResult(resp.status, resp.read(),
+                            dict(resp.headers), via)
+    finally:
+        conn.close()
 
 
 # --- pooled keep-alive client (the hot data-plane funnel) ----------------

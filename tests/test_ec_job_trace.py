@@ -140,6 +140,8 @@ def test_pull_and_pushes_carry_their_bytes(job):
     for s in pushes:
         assert s["parentId"] == dist["spanId"]
         assert 0 <= s["attrs"]["cpuSeconds"] <= s["durationMs"] / 1e3 + 0.05
+        # what http_upload did on this plain-HTTP cluster (ISSUE 26)
+        assert s["attrs"]["via"] == "sendfile"
 
 
 def test_each_push_holds_the_receivers_span(job):
@@ -201,6 +203,10 @@ def test_trace_show_renders_one_tree_down_to_the_receivers(job):
     assert depth["job:erasure_coding"] < depth["ec.distribute"] < \
         depth["ec.push"] < depth["POST /admin/receive_file"]
     assert depth["ec.encode"] < depth["stage.h2d"]
+    pushes = [ln for ln in lines if "ms ec.push  " in ln]
+    assert len(pushes) == FILES_PUSHED
+    assert all(" via=sendfile " in ln and " ext=." in ln and
+               " cpuSeconds=" in ln for ln in pushes)
 
 
 # -- quiet routes -------------------------------------------------------------
@@ -239,11 +245,14 @@ def quiet_server():
     http.stop()
 
 
-def _names(trace_id):
+def _names(trace_id, spans=1):
+    """Names recorded under the trace, once `spans` of them are there:
+    a server span closes after its reply is on the wire, so the caller
+    can be back before it."""
     deadline = time.monotonic() + 2
     while True:
         got = sorted(s["name"] for s in tracing.spans_for(trace_id))
-        if got or time.monotonic() > deadline:
+        if len(got) >= spans or time.monotonic() > deadline:
             return got
         time.sleep(0.01)
 
@@ -263,7 +272,7 @@ def test_a_quiet_route_propagates_ids_and_records_nothing(quiet_server):
     trace_id, parent = seen["inner"]
     assert trace_id == "q-1" and parent      # under the unrecorded span
     assert _get(http, "/loud", "q-2") == 200
-    assert _names("q-2") == ["GET /loud", "inner"]
+    assert _names("q-2", spans=2) == ["GET /loud", "inner"]
 
 
 @pytest.mark.parametrize("path,status", [("/boom", 500), ("/refuse", 503)])
