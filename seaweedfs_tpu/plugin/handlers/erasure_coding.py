@@ -33,6 +33,47 @@ from ..worker import JobHandler
 
 from ..worker import must as _must
 
+# how many of the master's pulses a job gives a server the master has
+# just let go of (a beat late, the master's machine stalled): at its
+# start, before it takes the servers named as the spread it promises,
+# and at distribute, before it fails rather than place narrower.  The
+# master lets go after three; the fourth is for the beat that follows.
+PLACEMENT_WAIT_PULSES = 4
+
+
+def _cluster_status(worker) -> dict:
+    return master_json(worker.master, "GET", "/cluster/status", timeout=30)
+
+
+def _await_cluster(worker, settled) -> "tuple[dict, float, bool]":
+    """(the master's /cluster/status, seconds spent asking again,
+    whether `settled(status)` held at last): asks every quarter pulse
+    until it holds, for PLACEMENT_WAIT_PULSES at most."""
+    t0 = time.monotonic()
+    waited = 0.0
+    while True:
+        status = _cluster_status(worker)
+        pulse = float(status["pulseSeconds"])
+        ok = settled(status)
+        if ok or waited >= PLACEMENT_WAIT_PULSES * pulse:
+            return status, waited, ok
+        time.sleep(pulse / 4)
+        waited = time.monotonic() - t0
+
+
+def _servers_at_start(worker) -> "tuple[list[str], float]":
+    """(the servers a job starts under, seconds it waited for them):
+    those the master holds alive, once it has let go of none within
+    the last PLACEMENT_WAIT_PULSES.  One look in such a second would
+    take a cluster of three for one of two and promise 7/7; a server
+    gone for longer is no part of the cluster."""
+    def settled(status: dict) -> bool:
+        wait = PLACEMENT_WAIT_PULSES * float(status["pulseSeconds"])
+        return all(gone >= wait
+                   for gone in status["silentDataNodes"].values())
+    status, waited, _ = _await_cluster(worker, settled)
+    return status["dataNodes"], waited
+
 
 class EcEncodeHandler(JobHandler):
     job_type = "erasure_coding"
@@ -292,6 +333,8 @@ class EcEncodeHandler(JobHandler):
                                urls: list[str], source: str,
                                base: str) -> dict:
         self._mark_readonly(urls, vid)
+        # the spread the job starts under is the one it places on
+        started = _servers_at_start(worker)
         worker.report_progress(job_id, 0.1, "marked readonly")
         self._pull_volume(worker, vid, collection, source, base)
         worker.report_progress(job_id, 0.3, "copied volume files")
@@ -318,19 +361,46 @@ class EcEncodeHandler(JobHandler):
 
         # 4+5. distribute + mount
         placement = self._distribute_and_mount(worker, vid, collection,
-                                               ctx, base)
+                                               ctx, base, started)
         worker.report_progress(job_id, 0.8, "distributed shards")
         return placement
 
+    @staticmethod
+    def _placement_targets(worker, started: list[str]
+                           ) -> "tuple[list[str], float]":
+        """(the servers to place on, seconds waited for them): those
+        the job started under, in the master's order, once the master
+        names every one of them alive.  A server it does not name is
+        waited for PLACEMENT_WAIT_PULSES of the master's pulses; then
+        the job fails, and its caller unwinds, rather than spread the
+        shards over fewer servers than the volume's placement was
+        promised."""
+        if not started:
+            raise RuntimeError("no alive volume servers")
+        status, waited, whole = _await_cluster(
+            worker, lambda st: set(started) <= set(st["dataNodes"]))
+        targets = [t for t in status["dataNodes"] if t in started]
+        if not whole:
+            gone = sorted(set(started) - set(targets))
+            raise RuntimeError(
+                f"the master names {len(targets)} of the {len(started)} "
+                f"servers the job started under ({', '.join(gone)} "
+                f"unheard for {waited:.1f}s, {PLACEMENT_WAIT_PULSES} "
+                f"pulses of {status['pulseSeconds']:g}s): not placing "
+                "on fewer")
+        return targets, waited
+
     def _distribute_and_mount(self, worker, vid: int, collection: str,
-                              ctx: ECContext, base: str) -> dict:
-        """Round-robin shard spread over alive servers (:532) + mount
-        (shard_distribution.go:209)."""
+                              ctx: ECContext, base: str,
+                              started: "tuple[list[str], float]") -> dict:
+        """Round-robin shard spread over the servers the job started
+        under (:532) + mount (shard_distribution.go:209)."""
         with tracing.span("ec.distribute", role="worker") as sp:
-            targets = master_json(worker.master, "GET", "/cluster/status",
-                                  timeout=30)["dataNodes"]
-            if not targets:
-                raise RuntimeError("no alive volume servers")
+            servers, waited_at_start = started
+            sp.set("serversAtStart", len(servers))
+            targets, waited = self._placement_targets(worker, servers)
+            if waited_at_start + waited > 0:
+                sp.set("waitSeconds", round(waited_at_start + waited, 3))
             placement: dict[str, list[int]] = {t: [] for t in targets}
             for sid in range(ctx.total):
                 placement[targets[sid % len(targets)]].append(sid)
@@ -372,9 +442,11 @@ class EcEncodeHandler(JobHandler):
             # per-volume progress throughout: a 64-volume batch takes
             # long enough that a silent job would trip the admin's
             # stall reaper and double-execute
+            started = None
             for i, vid in enumerate(vids):
                 vol_urls[vid] = self._lookup_urls(worker, vid)
                 self._mark_readonly(vol_urls[vid], vid)
+                started = started or _servers_at_start(worker)
                 self._pull_volume(worker, vid, collection,
                                   vol_urls[vid][0], bases[vid])
                 worker.report_progress(
@@ -402,7 +474,7 @@ class EcEncodeHandler(JobHandler):
 
             for i, vid in enumerate(vids):
                 self._distribute_and_mount(worker, vid, collection,
-                                           ctx, bases[vid])
+                                           ctx, bases[vid], started)
                 worker.report_progress(
                     job_id, 0.6 + 0.3 * (i + 1) / n,
                     f"distributed volume {vid} ({i + 1}/{n})")

@@ -110,12 +110,17 @@ class MasterServer:
         r("GET", "/cluster/watch", self._watch)
         self.grpc_server = None
         self.grpc_port = 0
+        self._clock_stop = threading.Event()
 
     # -- lifecycle --------------------------------------------------------
 
     def start(self):
         self.http.start()
         self.raft.start()
+        # the master's own clock: a few ticks a pulse, so the topology
+        # can tell a stall of this process (or its machine) from the
+        # servers' silence (Topology.tick)
+        threading.Thread(target=self._clock_loop, daemon=True).start()
         # gRPC wire plane (pb/grpc_client_server.go analog): optional —
         # JSON-HTTP stays the always-on surface
         try:
@@ -129,7 +134,12 @@ class MasterServer:
                   f"{e!r}")
         return self
 
+    def _clock_loop(self) -> None:
+        while not self._clock_stop.wait(self.topology.pulse_seconds / 4):
+            self.topology.tick()
+
     def stop(self):
+        self._clock_stop.set()
         if self.grpc_server is not None:
             self.grpc_server.stop(grace=0.5)
         self.raft.stop()
@@ -533,6 +543,11 @@ class MasterServer:
             "term": self.raft.term,
             "topologyId": self.raft.topology_id,
             "dataNodes": [n.url for n in nodes],
+            # servers let go of, with the seconds since, and the pulse
+            # the rule of three counts in: what a placement may wait on
+            "silentDataNodes": {u: round(s, 3) for u, s in
+                                self.topology.silent_nodes().items()},
+            "pulseSeconds": self.topology.pulse_seconds,
             "volumeSizeLimit": self.topology.volume_size_limit,
             # raft log view (shell cluster.raft.status; the reference's
             # RaftListClusterServers surface)

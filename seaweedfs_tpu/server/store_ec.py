@@ -18,6 +18,7 @@ from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
+from .. import stats, tracing
 from ..ops import rs_cpu, rs_native
 from ..storage import types
 from ..storage.erasure_coding import EcVolume
@@ -38,6 +39,21 @@ _TTL_ENOUGH = 7 * 60.0
 # under DEFAULT_BUCKETS' floor, WAN survivor fan-outs above it
 _DEGRADED_BUCKETS = (0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1,
                      0.25, 0.5, 1.0, 2.5, 5.0, 10.0)
+
+
+def _count_interval(source: str) -> None:
+    stats.PROCESS.counter_add(
+        "ec_read_intervals_total", 1.0,
+        help_text="needle intervals read through the EC path, by where "
+                  "the bytes came from: a shard here, a shard on "
+                  "another server, or reconstruction", source=source)
+
+
+def _observe_remote(seconds: float) -> None:
+    stats.PROCESS.histogram_observe(
+        "ec_remote_read_seconds", seconds, buckets=_DEGRADED_BUCKETS,
+        help_text="one interval fetched from another server's shard "
+                  "(GET /admin/ec/shard_read), failed tries included")
 
 
 def _degraded_enabled() -> bool:
@@ -88,16 +104,19 @@ class EcReader:
     # -- public -----------------------------------------------------------
 
     def read_needle(self, ev: EcVolume, needle_id: int,
-                    cookie: int | None = None) -> Needle:
+                    cookie: int | None = None,
+                    traced: bool = False) -> Needle:
         """store_ec.go:141 ReadEcShardNeedle: the local read path with
         this reader's scatter/reconstruct interval resolution.  The
         returned needle is tagged `was_degraded` when any interval
         reconstructed — the volume server's hot-cache promotion policy
-        (SEAWEEDFS_TPU_DEGRADED_PROMOTE) keys off it."""
+        (SEAWEEDFS_TPU_DEGRADED_PROMOTE) keys off it.  `traced`: the
+        request carries a trace parent, so somebody will read its
+        trace, and each interval leaves an `ec.read_interval` span."""
         degraded = [False]
         n = ev.read_needle_with(
             lambda iv: self._read_interval(ev, needle_id, iv,
-                                           degraded),
+                                           degraded, traced),
             needle_id, cookie=cookie)
         n.was_degraded = degraded[0]
         return n
@@ -105,24 +124,46 @@ class EcReader:
     # -- interval resolution ---------------------------------------------
 
     def _read_interval(self, ev: EcVolume, needle_id: int, iv,
-                       degraded: "list | None" = None) -> bytes:
+                       degraded: "list | None" = None,
+                       traced: bool = False) -> bytes:
+        """One interval from wherever it can be had, counted by where
+        that was (`ec_read_intervals_total{source}`); a span of it on
+        a traced request, or where it passed SEAWEEDFS_TPU_SLOW_MS."""
         sid, off = iv.to_shard_id_and_offset(
             LARGE_BLOCK_SIZE, SMALL_BLOCK_SIZE, ev.ctx.data_shards)
+        start, t0 = time.time(), time.perf_counter()
+        source, data = self._resolve_interval(ev, sid, off, iv.size,
+                                              degraded)
+        took = time.perf_counter() - t0
+        _count_interval(source)
+        slow = tracing.slow_ms()
+        if traced or (slow > 0 and took * 1e3 >= slow):
+            tracing.emit_span(
+                "ec.read_interval", start, took, attrs={
+                    "source": source, "shard": sid, "bytes": iv.size})
+        return data
+
+    def _resolve_interval(self, ev: EcVolume, sid: int, off: int,
+                          size: int, degraded: "list | None"
+                          ) -> "tuple[str, bytes]":
+        """(source, bytes): `local`, `remote` or `reconstructed`."""
         # 1. local
         shard = ev.shards.get(sid)
         if shard is not None:
             with ev.lock:
-                return shard.read_at(off, iv.size)
+                return "local", shard.read_at(off, size)
         # 2. remote direct
         locs = self._shard_locations(ev)
         for url in locs.get(sid, []):
-            data = self._remote_read(url, ev.id, sid, off, iv.size)
+            t0 = time.perf_counter()
+            data = self._remote_read(url, ev.id, sid, off, size)
+            if url != self.self_url:
+                _observe_remote(time.perf_counter() - t0)
             if data is not None:
-                return data
+                return "remote", data
         # 3. reconstruct from survivors — the DEGRADED read path: make
         # it countable (the SLO difference between "one dead peer" and
         # "every read pays a d-way fan-out" lives in this counter)
-        from .. import stats
         if not _degraded_enabled():
             raise NotFoundError(
                 f"volume {ev.id}: shard {sid} unreachable and degraded "
@@ -138,19 +179,20 @@ class EcReader:
         from .. import profiling
         profiling.flight_note(
             "ecDegraded", {"vid": ev.id, "shard": sid,
-                           "bytes": iv.size})
+                           "bytes": size})
         t0 = time.perf_counter()
         try:
             step = _degraded_stream_bytes()
-            if iv.size > step:
+            if size > step:
                 # large interval: decode-on-read in slice windows
                 # through the GF kernel — survivor fetch overlaps the
                 # matrix apply (arXiv:1908.01527 repair pipelining
                 # applied to the READ path), nothing is written to
                 # disk, and memory stays bounded at d x window
                 try:
-                    return self._recover_interval_streamed(
-                        ev, sid, off, iv.size, locs, step)
+                    return "reconstructed", \
+                        self._recover_interval_streamed(
+                            ev, sid, off, size, locs, step)
                 except _DeadlineExceeded:
                     raise   # budget verdict: re-planning cannot
                     # conjure time — surface the 504 now
@@ -159,7 +201,8 @@ class EcReader:
                     # failover: the one-shot path below re-plans from
                     # everything reachable rather than failing the read
                     pass
-            return self._recover_interval(ev, sid, off, iv.size)
+            return "reconstructed", \
+                self._recover_interval(ev, sid, off, size)
         finally:
             stats.PROCESS.histogram_observe(
                 "ec_degraded_read_seconds",
@@ -210,7 +253,6 @@ class EcReader:
         return None
 
     def _note_failover(self, url: str) -> None:
-        from .. import stats
         stats.PROCESS.counter_add(
             "ec_read_source_failovers_total", 1.0,
             help_text="EC reads that abandoned a shard source "

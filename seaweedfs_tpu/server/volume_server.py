@@ -21,12 +21,13 @@ import time
 import urllib.parse
 
 from ..util import wlog
-from .. import security
+from .. import security, tracing
 from ..storage import types
 from ..storage.erasure_coding import ECContext
 from ..storage.erasure_coding import ec_decoder, ec_encoder
 from ..storage.erasure_coding.ec_context import to_ext
 from ..storage.needle import Needle
+from ..stats import PROCESS
 from ..storage.store import Store
 from .httpd import FileSlice, HttpServer, Request, http_bytes, \
     http_download, http_json, is_admin_path
@@ -39,6 +40,17 @@ _check_path_fields = security.check_path_fields
 # field) — the read plane's registration math (read_plane.py), reused
 # when native WRITE-plane appends warm the read plane
 _WP_DATA_OFFSET = types.NEEDLE_HEADER_SIZE + 4
+
+
+def _count_heartbeat_error(e: BaseException) -> str:
+    """One more heartbeat that raised or did not reach the master,
+    under the exception's type; returns that label."""
+    kind = type(e).__name__
+    PROCESS.counter_add(
+        "volume_heartbeat_errors_total", 1.0,
+        help_text="heartbeats that raised or did not reach the master",
+        error=kind)
+    return kind
 
 
 class VolumeServer:
@@ -570,8 +582,12 @@ class VolumeServer:
             r = master_json(self.master, "POST", "/heartbeat", hb,
                             timeout=5,
                             headers=self.security.admin_headers())
-        except OSError:
-            return  # no leader reachable; retry next pulse
+        except OSError as e:
+            # no leader reachable, or none that answered in time: the
+            # next pulse tries again, and the miss is on the record
+            # (three in a row and the master holds this server dead)
+            _count_heartbeat_error(e)
+            return
         err = r.get("error")
         if err:
             # a rejected heartbeat (bad admin key, whitelist miss) means
@@ -591,8 +607,27 @@ class VolumeServer:
             self._topology_id = tid
 
     def _heartbeat_loop(self) -> None:
+        """One beat a pulse for as long as the server lives.  A beat
+        that raises is counted (`volume_heartbeat_errors_total{error}`),
+        said once per kind, and followed by the next: a thread that
+        ended here would leave the server dead at the master for good,
+        and every job after would place its shards without it."""
+        said: set[str] = set()
         while not self._hb_stop.wait(self.pulse_seconds):
-            self._heartbeat_once()
+            t0 = time.perf_counter()
+            try:
+                self._heartbeat_once()
+            except Exception as e:  # noqa: BLE001 — the loop's outer
+                # edge: whatever a beat raises, the next one is due
+                kind = _count_heartbeat_error(e)
+                if kind not in said:
+                    said.add(kind)
+                    wlog.error(f"volume server {self.url}: heartbeat "
+                               f"raised {kind}: {e}; beating on")
+            PROCESS.histogram_observe(
+                "volume_heartbeat_seconds", time.perf_counter() - t0,
+                help_text="one heartbeat: collecting the tables and "
+                          "the master's answer")
 
     # -- public data path -------------------------------------------------
 
@@ -704,9 +739,11 @@ class VolumeServer:
         else:
             token = self._nc_epoch    # BEFORE the store read
             try:
-                n = self.store.read_needle(fid.volume_id, fid.key,
-                                           cookie=fid.cookie,
-                                           ec_reader=self.ec_reader)
+                n = self.store.read_needle(
+                    fid.volume_id, fid.key, cookie=fid.cookie,
+                    ec_reader=self.ec_reader,
+                    traced=req is not None and
+                    bool(req.headers.get(tracing.HEADER)))
             except KeyError:
                 return 404, {"error": "not found"}
             except ValueError as e:

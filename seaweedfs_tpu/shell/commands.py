@@ -1520,6 +1520,12 @@ def _render_node_top(url: str, b: "dict[str, list]",
         - _counter_sum(b, "seaweedfs_tpu_qos_rejected_total")
     if rejected > 0:
         line += f"  qos-rejected={rejected:.0f}"
+    # a volume server whose heartbeats raise or miss the master is one
+    # the master is about to let go of: the count since it started
+    hb_errors = _counter_sum(
+        a, "seaweedfs_tpu_volume_heartbeat_errors_total")
+    if hb_errors > 0:
+        line += f"  heartbeat-errors={hb_errors:.0f}"
     out.append(line)
     kern = _gauge(a, "seaweedfs_tpu_device_kernel_last_ms",
                   {"kernel": "gf_apply_matrix"})

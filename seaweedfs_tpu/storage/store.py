@@ -110,6 +110,11 @@ class Store:
         self.locations = [DiskLocation(d, fsync=fsync)
                           for d in directories]
         self.lock = threading.RLock()
+        # guards the locations' tables alone, for as long as an entry
+        # takes to put in, take out or copy: `lock` is held through a
+        # mount's or a delete's file I/O, and a heartbeat must wait on
+        # neither (nor they on its dat_size() calls)
+        self._tables = threading.Lock()
         for loc in self.locations:
             loc.load_existing()
 
@@ -152,13 +157,15 @@ class Store:
                 replica_placement=ReplicaPlacement.from_string(replication),
                 ttl=read_ttl(ttl) if ttl else EMPTY_TTL,
                 mmap_read_mb=MMAP_READ_MB, fsync=loc.fsync)
-            loc.volumes[vid] = v
+            with self._tables:
+                loc.volumes[vid] = v
             return v
 
     def delete_volume(self, vid: int) -> None:
         with self.lock:
             for loc in self.locations:
-                v = loc.volumes.pop(vid, None)
+                with self._tables:
+                    v = loc.volumes.pop(vid, None)
                 if v is not None:
                     v.destroy()
                     return
@@ -167,7 +174,8 @@ class Store:
     def unmount_volume(self, vid: int) -> None:
         with self.lock:
             for loc in self.locations:
-                v = loc.volumes.pop(vid, None)
+                with self._tables:
+                    v = loc.volumes.pop(vid, None)
                 if v is not None:
                     v.close()
                     return
@@ -187,7 +195,8 @@ class Store:
                                collection=collection,
                                mmap_read_mb=MMAP_READ_MB,
                                fsync=loc.fsync)
-                    loc.volumes[vid] = v
+                    with self._tables:
+                        loc.volumes[vid] = v
                     return v
             raise KeyError(f"volume {vid} files not found")
 
@@ -214,17 +223,20 @@ class Store:
         return size, unchanged
 
     def read_needle(self, vid: int, needle_id: int,
-                    cookie: int | None = None, ec_reader=None) -> Needle:
+                    cookie: int | None = None, ec_reader=None,
+                    traced: bool = False) -> Needle:
         """store.go:604 ReadVolumeNeedle.  For EC volumes, `ec_reader`
         (server/store_ec.EcReader) enables scatter/degraded resolution;
-        without it only locally-complete needles are readable."""
+        without it only locally-complete needles are readable;
+        `traced` asks it for a span of each interval."""
         v = self.find_volume(vid)
         if v is not None:
             return v.read_needle(needle_id, cookie=cookie)
         ev = self.find_ec_volume(vid)
         if ev is not None:
             if ec_reader is not None:
-                return ec_reader.read_needle(ev, needle_id, cookie=cookie)
+                return ec_reader.read_needle(ev, needle_id, cookie=cookie,
+                                             traced=traced)
             return ev.read_needle_local(needle_id, cookie=cookie)
         raise KeyError(f"volume {vid} not found")
 
@@ -255,7 +267,8 @@ class Store:
                 if any(os.path.exists(base + to_ext(s))
                        for s in (shard_ids or range(32))):
                     ev = EcVolume(loc.directory, vid, collection=collection)
-                    loc.ec_volumes[vid] = ev
+                    with self._tables:
+                        loc.ec_volumes[vid] = ev
                     return ev
             raise KeyError(f"no local shards for volume {vid}")
 
@@ -276,32 +289,45 @@ class Store:
                 ev = loc.ec_volumes.get(vid)
                 if ev is None:
                     continue
-                if shard_ids is None:
-                    loc.ec_volumes.pop(vid).close()
-                    return
-                for sid in shard_ids:
-                    shard = ev.shards.pop(int(sid), None)
-                    if shard is not None:
-                        shard.close()
-                if not ev.shards:
-                    loc.ec_volumes.pop(vid).close()
+                if shard_ids is not None:
+                    for sid in shard_ids:
+                        shard = ev.shards.pop(int(sid), None)
+                        if shard is not None:
+                            shard.close()
+                if shard_ids is None or not ev.shards:
+                    with self._tables:
+                        del loc.ec_volumes[vid]
+                    ev.close()
                 return
 
     # -- heartbeat (store.go:371 CollectHeartbeat) ------------------------
 
     def collect_heartbeat(self) -> dict:
+        """What this server holds, for the master.  The tables are
+        copied under `_tables` and read from the copies, so a volume
+        may be mounted or deleted while this runs; one that goes away
+        between the copy and its reading is left out, as it would be a
+        pulse later."""
         volumes = []
         ec_shards = []
         max_volume_count = 0
         max_file_key = 0
-        for loc in self.locations:
-            max_volume_count += loc.max_volume_count
-            for vid, v in loc.volumes.items():
+        with self._tables:
+            tables = [(loc.max_volume_count, list(loc.volumes.items()),
+                       list(loc.ec_volumes.items()))
+                      for loc in self.locations]
+        for max_count, vols, ec_vols in tables:
+            max_volume_count += max_count
+            for vid, v in vols:
+                try:
+                    size = v.dat_size()
+                except (ValueError, OSError):
+                    continue    # closed under us: deleted or unmounted
                 max_file_key = max(max_file_key, v.max_file_key())
                 volumes.append({
                     "id": vid,
                     "collection": v.collection,
-                    "size": v.dat_size(),
+                    "size": size,
                     "fileCount": v.file_count(),
                     "deleteCount": v.deleted_count(),
                     "deletedByteCount": v.deleted_bytes(),
@@ -315,7 +341,7 @@ class Store:
                     # volume.tier.compact select tiered volumes
                     "remoteTiered": v.is_remote,
                 })
-            for vid, ev in loc.ec_volumes.items():
+            for vid, ev in ec_vols:
                 ec_shards.append({
                     "id": vid,
                     "collection": ev.collection,

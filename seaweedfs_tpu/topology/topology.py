@@ -15,7 +15,9 @@ import threading
 import time
 from dataclasses import dataclass, field
 
+from .. import stats
 from ..storage.replica_placement import ReplicaPlacement
+from ..util import wlog
 
 
 @dataclass
@@ -57,6 +59,9 @@ class DataNodeInfo:
     volumes: dict[int, VolumeInfo] = field(default_factory=dict)
     ec_shards: dict[int, EcShardInfo] = field(default_factory=dict)
     last_seen: float = 0.0
+    # what the last look at this node's liveness found; a change is
+    # counted (Topology._note_liveness)
+    held_alive: bool = True
 
     @property
     def volume_count(self) -> int:
@@ -79,6 +84,10 @@ class Topology:
         self._max_volume_id = 0
         import itertools
         self._pick_rr = itertools.count()
+        # when the master's own clock last ticked (MasterServer ticks a
+        # few times a pulse); None where nobody ticks, and liveness is
+        # then by the heartbeats' ages alone
+        self._ticked: float | None = None
 
     # -- heartbeat registration (topology.go RegisterVolumeLayout etc) ----
 
@@ -89,6 +98,12 @@ class Topology:
             if node is None:
                 node = DataNodeInfo(url=url)
                 self.nodes[url] = node
+            else:
+                # a node that fell silent and is back: both halves on
+                # the record, whether or not anyone asked meanwhile
+                self._note_liveness(
+                    node, node.last_seen >= self._liveness_deadline())
+                self._note_liveness(node, True)
             node.public_url = hb.get("publicUrl", url)
             node.data_center = hb.get("dataCenter") or node.data_center
             node.rack = hb.get("rack") or node.rack
@@ -118,17 +133,84 @@ class Topology:
             for vid in node.ec_shards:
                 self._max_volume_id = max(self._max_volume_id, vid)
 
+    def tick(self) -> None:
+        """The master's own clock, a few times a pulse."""
+        with self.lock:
+            self._forgive_own_stall()
+            self._ticked = time.monotonic()
+
+    def _forgive_own_stall(self) -> None:
+        """A master that did not run for a pulse or more (its process
+        or its whole machine stood still: 4.5-9 s when a TPU's owner
+        initialises beside it, PERF.md 7) heard no heartbeat meanwhile,
+        and the first look after it would find every server three
+        pulses unheard.  The time it did not listen is not held against
+        them: three pulses are three pulses of listening.  Called before
+        liveness is judged."""
+        with self.lock:     # re-entrant: the callers hold it already
+            if self._ticked is None:
+                return
+            now = time.monotonic()
+            gap = now - self._ticked
+            if gap <= self.pulse_seconds:
+                return
+            self._ticked = now
+            for n in self.nodes.values():
+                if n.last_seen > 0:
+                    n.last_seen = min(now, n.last_seen + gap)
+        stats.PROCESS.counter_add(
+            "master_own_stall_seconds_total", gap,
+            help_text="seconds the master itself did not run (a pulse "
+                      "or more at a time), not held against the "
+                      "volume servers' heartbeats")
+        wlog.warning("the master did not run for %.1fs: not held against "
+                     "%d volume servers", gap, len(self.nodes),
+                     component="topology")
+
     def _liveness_deadline(self) -> float:
         # heartbeat ages on the monotonic clock (SWFS011): an NTP step
         # backwards would otherwise declare the whole fleet dead, and
         # a step forward would immortalize nodes that stopped pulsing
+        self._forgive_own_stall()
         return time.monotonic() - 3 * self.pulse_seconds
 
+    def _note_liveness(self, node: DataNodeInfo, alive: bool) -> None:
+        """Counts a node's passing from alive to dead or back
+        (`master_node_transitions_total{to}`); called under `lock`."""
+        if alive != node.held_alive:
+            node.held_alive = alive
+            wlog.warning(
+                "volume server %s %s: last heard %.1fs ago (%.0fs pulse)",
+                node.url, "is back" if alive else "held dead",
+                time.monotonic() - node.last_seen, self.pulse_seconds,
+                component="topology")
+            stats.PROCESS.counter_add(
+                "master_node_transitions_total", 1.0,
+                help_text="volume servers the master let go of (three "
+                          "pulses unheard) or took back",
+                to="alive" if alive else "dead")
+
     def alive_nodes(self) -> list[DataNodeInfo]:
-        deadline = self._liveness_deadline()
         with self.lock:
-            return [n for n in self.nodes.values()
-                    if n.last_seen >= deadline]
+            deadline = self._liveness_deadline()
+            alive = []
+            for n in self.nodes.values():
+                self._note_liveness(n, n.last_seen >= deadline)
+                if n.held_alive:
+                    alive.append(n)
+            return alive
+
+    def silent_nodes(self) -> dict[str, float]:
+        """{url: seconds since the master let go of it} for every
+        server it has heard and no longer holds alive, the ones marked
+        dead on sight apart.  Whoever promises a placement (an EC job)
+        reads it to tell a cluster of two from a cluster of three a
+        beat of which is late."""
+        with self.lock:
+            deadline = self._liveness_deadline()
+            return {n.url: deadline - n.last_seen
+                    for n in self.nodes.values()
+                    if 0 < n.last_seen < deadline}
 
     def mark_dead(self, url: str) -> None:
         """Immediately expire a node observed unreachable (the analog of
@@ -188,8 +270,8 @@ class Topology:
         rp = ReplicaPlacement.from_string(replication or "000")
         want_copies = rp.copy_count()
         by_vid: dict[int, list[DataNodeInfo]] = {}
-        deadline = self._liveness_deadline()
         with self.lock:
+            deadline = self._liveness_deadline()
             for node in self.nodes.values():
                 if node.last_seen < deadline:
                     # a disconnected node's volumes leave the writable

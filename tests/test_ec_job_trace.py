@@ -133,7 +133,8 @@ def test_pull_and_pushes_carry_their_bytes(job):
         for p in glob.glob(os.path.join(d, "*" + ext)))
     assert sum(s["attrs"]["bytes"] for s in pushes) == on_disk
     dist = _one(spans, "ec.distribute")
-    assert dist["attrs"] == {"servers": 3, "bytes": on_disk}
+    assert dist["attrs"] == {"serversAtStart": 3, "servers": 3,
+                             "bytes": on_disk}
     exts = sorted(s["attrs"]["ext"] for s in pushes)
     assert exts == sorted([f".ec{i:02d}" for i in range(14)]
                           + [".ecx", ".vif"] * 3)
