@@ -72,7 +72,10 @@ def main(argv: list[str] | None = None) -> int:
     v.add_argument("-port", type=int, default=8080)
     v.add_argument("-dir", default=".", help="comma-separated data dirs")
     v.add_argument("-mserver", default="127.0.0.1:9333")
-    v.add_argument("-max", type=int, default=8)
+    v.add_argument("-max", type=int, default=64,
+                   help="volumes this server offers: 64 of this repo's "
+                        "1024 MiB default limit (upstream's 8 are of "
+                        "30,000 MB each)")
     v.add_argument("-dataCenter", default="")
     v.add_argument("-rack", default="")
     v.add_argument("-tierBackend", default="",

@@ -31,24 +31,10 @@ import numpy as np
 from . import gf256, rs_matrix
 
 
-def _windowed_wanted(flat: np.ndarray) -> bool:
-    """Take the windowed double-buffered staging path (ops.staging)?
-    Yes whenever windowing is enabled AND either the batch spans more
-    than one window (there is something to pipeline) or a device mesh
-    is up (mesh placement always rides the launch).  A one-window
-    single-device batch gains nothing from a staging thread, so it
-    keeps the legacy one-shot device_put."""
-    from . import staging
-    wb = staging.window_bytes()
-    if wb <= 0:
-        return False
-    _batch_sh, _repl_sh, ndev = staging.encode_shardings()
-    return ndev > 1 or flat.nbytes > wb
-
-
 def _staged_h2d(flat: np.ndarray) -> jax.Array:
-    """Stage a packed host buffer onto the default device and record
-    the h2d window (profiling.device_note).  Fencing policy matters:
+    """One-shot put of a packed host buffer on the default device, for
+    a caller with staging switched off (ops.staging window MB = 0);
+    records the h2d window (profiling.device_note).  Fencing policy:
     on the CPU backend device_put is effectively a synchronous copy,
     so blocking costs nothing and yields an honest window.  On async
     backends (TPU) a fence here would serialize the transfer against
@@ -211,6 +197,22 @@ class _PendingParity:
         return out
 
 
+def _launch_lazy(mat, data: np.ndarray, op: str, payload_bytes, run):
+    """Dispatch mat x data without waiting: through ops.staging, or
+    one-shot where staging is switched off."""
+    from . import staging
+    b = data.shape[1]
+    flat = pack_words(data)
+    if staging.window_bytes() > 0:
+        return staging.WindowedLaunch(
+            mat, flat, gf_apply_matrix_words, len(mat), b, op=op,
+            payload_bytes=payload_bytes, run=run)
+    dev = _staged_h2d(flat)
+    t_dispatch = time.perf_counter()
+    out32 = gf_apply_matrix_words(jnp.asarray(mat), dev)
+    return _PendingParity(out32, b, dispatched_at=t_dispatch)
+
+
 class ReedSolomonJax:
     """TPU encoder/decoder for RS(data, parity), API-compatible with the
     CPU twin (`rs_cpu.ReedSolomonCPU`)."""
@@ -243,8 +245,8 @@ class ReedSolomonJax:
         return gf_apply_matrix(self._parity_rows, data)
 
     def parity_lazy(self, data,
-                    payload_bytes: "int | None" = None
-                    ) -> "_PendingParity":
+                    payload_bytes: "int | None" = None,
+                    run=None) -> "_PendingParity":
         """Dispatch the parity launch WITHOUT waiting for the result.
 
         Returns a handle whose .materialize() blocks on the device and
@@ -258,55 +260,37 @@ class ReedSolomonJax:
         aliases host memory (CPU), the kernel has consumed the input by
         the time the output is fetchable.
 
-        The default path is the windowed double-buffered staging
-        pipeline (ops.staging): the batch is split into column
-        windows, a staging thread overlaps window N+1's h2d with
-        window N's kernel, and the handle additionally exposes
-        .windows() so the encode writer can push each parity window to
-        its shard sink while later windows are still in flight.
-        SEAWEEDFS_TPU_H2D_WINDOW_MB=0 restores the one-shot
+        The launch goes through ops.staging: a batch that is one
+        whole window (what the EC file pipeline hands over) is put on
+        the device as it stands, a wider one is cut into column
+        windows that a staging thread packs and puts while earlier
+        ones compute.  The handle exposes .windows() so the encode
+        writer can push each parity window to its shard sink as it
+        lands.  SEAWEEDFS_TPU_H2D_WINDOW_MB=0 restores the one-shot
         device_put.
 
         `payload_bytes`: how many of `data`'s bytes the caller counts
-        as its own (the encoder pads a short launch up to a compiled
-        shape); the staging ledger keeps them beside what it sent.
+        as its own (the encoder sends a short tail in the full
+        window's shape); the staging ledger keeps them beside what it
+        sent.  `run`: the staging.Run of the encode this launch is
+        part of, for the overlap.
         """
         data = self._check(data, self.data_shards)
-        b = data.shape[1]
-        flat = pack_words(np.ascontiguousarray(data))
-        if _windowed_wanted(flat):
-            from . import staging
-            return staging.WindowedLaunch(
-                self._parity_rows, flat, gf_apply_matrix_words,
-                self.parity_shards, b, payload_bytes=payload_bytes)
-        dev = _staged_h2d(flat)
-        t_dispatch = time.perf_counter()
-        out32 = gf_apply_matrix_words(self._parity_rows, dev)
-        return _PendingParity(out32, b, dispatched_at=t_dispatch)
+        return _launch_lazy(self._parity_rows, data, "encode",
+                            payload_bytes, run)
 
     def apply_matrix(self, mat, data) -> np.ndarray:
         """out[r] = XOR_k mat[r,k] * data[k] — public generic apply
         (numpy in, numpy out via the host word-packing fast path)."""
         return gf_apply_matrix(jnp.asarray(mat, dtype=jnp.uint8), data)
 
-    def apply_matrix_lazy(self, mat, data) -> "_PendingParity":
+    def apply_matrix_lazy(self, mat, data, run=None
+                          ) -> "_PendingParity":
         """Async generic apply: dispatch without waiting (same contract
         as parity_lazy) so a staged pipeline can overlap D2H of launch k
-        with H2D+kernel of k+1; windowed/mesh-staged exactly like
-        parity_lazy."""
-        data = np.ascontiguousarray(data)
-        b = data.shape[1]
-        flat = pack_words(data)
-        if _windowed_wanted(flat):
-            from . import staging
-            return staging.WindowedLaunch(
-                np.asarray(mat, dtype=np.uint8), flat,
-                gf_apply_matrix_words, len(mat), b, op="rebuild")
-        dev = _staged_h2d(flat)
-        t_dispatch = time.perf_counter()
-        out32 = gf_apply_matrix_words(
-            jnp.asarray(mat, dtype=jnp.uint8), dev)
-        return _PendingParity(out32, b, dispatched_at=t_dispatch)
+        with H2D+kernel of k+1; staged exactly like parity_lazy."""
+        return _launch_lazy(np.asarray(mat, dtype=np.uint8), data,
+                            "rebuild", None, run)
 
     def encode(self, shards) -> jax.Array:
         """shards: [total, B] with data rows filled; returns full array with

@@ -1,31 +1,34 @@
-"""Windowed, double-buffered host->device staging for the encode path
-(ROADMAP item 2: the end-to-end multi-chip TPU encode).
+"""Windowed host->device staging for the encode path (ROADMAP item 2:
+the end-to-end multi-chip TPU encode).
 
-A one-shot ``device_put`` of the whole batch *serializes* the h2d
-plane against the kernel: nothing computes while bytes move, nothing
-moves while the kernel runs.  This module replaces that with a staging
-pipeline in which three planes run concurrently:
+A one-shot ``device_put`` of a whole volume *serializes* the h2d plane
+against the kernel: nothing computes while bytes move, nothing moves
+while the kernel runs.  Here three planes run concurrently:
 
-    host buffer N+1 --copy+device_put--> device   (staging thread)
-    device window N --kernel----------> parity    (async dispatch)
-    device window N-1 --fetch---------> sinks     (consumer thread)
+    host window N+1 --device_put--> device        (staging thread)
+    device window N --kernel------> parity        (async dispatch)
+    device window N-1 --fetch-----> sinks         (consumer thread)
 
-* The batch ([K, W] packed uint32 words — 4 GF bytes per word, see
-  ops.rs_jax) is split into COLUMN windows of ~``h2d window MB``
-  staged bytes.  GF constant-matrix apply is byte-column-independent,
-  so window boundaries never change an output byte.
-* A dedicated staging thread copies each window into a REUSED host
-  staging buffer (module-level pool — the copy target is stable,
-  warm memory, never a fresh multi-MB allocation per window), issues
-  ``jax.device_put`` and fences ONLY ITSELF (``block_until_ready`` on
-  the staging thread yields an honest per-window h2d wall without
-  stalling dispatch or fetch), then dispatches the kernel for that
-  window — so window N+1's transfer overlaps window N's kernel.
-* In-flight windows are bounded by a semaphore (default 2 = classic
-  double buffering); each window's staging buffer is released back to
-  the pool only after that window's OUTPUT is on the host — the
-  aliasing-safe recycle point on backends where ``device_put`` may
-  alias host memory (CPU).
+* The unit is a WINDOW of ~``h2d window MB`` staged bytes of a [K, W]
+  batch of packed uint32 words (4 GF bytes per word, see ops.rs_jax).
+  GF constant-matrix apply is byte-column-independent, so window
+  boundaries never change an output byte.
+* A batch that is one whole C-contiguous window is put ON THE DEVICE AS
+  IT STANDS (a direct window): the EC file pipeline reads each work
+  item straight into such a buffer (ec_encoder._encode_work_items), so
+  an encode copies nothing on the host between the read and the put.
+  A wider batch (a direct caller, the mesh path's wide batches) is cut
+  into column windows, each packed (``np.copyto``) into a reused pool
+  buffer first.  What the stager sees decides, nothing else.
+* The staging thread issues ``jax.device_put``, fences ONLY ITSELF (an
+  honest per-window h2d wall without stalling dispatch or fetch) and
+  dispatches the kernel for that window.
+* Windows in flight: inside a cut launch a semaphore bounds them;
+  across direct launches the CALLER's recycled buffers do
+  (ec_encoder._staged_run sizes its pool from ``inflight_depth()``).
+  Either buffer is reused only after its window's OUTPUT is on the
+  host — the aliasing-safe recycle point on backends where
+  ``device_put`` may alias host memory (CPU).
 * With more than one visible device the window is placed with
   ``NamedSharding(Mesh(jax.devices(), ("batch",)),
   PartitionSpec(None, "batch"))`` — the packed-words batch axis is
@@ -34,25 +37,29 @@ pipeline in which three planes run concurrently:
   ``SEAWEEDFS_TPU_ENCODE_MESH=0``) falls back to plain placement.
 
 Knobs:
-  SEAWEEDFS_TPU_H2D_WINDOW_MB   staged bytes per window (default 32;
-                                0 disables windowing -> legacy
+  SEAWEEDFS_TPU_H2D_WINDOW_MB   staged bytes per window, and so per
+                                work item of a device encode (default
+                                32; 0 disables staging -> legacy
                                 one-shot device_put)
   SEAWEEDFS_TPU_H2D_INFLIGHT    staged windows in flight (default 2)
   SEAWEEDFS_TPU_ENCODE_MESH     1/0 force mesh sharding on/off
                                 (default: on when >1 device)
 
 Telemetry: per-window ``device_note``/``kernel_note`` (profiling.py)
-plus a per-launch overlap fraction — 0 when the three planes ran
-serially, 1 when the wall equals the slowest single plane — surfaced
-as the ``device_h2d_overlap_fraction`` gauge (cluster.top) and a
-process-wide aggregate snapshot() the benchmark takes deltas of.
-Per window, two trace spans under the span that was current when the
-launch began (tracing.py, one batch when the launch ends):
-``stage.h2d`` (pack + put + fence) and ``stage.d2h`` (the fetch).  The
-ledger splits the h2d seconds into the host pack and the rest, counts
-payload beside padded bytes, and says which side of the hand-off
-waited: the stager on a slot (the consumer is slower) or the consumer
-on a ready window (the stager is slower).
+plus an overlap fraction per RUN (the launches of one encode or
+rebuild; a launch made alone is its own run) — 0 when the h2d and the
+fetch plane ran serially, 1 when the wall from the first put to the
+last fetch equals the slower plane alone — surfaced as the
+``device_h2d_overlap_fraction`` gauge (cluster.top) and a process-wide
+aggregate snapshot() the benchmark takes deltas of.  Per window, two
+trace spans under the span that was current when the launch began
+(tracing.py, one batch when the launch ends): ``stage.h2d`` (pack +
+put + fence) and ``stage.d2h`` (the fetch).  The ledger splits the h2d
+seconds into the host pack (microseconds for a direct window) and the
+rest, counts direct beside all windows and payload beside padded
+bytes, and says which side of the hand-off waited: the stager on a
+slot (the consumer is slower) or the consumer on a ready window (the
+stager is slower).
 """
 
 from __future__ import annotations
@@ -175,9 +182,10 @@ def _give_buf(buf: np.ndarray) -> None:
 
 class StagingStats:
     """One launch's staging ledger (a launch = one parity_lazy /
-    apply_matrix_lazy batch)."""
+    apply_matrix_lazy batch), or one Run's for the overlap."""
 
-    __slots__ = ("windows", "h2d_bytes", "h2d_seconds", "d2h_bytes",
+    __slots__ = ("windows", "direct_windows", "h2d_bytes",
+                 "h2d_seconds", "d2h_bytes",
                  "d2h_seconds", "start", "end", "overlap_fraction",
                  "overlap_numer", "overlap_denom", "payload_bytes",
                  "pack_seconds", "slot_wait_seconds",
@@ -185,10 +193,12 @@ class StagingStats:
 
     def __init__(self):
         self.windows = 0
+        self.direct_windows = 0    # put as the caller filled them
         self.h2d_bytes = 0         # what was sent, padding included
         self.payload_bytes = 0     # the part of it that was asked for
         self.h2d_seconds = 0.0     # pack + put + fence
-        self.pack_seconds = 0.0    # the host np.copyto alone
+        self.pack_seconds = 0.0    # of it, getting a buffer to put:
+        # the np.copyto of a packed window, a glance for a direct one
         self.slot_wait_seconds = 0.0    # stager blocked on a slot
         self.ready_wait_seconds = 0.0   # consumer blocked on a window
         self.d2h_bytes = 0
@@ -205,7 +215,7 @@ class StagingStats:
         async backends offer is the host-side fetch) ran strictly
         serially (wall == sum of both), 1 = fully overlapped (wall ==
         the slower plane alone).  numer/denom are kept so the process
-        aggregate can weight launches without re-deriving the math."""
+        aggregate can weight runs without re-deriving the math."""
         wall = self.end - self.start
         busy = self.h2d_seconds + self.d2h_seconds
         headroom = busy - max(self.h2d_seconds, self.d2h_seconds)
@@ -219,7 +229,8 @@ class StagingStats:
 
 
 _agg_lock = threading.Lock()
-_agg = {"launches": 0, "windows": 0, "h2d_bytes": 0,
+_agg = {"launches": 0, "windows": 0, "direct_windows": 0,
+        "h2d_bytes": 0,
         "h2d_seconds": 0.0, "d2h_bytes": 0, "d2h_seconds": 0.0,
         "overlap_numer": 0.0, "overlap_denom": 0.0,
         "payload_bytes": 0, "pack_seconds": 0.0,
@@ -233,20 +244,59 @@ def reset_aggregate() -> None:
 
 
 def _note_launch(s: StagingStats) -> None:
-    """Fold one finish()ed launch into the process aggregate (the
-    overlap numer/denom come from finish() — one definition)."""
+    """Fold one consumed launch into the process aggregate; its share
+    of the overlap comes with its run (_close_run)."""
     with _agg_lock:
         _agg["launches"] += 1
         for key in _agg:
-            if key != "launches":
+            if key not in ("launches", "overlap_numer", "overlap_denom"):
                 _agg[key] += getattr(s, key)
 
 
+def _close_run(s: StagingStats, op: str) -> None:
+    """finish() a run's ledger (one definition of the overlap), show
+    it on the gauge and weigh it into the process aggregate."""
+    from .. import profiling
+    s.finish()
+    profiling.overlap_note(s.overlap_fraction, s.windows, op=op)
+    with _agg_lock:
+        _agg["overlap_numer"] += s.overlap_numer
+        _agg["overlap_denom"] += s.overlap_denom
+
+
+class Run:
+    """The launches of one encode or rebuild, for the overlap: a
+    launch of one window overlaps nothing INSIDE itself, launch k+1's
+    put overlaps launch k's fetch.  The pipeline that makes the
+    launches hands its Run to every *_lazy call and closes it; the
+    overlap is the run's wall, first put to last fetch, against its
+    summed h2d and d2h seconds.  Launches join as their one consumer
+    drains them; `inflight` is how many the pipeline may have staged
+    between its reader and its writer."""
+
+    def __init__(self, op: str = "encode"):
+        self.op = op
+        self.inflight = inflight_depth()
+        self.stats = StagingStats()
+
+    def add(self, s: StagingStats) -> None:
+        r = self.stats
+        r.start = min(r.start, s.start) if r.windows else s.start
+        r.end = max(r.end, s.end)
+        r.windows += s.windows
+        r.h2d_seconds += s.h2d_seconds
+        r.d2h_seconds += s.d2h_seconds
+
+    def close(self) -> None:
+        if self.stats.windows:
+            _close_run(self.stats, self.op)
+
+
 def snapshot() -> dict:
-    """Process-wide aggregate across every windowed launch since the
+    """Process-wide aggregate across every staged launch since the
     last reset_aggregate() — what the bench records next to the e2e
-    number (windows staged, achieved staged-h2d GB/s, byte-weighted
-    overlap fraction)."""
+    number (windows staged and how many of them direct, achieved
+    staged-h2d GB/s, overlap fraction weighted over the runs)."""
     with _agg_lock:
         a = dict(_agg)
     a["h2d_gbps"] = round(
@@ -305,10 +355,17 @@ class _Stager:
                         raise _StagingError()
                 self.stats.slot_wait_seconds += \
                     time.perf_counter() - t_wait
-                buf = _take_buf((k, npad))
                 wall0 = time.time()
                 t0 = time.perf_counter()
-                np.copyto(buf[:, :n], self.flat[:, w0:w0 + n])
+                # a batch that is one whole window goes as it stands;
+                # the caller keeps it until the launch is consumed
+                direct = n == npad == self.flat.shape[1] and \
+                    self.flat.flags.c_contiguous
+                if direct:
+                    buf = self.flat
+                else:
+                    buf = _take_buf((k, npad))
+                    np.copyto(buf[:, :n], self.flat[:, w0:w0 + n])
                 t_pack = time.perf_counter() - t0
                 # pad columns (mesh divisibility) are left dirty on
                 # purpose: the GF apply is column-independent and the
@@ -320,6 +377,7 @@ class _Stager:
                 dev.block_until_ready()
                 dt = time.perf_counter() - t0
                 self.stats.windows += 1
+                self.stats.direct_windows += direct
                 self.stats.h2d_bytes += buf.nbytes
                 self.stats.h2d_seconds += dt
                 self.stats.pack_seconds += t_pack
@@ -327,7 +385,8 @@ class _Stager:
                 profiling.device_note("h2d", buf.nbytes, dt)
                 t_dispatch = time.perf_counter()
                 out = self.kernel(self.mat, dev)
-                self.ready.put((w0, n, out, buf, t_dispatch))
+                self.ready.put((w0, n, out, None if direct else buf,
+                                t_dispatch))
         except _StagingError:
             pass
         except BaseException as e:  # noqa: BLE001 — re-raised by the
@@ -337,8 +396,8 @@ class _Stager:
 
 
 class WindowedLaunch:
-    """One double-buffered staged kernel launch over a [K, W] packed
-    batch.
+    """One staged kernel launch over a [K, W] packed batch: one
+    direct window, or the batch cut into double-buffered windows.
 
     ``kernel(mat_dev, window_dev) -> out32`` is dispatched per window
     by the staging thread as soon as that window's transfer fences, so
@@ -353,7 +412,8 @@ class WindowedLaunch:
 
     def __init__(self, mat, flat32: np.ndarray, kernel, out_rows: int,
                  nbytes: int, op: str = "encode",
-                 payload_bytes: "int | None" = None):
+                 payload_bytes: "int | None" = None,
+                 run: "Run | None" = None):
         import weakref
 
         from .. import tracing
@@ -362,6 +422,7 @@ class WindowedLaunch:
         self._rows = out_rows
         self._nbytes = nbytes
         self._op = op  # telemetry label: "encode" vs "rebuild"
+        self._run = run  # whose overlap this launch counts in
         self._consumed = False
         # the launch's spans hang under the span current NOW, on the
         # caller's thread: the stager and the consumer run elsewhere
@@ -374,9 +435,9 @@ class WindowedLaunch:
             mat = jax.device_put(np.asarray(mat), repl_sh)
         self._s = _Stager(mat, flat32, kernel, batch_sh)
         # payload: what the caller says of the batch is volume bytes
-        # (the encoder pads a short launch up to a compiled shape and
-        # knows how much of it it read); without that, the batch less
-        # its word and mesh padding
+        # (the encoder sends a short tail in the full window's shape
+        # and knows how much of it it read); without that, the batch
+        # less its word and mesh padding
         self._s.stats.payload_bytes = k * nbytes \
             if payload_bytes is None else payload_bytes
         # dropped-handle backstop: stop the stager when the handle is
@@ -416,7 +477,8 @@ class WindowedLaunch:
                 host = np.asarray(out)  # the backend's only fence:
                 # waits out any kernel remainder + the d2h transfer
                 dt = time.perf_counter() - t0
-                _give_buf(buf)
+                if buf is not None:     # a pool buffer, not the caller's
+                    _give_buf(buf)
                 s.slots.release()
                 s.stats.d2h_bytes += host.nbytes
                 s.stats.d2h_seconds += dt
@@ -431,10 +493,11 @@ class WindowedLaunch:
             if s.errors:
                 raise s.errors[0]
             s.stats.end = time.perf_counter()
-            s.stats.finish()
-            profiling.overlap_note(s.stats.overlap_fraction,
-                                   s.stats.windows, op=self._op)
             _note_launch(s.stats)
+            if self._run is not None:
+                self._run.add(s.stats)
+            else:               # made alone: a run of its own
+                _close_run(s.stats, self._op)
         finally:
             s.stop.set()
             self._emit_spans(d2h_windows)

@@ -1404,14 +1404,14 @@ def device_note(direction: str, nbytes: int,
 
 def overlap_note(fraction: float, windows: int,
                  op: str = "encode") -> None:
-    """Record one windowed staging launch's h2d/d2h overlap fraction
-    (ops.staging: 0 = the staging and consume planes ran serially,
-    1 = the wall equalled the slower plane alone) plus the window
-    count — the figure that says whether the double-buffered pipeline
-    actually pipelined."""
+    """Record the h2d/d2h overlap fraction of one staging run — the
+    launches of one encode or rebuild (ops.staging.Run: 0 = the
+    staging and consume planes ran serially, 1 = the wall equalled
+    the slower plane alone) — plus its window count: the figure that
+    says whether the staged pipeline actually pipelined."""
     m = _process_metrics()
     m.gauge_set("device_h2d_overlap_fraction", fraction,
-                help_text="last windowed launch's h2d/d2h overlap "
+                help_text="last encode's or rebuild's h2d/d2h overlap "
                           "fraction (0 serial .. 1 fully overlapped)",
                 op=op)
     m.counter_add("device_staged_windows_total", float(windows),

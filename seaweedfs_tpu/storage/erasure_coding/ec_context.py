@@ -23,8 +23,10 @@ SMALL_BLOCK_SIZE = 1024 * 1024          # 1MB
 
 # Batch bytes per encode step (the Go path uses 256KB,
 # ec_encoder.go:61-67; any batch that divides the block size yields
-# byte-identical shard files, so the TPU path uses far larger batches
-# to amortize dispatch: geometry is preserved either way).
+# byte-identical shard files: geometry is preserved either way).  The
+# host codecs step by CPU_BATCH_SIZE.  A device codec steps by what one
+# staging window holds (ECContext.batch_size / rows_per_launch);
+# TPU_BATCH_SIZE is what parallel/ec_batch.py's own loop sends a launch.
 CPU_BATCH_SIZE = 1024 * 1024
 TPU_BATCH_SIZE = 64 * 1024 * 1024
 
@@ -261,22 +263,39 @@ class ECContext:
         from ...ops.rs_cpu import ReedSolomonCPU
         return ReedSolomonCPU(self.data_shards, self.parity_shards)
 
+    def _window_per_shard(self) -> int:
+        """Bytes of one shard row that fit one staging window
+        (ops.staging: what is put on the device in one piece)."""
+        from ...ops import staging
+        wb = staging.window_bytes() or \
+            int(staging.DEFAULT_WINDOW_MB * (1 << 20))
+        return max(1, wb // self.data_shards)
+
     def batch_size(self, block_size: int) -> int:
-        pref = TPU_BATCH_SIZE if self.backend == "jax" else CPU_BATCH_SIZE
-        return min(pref, block_size)
+        """Bytes per shard of one codec step WITHIN a block.  Host
+        codecs: CPU_BATCH_SIZE.  Device codec: the largest chunk that
+        divides the block and fits one staging window, so a step is
+        one window, put as the reader filled it."""
+        if self.backend != "jax":
+            return min(CPU_BATCH_SIZE, block_size)
+        n = -(-block_size // self._window_per_shard())
+        while block_size % n:
+            n += 1
+        return block_size // n
 
     def rows_per_launch(self, block_size: int) -> int:
         """How many independent stripe rows to stack into one codec
         launch.  Rows are independent — shard i's file is the in-order
-        concatenation of every row's block i — so stacking R rows on the
-        batch axis yields byte-identical output while amortizing device
-        dispatch over R*data_shards*block_size input bytes.  This is
-        what lets the 1MB small-block tail geometry
-        (ec_encoder.go:304-319) feed the TPU in 64MB launches instead
-        of one blocking round-trip per 1MB block (the round-2 3,000x
-        end-to-end collapse)."""
-        pref = TPU_BATCH_SIZE if self.backend == "jax" else CPU_BATCH_SIZE
-        return max(1, pref // block_size)
+        concatenation of every row's block i — so stacking R rows on
+        the batch axis yields byte-identical output.  A device codec
+        gets as many whole rows as one staging window holds (3 of the
+        1MB rows for RS(10,4), 5 for RS(6,3)): the launch IS the
+        window, one compiled shape per scheme whatever the volume's
+        size, and never one blocking round-trip per 1MB block (the
+        round-2 3,000x end-to-end collapse)."""
+        if self.backend != "jax":
+            return max(1, CPU_BATCH_SIZE // block_size)
+        return max(1, self._window_per_shard() // block_size)
 
     def __str__(self) -> str:
         return f"{self.data_shards}+{self.parity_shards}"

@@ -6,8 +6,10 @@ data_shards blocks (1GB rows first, then 1MB rows for the tail, zero-
 padded past EOF), parity blocks are computed per row, and each block is
 appended to its shard file.  The file geometry is identical to the
 reference for ANY batch size that divides the block size — the Go path
-encodes in 256KB batches (ec_encoder.go:61), the TPU path uses 64MB
-batches to amortize device dispatch; outputs are byte-identical.
+encodes in 256KB batches (ec_encoder.go:61); the device path's batch is
+one staging window (ops.staging, 32MB staged by default: 3 rows of
+RS(10,4)), read straight into the buffer that is put on the device;
+outputs are byte-identical.
 
 Rebuild regenerates missing shards from >= data_shards survivors in
 1MB steps (ec_encoder.go:323 rebuildEcFiles).
@@ -58,10 +60,6 @@ def write_ec_files(base_file_name: str, ctx: ECContext | None = None,
     _generate_ec_files(base_file_name, ctx, progress=progress)
 
 
-def _next_pow2(n: int) -> int:
-    return 1 << (n - 1).bit_length()
-
-
 def _encode_work_items(dat_size: int, ctx: ECContext
                        ) -> "list[tuple[int, int, int, int, int]]":
     """The exact batch schedule of ec_encoder.go:280 encodeDatFile
@@ -73,10 +71,12 @@ def _encode_work_items(dat_size: int, ctx: ECContext
       d strided block slices at batch_offset;
     - small rows (1MB blocks) are AGGREGATED: one item covers
       real_rows consecutive rows read contiguously and stacked on the
-      batch axis (batch_bytes = padded_rows * block_size per shard).
-      batch_bytes is padded up to a power-of-two row count so the
-      whole volume compiles to a handful of device kernel shapes; the
-      writer emits only real_rows * block_size bytes per shard.
+      batch axis.  batch_bytes is the same for every item, the tail's
+      too (ctx.rows_per_launch rows: for a device codec what one
+      staging window holds, so every volume of a scheme runs one
+      compiled shape); a tail of fewer rows leaves its last columns
+      dirty and the writer emits only real_rows * block_size bytes
+      per shard.
 
     Either way the shard files are byte-identical to the reference:
     shard i's file is the in-order concatenation of row blocks i, and
@@ -97,9 +97,8 @@ def _encode_work_items(dat_size: int, ctx: ECContext
     r_full = ctx.rows_per_launch(SMALL_BLOCK_SIZE)
     while rows_left > 0:
         g = min(r_full, rows_left)
-        padded = min(r_full, _next_pow2(g))
         work.append((processed, SMALL_BLOCK_SIZE, 0,
-                     padded * SMALL_BLOCK_SIZE, g))
+                     r_full * SMALL_BLOCK_SIZE, g))
         rows_left -= g
         processed += g * small_row
     return work
@@ -220,7 +219,7 @@ class _OverlappedFlusher:
             _os.fdatasync(f.fileno())
 
 
-def _staged_run(work, read_item, compute, write_item) -> None:
+def _staged_run(work, read_item, compute, write_item, run=None) -> None:
     """Triple-buffered staging pipeline (SURVEY §7 "hard parts" #2),
     shared by encode and rebuild: a reader thread stages disk batches
     into host buffers, the calling thread runs the GF kernel (device
@@ -237,8 +236,11 @@ def _staged_run(work, read_item, compute, write_item) -> None:
     consumed the buffer once its output is fetchable).
     write_item(payload, result) -> None: append to the output files.
 
-    Host memory is bounded by a pool of 3 recycled buffers (one per
-    stage — read/compute/write), so peak RSS stays ~3x one batch
+    Host memory is bounded by a pool of recycled buffers: one being
+    read, one being written and between them 1 in a host codec, or in
+    a device codec the staged windows in flight of `run` (_device_run:
+    a device batch is one window, so this pool is what bounds them;
+    the run is closed here), so peak RSS stays a few batches
     instead of growing with queue depth.  A shared stop event unblocks
     every stage on any error or interrupt: a parked producer can never
     deadlock the join, and a writer failure (ENOSPC) aborts the read +
@@ -250,7 +252,7 @@ def _staged_run(work, read_item, compute, write_item) -> None:
     q_read: "queue.Queue" = queue.Queue()
     q_write: "queue.Queue" = queue.Queue()
     pool: "queue.Queue" = queue.Queue()
-    for _ in range(3):
+    for _ in range((run.inflight if run else 1) + 2):
         pool.put(None)  # lazy-allocated buffer slots
     stop = threading.Event()
     errors: list[BaseException] = []
@@ -324,8 +326,20 @@ def _staged_run(work, read_item, compute, write_item) -> None:
                 break
             if item is not None and hasattr(item[1], "abort"):
                 item[1].abort()
+        if run is not None:
+            run.close()
     if errors:
         raise errors[0]
+
+
+def _device_run(lazy, op: str):
+    """The staging.Run that a device codec's launches of one pipeline
+    count their overlap in, and that says how many of them may be in
+    flight; None for a host codec."""
+    if lazy is None:
+        return None
+    from ...ops import staging
+    return staging.Run(op)
 
 
 def _generate_ec_files(base_file_name: str, ctx: ECContext,
@@ -398,12 +412,14 @@ def _generate_ec_files(base_file_name: str, ctx: ECContext,
         return (buf, real, from_dat)
 
     lazy = getattr(codec, "parity_lazy", None)
+    run = _device_run(lazy, "encode")
 
     def compute(payload):
         buf, _real, from_dat = payload
         if lazy is not None:
-            # async dispatch; writer materializes
-            return lazy(buf, payload_bytes=from_dat)
+            # async dispatch of the buffer as the reader filled it;
+            # the writer materializes
+            return lazy(buf, payload_bytes=from_dat, run=run)
         return np.ascontiguousarray(np.asarray(codec.parity(buf)))
 
     written = 0  # volume bytes whose d+p shard slices reached the sinks
@@ -418,26 +434,25 @@ def _generate_ec_files(base_file_name: str, ctx: ECContext,
         for i in range(d):
             sinks[i].write(buf[i, :real].data)
         if hasattr(parity, "windows"):
-            # windowed staged launch (ops.staging): push each parity
-            # window to its shard sink AS IT LANDS, so the d2h fetch
-            # of window k and the scatter-sink sends overlap the h2d
-            # staging of windows k+1, k+2...  Always drain fully —
-            # a partial drain would recycle staging buffers the
-            # stager thread is still copying from.
+            # staged launch (ops.staging), one window when the batch
+            # is this pipeline's own: push each parity window to its
+            # shard sink AS IT LANDS, so the d2h fetch of window k and
+            # the scatter-sink sends overlap the put of the next
+            # batches.  Always drain fully — a partial drain would
+            # recycle a buffer the stager thread still reads.
             for w0, chunk in parity.windows():
                 n = min(chunk.shape[1], real - w0)
                 if n <= 0:
-                    continue  # device-shape padding beyond `real`
+                    continue  # a wider batch's windows beyond `real`
                 for j in range(ctx.total - d):
                     sinks[d + j].write(chunk[j, :n].data)
-                # per window, not per launch: a 64MB launch behind a
-                # cold compile is the longest silence a job has, and
-                # the admin presumes a silent worker dead
+                # per window: a launch behind a cold compile is the
+                # longest silence a job has, and the admin presumes a
+                # silent worker dead
                 note(written + d * (w0 + n))
         else:
             if hasattr(parity, "materialize"):
-                # legacy one-shot lazy handle (windowing disabled, or
-                # a single-device batch inside one window):
+                # legacy one-shot lazy handle (staging switched off):
                 # accepts_lazy means _staged_run no longer
                 # materializes for us
                 parity = parity.materialize()
@@ -464,7 +479,7 @@ def _generate_ec_files(base_file_name: str, ctx: ECContext,
         [s.file for s in sinks if hasattr(s, "file")])
     ok = False
     try:
-        _staged_run(work, read_item, compute, write_item)
+        _staged_run(work, read_item, compute, write_item, run)
         for s in sinks:
             s.end_stream()   # all tail chunks + receiver responses
         for s in sinks:      # drain concurrently, then verify each
@@ -678,11 +693,12 @@ def rebuild_from_sources(base_file_name: str, ctx: ECContext,
         return (buf, n)
 
     lazy = getattr(codec, "apply_matrix_lazy", None)
+    run = _device_run(lazy, "rebuild")
 
     def compute(payload):
         buf, _n = payload
         if lazy is not None:
-            return lazy(rec_matrix, buf)
+            return lazy(rec_matrix, buf, run=run)
         return np.ascontiguousarray(
             np.asarray(codec.apply_matrix(rec_matrix, buf)))
 
@@ -703,7 +719,7 @@ def rebuild_from_sources(base_file_name: str, ctx: ECContext,
     flusher = _OverlappedFlusher(outputs.values())
     ok = False
     try:
-        _staged_run(work, read_item, compute, write_item)
+        _staged_run(work, read_item, compute, write_item, run)
         ok = True
     finally:
         try:

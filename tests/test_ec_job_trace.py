@@ -364,14 +364,15 @@ def small_windows(monkeypatch):
 
 def test_payload_bytes_are_the_volumes_and_pack_is_part_of_h2d(
         small_windows, tmp_path):
-    """A padded launch: 13 small rows are stacked into a launch of 16
-    (the compiled shape), and the last row is short."""
+    """A short tail: 13 small rows at 3 a window are 5 work items, the
+    last of one row sent in the window's shape, and the last row is
+    short."""
     from seaweedfs_tpu.storage.erasure_coding import (ec_context,
                                                       ec_encoder)
     from seaweedfs_tpu.storage.erasure_coding.ec_context import ECContext
     small_windows.setattr(ec_encoder, "SMALL_BLOCK_SIZE", 4096)
     small_windows.setattr(ec_context, "SMALL_BLOCK_SIZE", 4096)
-    small_windows.setattr(ec_context, "TPU_BATCH_SIZE", 16 * 4096)
+    small_windows.setenv("SEAWEEDFS_TPU_H2D_WINDOW_MB", "0.125")
     dat_size = 12 * 10 * 4096 + 12_345
     (tmp_path / "v.dat").write_bytes(np.random.default_rng(3).integers(
         0, 256, dat_size, dtype=np.uint8).tobytes())
@@ -380,9 +381,9 @@ def test_payload_bytes_are_the_volumes_and_pack_is_part_of_h2d(
                                   ECContext(backend="jax"))
     snap = staging.snapshot()
     assert snap["payload_bytes"] == dat_size
-    assert snap["h2d_bytes"] == 16 * 10 * 4096 > dat_size
+    assert snap["h2d_bytes"] == 15 * 10 * 4096 > dat_size
     assert 0 < snap["pack_seconds"] <= snap["h2d_seconds"]
-    assert snap["windows"] > 2
+    assert snap["launches"] == snap["windows"] == 5
 
 
 def _launch(data, kernel=gf_apply_matrix_words):

@@ -240,7 +240,11 @@ def test_encode_work_items_tile_exactly(backend):
     with no gap and no overlap, and the writer emits exactly the ceil
     geometry (n_large*1GB + ceil(tail/row)*1MB per shard).  Fuzzed
     over sizes straddling the 1GB-row and 1MB-row boundaries; pure
-    index arithmetic, no bytes are allocated."""
+    index arithmetic, no bytes are allocated.  The device codec's
+    items are one staging window each: every batch of small rows has
+    the one shape, the tail's too, and a chunk of a large row divides
+    the block and fits the window."""
+    from seaweedfs_tpu.ops import staging
     from seaweedfs_tpu.storage.erasure_coding.ec_context import (
         LARGE_BLOCK_SIZE, SMALL_BLOCK_SIZE)
     from seaweedfs_tpu.storage.erasure_coding.ec_encoder import (
@@ -286,8 +290,12 @@ def test_encode_work_items_tile_exactly(backend):
                     assert block == SMALL_BLOCK_SIZE and b0 == 0
                     assert batch % block == 0  # whole padded rows
                     assert real_rows * block <= batch
+                    assert batch == ctx.rows_per_launch(block) * block
                     got += [(row_start + r * small_row + shard * block,
                              block) for r in range(real_rows)]
+                if backend == "jax":    # one window, never wider
+                    assert d * batch <= staging.window_bytes()
+                    assert block % batch == 0 or batch % block == 0
             assert _merge_intervals(got) == _merge_intervals(expect), \
                 f"dat_size={dat_size} shard={shard}"
         # writer geometry: per-shard output bytes == ceil geometry
@@ -350,8 +358,8 @@ def test_row_aggregated_encode_byte_identical(tmp_path, patched_blocks,
     fix) must produce byte-identical shard files to encoding one row
     per launch — the shard-file layout is the in-order concatenation of
     row blocks either way.  Covers: a large row, a run of aggregated
-    small rows, a non-power-of-two tail group, and zero-padding past
-    EOF inside the final row."""
+    small rows, a tail group of fewer rows than a launch holds, and
+    zero-padding past EOF inside the final row."""
     d_agg = tmp_path / "agg"
     d_one = tmp_path / "one"
     d_agg.mkdir()

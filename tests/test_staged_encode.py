@@ -178,10 +178,12 @@ def test_generate_ec_files_windowed_byte_identical(knobs, tmp_path,
                                                       ec_encoder)
     from seaweedfs_tpu.storage.erasure_coding.ec_context import ECContext
 
-    # shrink geometry: 4KB "small rows", 16KB device batches
+    # shrink geometry: 4KB "small rows"; the knobs' 2KB window holds
+    # no whole row, so a work item is one row, wider than a window:
+    # every launch is cut and packed
     monkeypatch.setattr(ec_encoder, "SMALL_BLOCK_SIZE", 4096)
     monkeypatch.setattr(ec_context, "SMALL_BLOCK_SIZE", 4096)
-    monkeypatch.setattr(ec_context, "TPU_BATCH_SIZE", 16384)
+    staging.reset_aggregate()
 
     blob = np.random.default_rng(7).integers(
         0, 256, 200_001, dtype=np.uint8).tobytes()
@@ -196,16 +198,20 @@ def test_generate_ec_files_windowed_byte_identical(knobs, tmp_path,
         a = (tmp_path / f"j.ec{i:02d}").read_bytes()
         b = (tmp_path / f"c.ec{i:02d}").read_bytes()
         assert a == b, f"shard {i} differs under windowed staging"
+    snap = staging.snapshot()
+    assert snap["launches"] == 5            # 200,001 B: 5 rows of 40KB
+    assert snap["windows"] > 5 * 10 and snap["direct_windows"] == 0
 
 
 @pytest.mark.parametrize("window_mb", ["0", "64"])
 def test_generate_ec_files_one_shot_fallback(tmp_path, monkeypatch,
                                              window_mb):
-    """Review regression: with windowing disabled ("0") or a
-    single-device batch that fits inside one window ("64"), the codec
+    """Review regression: with staging switched off ("0") the codec
     hands the pipeline the LEGACY _PendingParity handle — the
     accepts_lazy writer must materialize it itself instead of
-    subscripting the handle (TypeError at the parity write)."""
+    subscripting the handle (TypeError at the parity write).  With a
+    window that holds the whole toy volume ("64") the pipeline's one
+    work item is one window, staged as it stands."""
     from seaweedfs_tpu.storage.erasure_coding import (ec_context,
                                                       ec_encoder)
     from seaweedfs_tpu.storage.erasure_coding.ec_context import ECContext
@@ -214,7 +220,7 @@ def test_generate_ec_files_one_shot_fallback(tmp_path, monkeypatch,
     monkeypatch.setenv("SEAWEEDFS_TPU_H2D_WINDOW_MB", window_mb)
     monkeypatch.setattr(ec_encoder, "SMALL_BLOCK_SIZE", 4096)
     monkeypatch.setattr(ec_context, "SMALL_BLOCK_SIZE", 4096)
-    monkeypatch.setattr(ec_context, "TPU_BATCH_SIZE", 16384)
+    staging.reset_aggregate()
     blob = np.random.default_rng(8).integers(
         0, 256, 60_000, dtype=np.uint8).tobytes()
     for kind in ("j", "c"):
@@ -227,6 +233,9 @@ def test_generate_ec_files_one_shot_fallback(tmp_path, monkeypatch,
     for i in range(D + P):
         assert (tmp_path / f"j.ec{i:02d}").read_bytes() == \
             (tmp_path / f"c.ec{i:02d}").read_bytes(), f"shard {i}"
+    snap = staging.snapshot()
+    assert snap["windows"] == snap["direct_windows"] == \
+        (1 if window_mb == "64" else 0)
 
 
 # -- bench: predictive roofline stays honest ------------------------------
