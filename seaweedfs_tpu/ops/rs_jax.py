@@ -22,37 +22,12 @@ every reconstruction pattern of the same shape.
 
 from __future__ import annotations
 
-import time
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import gf256, rs_matrix
+from . import gf256, rs_matrix, staging
 
-
-def _staged_h2d(flat: np.ndarray) -> jax.Array:
-    """One-shot put of a packed host buffer on the default device, for
-    a caller with staging switched off (ops.staging window MB = 0);
-    records the h2d window (profiling.device_note).  Fencing policy:
-    on the CPU backend device_put is effectively a synchronous copy,
-    so blocking costs nothing and yields an honest window.  On async
-    backends (TPU) a fence here would serialize the transfer against
-    the compute thread's next-window prep — exactly the overlap the
-    lazy-parity pipeline exists to provide — so there we record bytes
-    only and let the transfer wall fold into the dispatch->fetch
-    kernel window that _PendingParity.materialize times (the host-side
-    fetch is the only fence that backend offers anyway)."""
-    from .. import profiling
-    t0 = time.perf_counter()
-    dev = jax.device_put(flat)
-    if jax.default_backend() == "cpu":
-        dev.block_until_ready()
-        profiling.device_note("h2d", flat.nbytes,
-                              time.perf_counter() - t0)
-    else:
-        profiling.device_note("h2d", flat.nbytes, None)
-    return dev
 
 def _expand_tables(mat: jax.Array) -> jax.Array:
     """[R, K] constant matrix -> [R, K, 8] per-bit multiply tables.
@@ -166,51 +141,12 @@ def gf_apply_matrix(mat, data) -> jax.Array:
     return out.reshape((mat.shape[0],) + batch_shape)
 
 
-class _PendingParity:
-    """An in-flight device parity launch (see ReedSolomonJax.parity_lazy)."""
-
-    def __init__(self, out32: jax.Array, nbytes: int,
-                 dispatched_at: float = 0.0):
-        self._out32 = out32
-        self._nbytes = nbytes
-        self._dispatched_at = dispatched_at
-
-    def materialize(self) -> np.ndarray:
-        """Block until the launch completes; returns uint8 [R, B].
-
-        Device telemetry (profiling.py): the fetch wall is the d2h
-        staging window the pipeline's writer thread actually waits on
-        (it includes any remaining kernel time — the only fence this
-        backend offers is the host-side fetch), and dispatch->fetch
-        is the per-launch window `cluster.top` shows as
-        device_kernel_last_ms."""
-        import time as _time
-        from .. import profiling
-        t0 = _time.perf_counter()
-        host = np.asarray(self._out32)
-        fetch = _time.perf_counter() - t0
-        out = unpack_words(host, self._nbytes)
-        profiling.device_note("d2h", host.nbytes, fetch)
-        if self._dispatched_at:
-            profiling.kernel_note(
-                "gf_apply_matrix", t0 + fetch - self._dispatched_at)
-        return out
-
-
 def _launch_lazy(mat, data: np.ndarray, op: str, payload_bytes, run):
-    """Dispatch mat x data without waiting: through ops.staging, or
-    one-shot where staging is switched off."""
-    from . import staging
-    b = data.shape[1]
-    flat = pack_words(data)
-    if staging.window_bytes() > 0:
-        return staging.WindowedLaunch(
-            mat, flat, gf_apply_matrix_words, len(mat), b, op=op,
-            payload_bytes=payload_bytes, run=run)
-    dev = _staged_h2d(flat)
-    t_dispatch = time.perf_counter()
-    out32 = gf_apply_matrix_words(jnp.asarray(mat), dev)
-    return _PendingParity(out32, b, dispatched_at=t_dispatch)
+    """Dispatch mat x data without waiting (ops.staging): the packed
+    batch is put on the device whole, whatever its size."""
+    return staging.WindowedLaunch(
+        mat, pack_words(data), gf_apply_matrix_words, len(mat),
+        data.shape[1], op=op, payload_bytes=payload_bytes, run=run)
 
 
 class ReedSolomonJax:
@@ -246,28 +182,24 @@ class ReedSolomonJax:
 
     def parity_lazy(self, data,
                     payload_bytes: "int | None" = None,
-                    run=None) -> "_PendingParity":
+                    run=None) -> "staging.WindowedLaunch":
         """Dispatch the parity launch WITHOUT waiting for the result.
 
         Returns a handle whose .materialize() blocks on the device and
         yields the [parity_shards, B] uint8 numpy array.  This lets a
         pipeline overlap the D2H fetch of launch k with the H2D+kernel
         of launch k+1 (the encode staging pipeline materializes in its
-        writer thread while the compute thread dispatches ahead).
+        writer thread while the compute stage puts ahead).
 
         Aliasing contract: `data` may be a recycled buffer, but only
-        AFTER materialize() returns — on backends where jnp.asarray
+        AFTER materialize() returns — on backends where device_put
         aliases host memory (CPU), the kernel has consumed the input by
         the time the output is fetchable.
 
-        The launch goes through ops.staging: a batch that is one
-        whole window (what the EC file pipeline hands over) is put on
-        the device as it stands, a wider one is cut into column
-        windows that a staging thread packs and puts while earlier
-        ones compute.  The handle exposes .windows() so the encode
-        writer can push each parity window to its shard sink as it
-        lands.  SEAWEEDFS_TPU_H2D_WINDOW_MB=0 restores the one-shot
-        device_put.
+        The batch is put on the device whole, as parity() has always
+        done: how big a device work item is, is the caller's to decide
+        (the EC file pipeline hands over one staging window,
+        ECContext.rows_per_launch).
 
         `payload_bytes`: how many of `data`'s bytes the caller counts
         as its own (the encoder sends a short tail in the full
@@ -285,10 +217,10 @@ class ReedSolomonJax:
         return gf_apply_matrix(jnp.asarray(mat, dtype=jnp.uint8), data)
 
     def apply_matrix_lazy(self, mat, data, run=None
-                          ) -> "_PendingParity":
+                          ) -> "staging.WindowedLaunch":
         """Async generic apply: dispatch without waiting (same contract
-        as parity_lazy) so a staged pipeline can overlap D2H of launch k
-        with H2D+kernel of k+1; staged exactly like parity_lazy."""
+        as parity_lazy, the batch put whole) so a staged pipeline can
+        overlap D2H of launch k with H2D+kernel of k+1."""
         return _launch_lazy(np.asarray(mat, dtype=np.uint8), data,
                             "rebuild", None, run)
 

@@ -43,8 +43,7 @@ questions read from:
 
 4. Prometheus-text helpers (`parse_prom_text`, `prom_histogram`,
    `histogram_quantile`) and `merge_folded` — the client half of the
-   plane, shared by `weed shell cluster.top` / `cluster.profile` and
-   `bench.py write_path`.
+   plane, used by `weed shell cluster.top` / `cluster.profile`.
 
 5. Cost attribution (ISSUE 15): every `stage()` window additionally
    samples `time.thread_time_ns()` at its boundaries, so each stage
@@ -1378,19 +1377,13 @@ def _process_metrics():
     return stats.PROCESS
 
 
-def device_note(direction: str, nbytes: int,
-                seconds: "float | None") -> None:
+def device_note(direction: str, nbytes: int, seconds: float) -> None:
     """Record one host<->device staging window (direction "h2d" or
-    "d2h"): cumulative bytes, a latency histogram, and a last-window
-    throughput gauge — the number ROADMAP item 2's double-buffered
-    staging work will watch.  seconds=None records bytes only: an
-    async backend's enqueue wall is not a transfer wall, and a bogus
-    gauge is worse than none (rs_jax._staged_h2d's fencing policy)."""
+    "d2h", `seconds` a fenced wall: ops.staging): cumulative bytes, a
+    latency histogram, and a last-window throughput gauge."""
     m = _process_metrics()
     m.counter_add("device_transfer_bytes_total", float(nbytes),
                   help_text="host<->device staging bytes", dir=direction)
-    if seconds is None:
-        return
     m.histogram_observe("device_transfer_seconds", seconds,
                         help_text="host<->device staging window "
                                   "latency", dir=direction)
@@ -1496,8 +1489,8 @@ def _unescape_label(v: str) -> str:
 def parse_prom_text(text: str) -> "dict[str, list]":
     """Parse Prometheus exposition text into
     {metric_name: [(labels_dict, value), ...]} — the client half of
-    stats.Metrics.render, for cluster.top and bench.py write_path to
-    read any node's /metrics without a dependency."""
+    stats.Metrics.render, for cluster.top to read any node's
+    /metrics without a dependency."""
     out: dict[str, list] = {}
     for line in text.splitlines():
         line = line.strip()

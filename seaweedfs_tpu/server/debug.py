@@ -184,10 +184,10 @@ def _attr_post(req: Request):
     disarms only the ISSUE 15 additions (CPU clocks, flight
     recorder); "drain" disarms only the ISSUE 18 native-plane
     flight-record drain (records keep accumulating C-side and age
-    off the ring).  Also the lever behind bench.py's within-cluster
-    overhead A/Bs: separate clusters cannot resolve a ~1% cost under
-    arm-to-arm boot noise, alternating armed/disarmed traffic
-    windows on ONE cluster can."""
+    off the ring).  Also the lever for a within-cluster overhead A/B:
+    separate clusters cannot resolve a ~1% cost under arm-to-arm boot
+    noise, alternating armed/disarmed traffic windows on ONE cluster
+    can."""
     from .. import profiling
     b = req.json()
     if "disarmed" not in b:

@@ -731,7 +731,7 @@ class FilerServer:
         # operation.py (on the limiter pool threads, via use_track),
         # the metadata commit by filer.write_file — together they say
         # whether a slow filer write sat in master assigns, volume
-        # round-trips, or the store (bench.py write_path reads these)
+        # round-trips, or the store
         with profiling.track("write", role="filer",
                              metrics=self.metrics):
             with profiling.stage("recv"):

@@ -833,9 +833,9 @@ class VolumeServer:
         # write-path latency decomposition (profiling.py): the track
         # covers this handler; recv/index/append/flush/replicate stage
         # cells land in write_stage_seconds{stage} plus sibling trace
-        # spans, so both `bench.py write_path` and `trace.show` can
-        # say WHERE a slow write spent its time (the 50x ROADMAP gap
-        # is unlocatable without this, arXiv:1709.05365 §5)
+        # spans, so `trace.show` can say WHERE a slow write spent its
+        # time (the 50x ROADMAP gap is unlocatable without this,
+        # arXiv:1709.05365 §5)
         from .. import profiling
         with profiling.track("write", role="volume",
                              metrics=self.metrics):

@@ -131,8 +131,7 @@ def cmd_ec_encode(env: CommandEnv, args: list[str]) -> str:
     the source's disks and no balance re-copy round follows (the 1.4x
     source write amplification collapses to the sidecars, ~0.07x).
     `-mode=local` keeps the seed shape — generate all shards on the
-    source, mount, then balance-move them off — and is the A/B
-    baseline bench.py measures against
+    source, mount, then balance-move them off — the A/B baseline
     (SEAWEEDFS_TPU_EC_ENCODE_MODE overrides the default)."""
     env.confirm_is_locked()
     opts = _parse_flags(args)
@@ -541,7 +540,7 @@ def cmd_ec_rebuild(env: CommandEnv, args: list[str]) -> str:
     slice windows straight into the GF pipeline (no whole-shard
     pre-copies).  `-mode=copy` keeps the legacy collect-then-rebuild
     (every remote survivor pulled in full via /admin/ec/copy first) —
-    the A/B baseline bench.py measures against."""
+    the A/B baseline."""
     env.confirm_is_locked()
     opts = _parse_flags(args)
     import os as _os

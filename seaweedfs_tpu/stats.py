@@ -30,7 +30,7 @@ GROUP_COMMIT_WAIT_BUCKETS = (0.0001, 0.00025, 0.0005, 0.001, 0.0025,
 # filer meta-plane sub-stages (filer/meta_plane.py): serialize and
 # barrier live in the 50us..25ms band; the async apply's per-event
 # share sits near the bottom of it.  Mean = sum/count is the number
-# bench.py's meta sub-stage split reports per arm.
+# to read per sub-stage.
 META_SUB_BUCKETS = (0.00005, 0.0001, 0.00025, 0.0005, 0.001, 0.0025,
                     0.005, 0.01, 0.025, 0.05, 0.1, 0.25)
 

@@ -52,10 +52,10 @@ def _cpu_engine() -> str:
 # A TPU is held by the first process that initialises a JAX backend on
 # it; a second one hangs in libtpu or drops to JAX-on-CPU without
 # raising.  So exactly one process per host declares ownership with
-# own_device() — the `worker` command, chip_smoke.py's device child,
-# `bench.py --measure tpu` — and every other role (master, volume,
-# filer and its pre-fork siblings, s3, admin, shell, mq, webdav ...)
-# resolves ECContext() to the host engine without importing jax.
+# own_device() — the `worker` command, chip_smoke.py's device child —
+# and every other role (master, volume, filer and its pre-fork
+# siblings, s3, admin, shell, mq, webdav ...) resolves ECContext() to
+# the host engine without importing jax.
 
 class DeviceUnavailable(RuntimeError):
     """JAX found no accelerator where one was required."""
@@ -267,9 +267,7 @@ class ECContext:
         """Bytes of one shard row that fit one staging window
         (ops.staging: what is put on the device in one piece)."""
         from ...ops import staging
-        wb = staging.window_bytes() or \
-            int(staging.DEFAULT_WINDOW_MB * (1 << 20))
-        return max(1, wb // self.data_shards)
+        return max(1, staging.WINDOW_BYTES // self.data_shards)
 
     def batch_size(self, block_size: int) -> int:
         """Bytes per shard of one codec step WITHIN a block.  Host

@@ -7,8 +7,8 @@ padded past EOF), parity blocks are computed per row, and each block is
 appended to its shard file.  The file geometry is identical to the
 reference for ANY batch size that divides the block size — the Go path
 encodes in 256KB batches (ec_encoder.go:61); the device path's batch is
-one staging window (ops.staging, 32MB staged by default: 3 rows of
-RS(10,4)), read straight into the buffer that is put on the device;
+one staging window (ops.staging, 32MB staged: 3 rows of RS(10,4)),
+read straight into the buffer that is put on the device;
 outputs are byte-identical.
 
 Rebuild regenerates missing shards from >= data_shards survivors in
@@ -119,9 +119,6 @@ class _StageTimer:
     def __init__(self, fn):
         import time as _time
         self._fn = fn
-        # forwarded so _staged_run still sees a lazy-capable writer
-        # through the timing wrap
-        self.accepts_lazy = getattr(fn, "accepts_lazy", False)
         self._clock = _time.perf_counter
         self._wall = _time.time
         self.start_wall = 0.0
@@ -143,21 +140,17 @@ class _StageTimer:
             self.last = t1
             self.calls += 1
 
-    def emit(self, name: str, trace_ctx, busy: bool = True,
-             **attrs) -> None:
+    def emit(self, name: str, trace_ctx, **attrs) -> None:
         """Record the stage window as a trace span parented to the
         span active when the rebuild started (`trace_ctx` from
         tracing.current_ids() — stages ran on other threads, so the
-        contextvar cannot be relied on here).  `busy=False` leaves
-        busySeconds out: a codec stage that only DISPATCHES to a
-        device (the lazy launch) is busy for microseconds, and the
-        number would read as a kernel time."""
+        contextvar cannot be relied on here).  A device codec stage's
+        busySeconds are its puts (ops.staging); the kernel runs behind
+        them and is fetched in the write stage."""
         if not self.calls:
             return
         from ... import tracing
-        attrs.update(calls=self.calls)
-        if busy:
-            attrs.update(busySeconds=round(self.busy, 6))
+        attrs.update(calls=self.calls, busySeconds=round(self.busy, 6))
         tracing.emit_span(
             name, self.start_wall, self.last - self.first,
             role=trace_ctx[2] if trace_ctx else "",
@@ -229,12 +222,13 @@ def _staged_run(work, read_item, compute, write_item, run=None) -> None:
 
     read_item(item, buf) -> payload: fill (or replace) the recycled
     buffer; the payload's FIRST element must be the buffer to recycle.
-    compute(payload) -> result: may return a lazy handle exposing
-    .materialize() (async device dispatch; the writer materializes, so
-    D2H of launch k overlaps H2D+kernel of k+1 — materializing before
-    the recycle is also the aliasing contract of *_lazy: the kernel has
-    consumed the buffer once its output is fetchable).
-    write_item(payload, result) -> None: append to the output files.
+    compute(payload) -> result: a host array, or a device launch
+    (ops.staging: put and dispatched here, not waited for).
+    write_item(payload, result) -> None: append to the output files;
+    it takes the result through _on_host, so D2H of launch k overlaps
+    H2D+kernel of k+1, and the buffer is recycled only after it
+    returns — the aliasing contract of *_lazy: the kernel has consumed
+    the buffer once its output is on the host.
 
     Host memory is bounded by a pool of recycled buffers: one being
     read, one being written and between them 1 in a host codec, or in
@@ -286,12 +280,8 @@ def _staged_run(work, read_item, compute, write_item, run=None) -> None:
                 item = _blocking(q_write.get)
                 if item is None:
                     return
-                payload, result = item
-                if hasattr(result, "materialize") and \
-                        not getattr(write_item, "accepts_lazy", False):
-                    result = result.materialize()
-                write_item(payload, result)
-                pool.put(payload[0])  # recycle the slot for the reader
+                write_item(*item)
+                pool.put(item[0][0])  # recycle the slot for the reader
         except _Stopped:
             pass
         except BaseException as e:  # noqa: BLE001
@@ -316,20 +306,17 @@ def _staged_run(work, read_item, compute, write_item, run=None) -> None:
         q_write.put(None)
         rt.join()
         wt.join()
-        # unwind path: compute results still queued were never
-        # materialized — a staged device launch (ops.staging) parked
-        # there must stop its stager thread NOW, not wait for GC
-        while True:
-            try:
-                item = q_write.get_nowait()
-            except queue.Empty:
-                break
-            if item is not None and hasattr(item[1], "abort"):
-                item[1].abort()
         if run is not None:
             run.close()
     if errors:
         raise errors[0]
+
+
+def _on_host(result) -> np.ndarray:
+    """A codec stage's result as a host array: a device launch is
+    fetched here, on the caller's thread; a host codec's is there."""
+    return result.materialize() if hasattr(result, "materialize") \
+        else result
 
 
 def _device_run(lazy, op: str):
@@ -417,8 +404,8 @@ def _generate_ec_files(base_file_name: str, ctx: ECContext,
     def compute(payload):
         buf, _real, from_dat = payload
         if lazy is not None:
-            # async dispatch of the buffer as the reader filled it;
-            # the writer materializes
+            # put and dispatched as the reader filled it, not waited
+            # for: the writer fetches
             return lazy(buf, payload_bytes=from_dat, run=run)
         return np.ascontiguousarray(np.asarray(codec.parity(buf)))
 
@@ -433,35 +420,14 @@ def _generate_ec_files(base_file_name: str, ctx: ECContext,
         buf, real, _from_dat = payload
         for i in range(d):
             sinks[i].write(buf[i, :real].data)
-        if hasattr(parity, "windows"):
-            # staged launch (ops.staging), one window when the batch
-            # is this pipeline's own: push each parity window to its
-            # shard sink AS IT LANDS, so the d2h fetch of window k and
-            # the scatter-sink sends overlap the put of the next
-            # batches.  Always drain fully — a partial drain would
-            # recycle a buffer the stager thread still reads.
-            for w0, chunk in parity.windows():
-                n = min(chunk.shape[1], real - w0)
-                if n <= 0:
-                    continue  # a wider batch's windows beyond `real`
-                for j in range(ctx.total - d):
-                    sinks[d + j].write(chunk[j, :n].data)
-                # per window: a launch behind a cold compile is the
-                # longest silence a job has, and the admin presumes a
-                # silent worker dead
-                note(written + d * (w0 + n))
-        else:
-            if hasattr(parity, "materialize"):
-                # legacy one-shot lazy handle (staging switched off):
-                # accepts_lazy means _staged_run no longer
-                # materializes for us
-                parity = parity.materialize()
-            for j in range(ctx.total - d):
-                sinks[d + j].write(parity[j, :real].data)
+        # the data rows first: a device launch's fetch runs under them
+        parity = _on_host(parity)
+        for j in range(ctx.total - d):
+            sinks[d + j].write(parity[j, :real].data)
         written += d * real
+        # per work item: a launch behind a cold compile is the longest
+        # silence a job has, and the admin presumes a silent worker dead
         note(written)
-
-    write_item.accepts_lazy = True
 
     # stage spans (tracing.py): capture the caller's span context NOW
     # — the reader/writer stages run on pipeline threads where the
@@ -507,9 +473,8 @@ def _generate_ec_files(base_file_name: str, ctx: ECContext,
             by_dest = stats.snapshot()[0]
             read_item.emit("encode.read", trace_ctx,
                            datBytes=dat_size, windows=len(work))
-            compute.emit("encode.codec", trace_ctx, busy=lazy is None,
-                         dataShards=d, parityShards=ctx.total - d,
-                         backend=ctx.backend)
+            compute.emit("encode.codec", trace_ctx, dataShards=d,
+                         parityShards=ctx.total - d, backend=ctx.backend)
             write_item.emit("encode.write", trace_ctx,
                             bytesByDest=by_dest, aborted=not ok)
 
@@ -704,6 +669,7 @@ def rebuild_from_sources(base_file_name: str, ctx: ECContext,
 
     def write_item(payload, rec):
         _buf, n = payload
+        rec = _on_host(rec)
         for row, sid in enumerate(missing):
             outputs[sid].write(rec[row, :n].data)
 
@@ -741,7 +707,7 @@ def rebuild_from_sources(base_file_name: str, ctx: ECContext,
             read_item.emit("rebuild.fetch", trace_ctx,
                            bytesBySource=by_source,
                            windows=len(work), sliceBytes=step)
-            compute.emit("rebuild.codec", trace_ctx, busy=lazy is None,
+            compute.emit("rebuild.codec", trace_ctx,
                          missingShards=list(missing),
                          dataShards=ctx.data_shards)
             write_item.emit("rebuild.write", trace_ctx,

@@ -48,7 +48,7 @@ Observability: every flushed batch lands
 `group_commit_batch_size{site}` (histogram — mean batch = sum/count)
 and every writer's barrier wait lands
 `group_commit_wait_seconds{site}` in stats.PROCESS, rendered by
-`cluster.top` and read by `bench.py write_path`.
+`cluster.top`.
 """
 
 from __future__ import annotations

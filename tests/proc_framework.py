@@ -11,6 +11,7 @@ does and murders processes the way hardware does."""
 
 from __future__ import annotations
 
+import itertools
 import os
 import signal
 import socket
@@ -79,16 +80,43 @@ mtls = true
 }
 
 
+_ports = itertools.count()
+
+
 def free_port() -> int:
-    with socket.socket() as s:
-        s.bind(("127.0.0.1", 0))
-        return s.getsockname()[1]
+    """A port for a role that binds it LATER, in another process,
+    seconds from now — so never one of the kernel's own range
+    (/proc/sys/net/ipv4/ip_local_port_range, 32768 up): a port probed
+    there with bind(0) and let go is handed to the next bind(0) of any
+    process on the machine (every in-process test server of the other
+    xdist workers), the role then dies of EADDRINUSE and wait_port()
+    is answered by the stranger.  Each worker walks a block of its
+    own below that range; the bind is the check that nothing else
+    holds the port now."""
+    worker = os.environ.get("PYTEST_XDIST_WORKER", "gw0")[2:]
+    base = 10000 + 1000 * (int(worker or 0) % 20)
+    for _ in range(1000):
+        port = base + next(_ports) % 1000
+        with socket.socket() as s:
+            try:
+                s.bind(("127.0.0.1", port))
+            except OSError:
+                continue
+            return port
+    raise RuntimeError(f"no free port in {base}..{base + 999}")
 
 
-def wait_port(port: int, timeout: float = 45.0) -> None:
-    """Startup on this 1-core box is slow; poll, never fixed-sleep."""
+def wait_port(port: int, timeout: float = 45.0,
+              popen: "subprocess.Popen | None" = None) -> None:
+    """Startup on this 1-core box is slow; poll, never fixed-sleep.
+    With `popen`, the port must be opened by THAT process: one that
+    exited (its port was taken) is an error, whoever answers there."""
     deadline = time.time() + timeout
     while time.time() < deadline:
+        if popen is not None and popen.poll() is not None:
+            raise RuntimeError(
+                f"process for port {port} exited {popen.returncode} "
+                "before it listened")
         try:
             with socket.create_connection(("127.0.0.1", port),
                                           timeout=1.0):
@@ -121,7 +149,7 @@ class Proc:
             [sys.executable, "-m", "seaweedfs_tpu", *self.args],
             cwd=REPO, env=env, stdout=self.log_f,
             stderr=subprocess.STDOUT)
-        wait_port(self.port)
+        wait_port(self.port, popen=self.popen)
         return self
 
     def kill9(self) -> None:

@@ -27,8 +27,8 @@ contention arXiv:1709.05365 measures.  Building blocks:
   token rate), `percentile`.
 
 The tier-1 fast subset (tests/test_soak.py) runs seconds of this; the
-`slow`-marked long run and `bench.py soak` run minutes, against a
-ProcCluster with the same helpers.
+`slow`-marked long run runs minutes, against a ProcCluster with the
+same helpers.
 """
 
 from __future__ import annotations

@@ -1,7 +1,7 @@
 """One whole trace per EC job (ISSUE 25): spans where the job's work
 happens, carried across the bulk transfers, kept past the poll chatter;
-and the staging ledger's split of the h2d seconds, its payload bytes and
-its two waits."""
+and the staging ledger's payload bytes, with the hand-off's waits read
+from the pipeline's own stage spans (ISSUE 29)."""
 
 import glob
 import os
@@ -355,41 +355,59 @@ def test_bulk_transfer_hangs_the_servers_span_under_the_callers(
 
 @pytest.fixture
 def small_windows(monkeypatch):
-    monkeypatch.setenv("SEAWEEDFS_TPU_H2D_WINDOW_MB", "0.004")
-    monkeypatch.setenv("SEAWEEDFS_TPU_H2D_INFLIGHT", "2")
-    monkeypatch.setenv("SEAWEEDFS_TPU_ENCODE_MESH", "0")
+    """Toy rows, three to a window of RS(10,4), one device to be seen."""
+    from seaweedfs_tpu.storage.erasure_coding import (ec_context,
+                                                      ec_encoder)
+    monkeypatch.setattr(staging, "WINDOW_BYTES", 32 * 4096)
+    monkeypatch.setattr(staging, "encode_shardings",
+                        lambda: (None, None, 1))
+    monkeypatch.setattr(ec_encoder, "SMALL_BLOCK_SIZE", 4096)
+    monkeypatch.setattr(ec_context, "SMALL_BLOCK_SIZE", 4096)
     staging.reset_aggregate()
     return monkeypatch
 
 
-def test_payload_bytes_are_the_volumes_and_pack_is_part_of_h2d(
+def _encode_traced(tmp_path, rid: str, progress=None) -> dict:
+    """A 13-row toy volume (5 work items, the last of one short row)
+    encoded under an `ec.encode` span; the stage spans by name."""
+    from seaweedfs_tpu.storage.erasure_coding import ec_encoder
+    from seaweedfs_tpu.storage.erasure_coding.ec_context import ECContext
+    dat_size = 12 * 10 * 4096 + 12_345
+    (tmp_path / "v.dat").write_bytes(np.random.default_rng(3).integers(
+        0, 256, dat_size, dtype=np.uint8).tobytes())
+    tracing.reset_buffer()
+    token = set_request_id(rid)
+    try:
+        with tracing.span("ec.encode", role="worker"):
+            ec_encoder.write_ec_files(str(tmp_path / "v"),
+                                      ECContext(backend="jax"),
+                                      progress=progress)
+    finally:
+        reset_request_id(token)
+    return {s["name"]: s for s in tracing.spans_for(rid)
+            if s["name"].startswith("encode.")}
+
+
+def test_payload_bytes_are_the_volumes_and_nothing_is_packed(
         small_windows, tmp_path):
     """A short tail: 13 small rows at 3 a window are 5 work items, the
     last of one row sent in the window's shape, and the last row is
     short."""
-    from seaweedfs_tpu.storage.erasure_coding import (ec_context,
-                                                      ec_encoder)
-    from seaweedfs_tpu.storage.erasure_coding.ec_context import ECContext
-    small_windows.setattr(ec_encoder, "SMALL_BLOCK_SIZE", 4096)
-    small_windows.setattr(ec_context, "SMALL_BLOCK_SIZE", 4096)
-    small_windows.setenv("SEAWEEDFS_TPU_H2D_WINDOW_MB", "0.125")
-    dat_size = 12 * 10 * 4096 + 12_345
-    (tmp_path / "v.dat").write_bytes(np.random.default_rng(3).integers(
-        0, 256, dat_size, dtype=np.uint8).tobytes())
-    with tracing.span("ec.encode", role="worker"):
-        ec_encoder.write_ec_files(str(tmp_path / "v"),
-                                  ECContext(backend="jax"))
+    stages = _encode_traced(tmp_path, "ledger-1")
     snap = staging.snapshot()
-    assert snap["payload_bytes"] == dat_size
-    assert snap["h2d_bytes"] == 15 * 10 * 4096 > dat_size
-    assert 0 < snap["pack_seconds"] <= snap["h2d_seconds"]
+    assert snap["payload_bytes"] == 12 * 10 * 4096 + 12_345
+    assert snap["h2d_bytes"] == 15 * 10 * 4096 > snap["payload_bytes"]
+    assert snap["pack_seconds"] == 0 < snap["h2d_seconds"]
     assert snap["launches"] == snap["windows"] == 5
+    assert all(stages[n]["attrs"]["calls"] == 5 for n in
+               ("encode.read", "encode.codec", "encode.write"))
 
 
-def _launch(data, kernel=gf_apply_matrix_words):
+def _launch(data):
     rs = ReedSolomonJax(10, 4)
     flat = pack_words(np.ascontiguousarray(data))
-    return staging.WindowedLaunch(rs._parity_rows, flat, kernel, 4,
+    return staging.WindowedLaunch(rs._parity_rows, flat,
+                                  gf_apply_matrix_words, 4,
                                   data.shape[1])
 
 
@@ -403,41 +421,38 @@ def test_payload_defaults_to_the_batch_less_its_padding(small_windows):
     assert snap["h2d_bytes"] == 10 * 4004
 
 
-def test_slot_wait_grows_with_a_slow_consumer(small_windows):
-    data = np.random.default_rng(2).integers(
-        0, 256, size=(10, 8192), dtype=np.uint8)
-    _launch(data).materialize()
-    quick = staging.snapshot()
-    staging.reset_aggregate()
-    n = 0
-    for _byte0, _chunk in _launch(data).windows():
-        time.sleep(0.02)                # the sinks are slow
-        n += 1
-    slow = staging.snapshot()
-    assert n == slow["windows"] >= 8
-    # two windows are staged ahead; the stager waits out most of the
-    # rest of the consumer's time (less its own work on a window)
-    assert slow["slot_wait_seconds"] >= 0.01 * n
-    assert slow["slot_wait_seconds"] > quick["slot_wait_seconds"] + 0.05
-    assert slow["ready_wait_seconds"] < slow["slot_wait_seconds"]
+def test_a_slow_writer_shows_in_the_write_stage(small_windows, tmp_path):
+    """What `slot_wait_seconds` stood for: the consumer is the slower
+    side.  The staging ledger has no wait to count; `encode.write` is
+    busy its whole length and `encode.codec` is not."""
+    stages = _encode_traced(tmp_path, "slow-writer",
+                            progress=lambda _d, _t: time.sleep(0.03))
+    write, codec = (stages[n] for n in ("encode.write", "encode.codec"))
+    assert write["attrs"]["busySeconds"] >= 5 * 0.03
+    assert write["attrs"]["busySeconds"] >= 0.8 * write["durationMs"] / 1e3
+    assert codec["attrs"]["busySeconds"] < write["attrs"]["busySeconds"]
+    snap = staging.snapshot()
+    assert snap["slot_wait_seconds"] == snap["ready_wait_seconds"] == 0
 
 
-def test_ready_wait_grows_with_a_slow_stager(small_windows):
-    data = np.random.default_rng(2).integers(
-        0, 256, size=(10, 8192), dtype=np.uint8)
+def test_a_slow_put_shows_in_the_codec_stage(small_windows, tmp_path):
+    """What `ready_wait_seconds` stood for: the staging side is the
+    slower one.  The puts run on the compute stage, so a device
+    codec's `encode.codec` says its busy seconds, and they hold the
+    ledger's h2d seconds."""
+    import jax
+    put = jax.device_put
 
-    def slow_kernel(mat, window):       # runs on the staging thread
-        time.sleep(0.02)
-        return gf_apply_matrix_words(mat, window)
-    _launch(data).materialize()
-    quick = staging.snapshot()
-    staging.reset_aggregate()
-    _launch(data, slow_kernel).materialize()
-    slow = staging.snapshot()
-    n = slow["windows"]
-    assert slow["ready_wait_seconds"] >= 0.01 * n
-    assert slow["ready_wait_seconds"] > quick["ready_wait_seconds"] + 0.05
-    assert slow["slot_wait_seconds"] < slow["ready_wait_seconds"]
+    def slow_put(x, *a, **kw):
+        time.sleep(0.03)
+        return put(x, *a, **kw)
+    small_windows.setattr(jax, "device_put", slow_put)
+    stages = _encode_traced(tmp_path, "slow-put")
+    snap = staging.snapshot()
+    assert snap["h2d_seconds"] >= 5 * 0.03
+    assert stages["encode.codec"]["attrs"]["busySeconds"] >= \
+        snap["h2d_seconds"]
+    assert snap["slot_wait_seconds"] == snap["ready_wait_seconds"] == 0
 
 
 def test_a_launch_emits_its_windows_under_the_callers_span(small_windows):

@@ -294,7 +294,7 @@ def test_encode_work_items_tile_exactly(backend):
                     got += [(row_start + r * small_row + shard * block,
                              block) for r in range(real_rows)]
                 if backend == "jax":    # one window, never wider
-                    assert d * batch <= staging.window_bytes()
+                    assert d * batch <= staging.WINDOW_BYTES
                     assert block % batch == 0 or batch % block == 0
             assert _merge_intervals(got) == _merge_intervals(expect), \
                 f"dat_size={dat_size} shard={shard}"

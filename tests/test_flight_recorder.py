@@ -475,8 +475,8 @@ def test_attribution_runtime_lever(front):
     """POST /debug/attribution {"disarmed": true} kills stage
     tracks, CPU sampling and flight capture in this process without
     a restart; {"disarmed": false} restores the env-configured
-    behavior.  (Also the lever behind bench.py's within-cluster
-    overhead A/B.)"""
+    behavior.  (Also the lever for a within-cluster overhead
+    A/B.)"""
     from seaweedfs_tpu.server import debug as debug_mod
     debug_mod.install_debug_routes(front)
     r = http_json("POST", f"{front.url}/debug/attribution",

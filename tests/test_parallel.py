@@ -101,22 +101,29 @@ def test_encode_volume_batch(mesh):
 
 def test_named_sharding_staged_encode_matches_shard_map(mesh,
                                                         monkeypatch):
-    """The tentpole's 1D Mesh(jax.devices(), ("batch",)) +
-    NamedSharding(P(None, "batch")) windowed staging path
-    (ops.staging, what parity_lazy ships) against the 2D shard_map
-    path and the CPU twin — the same cross-implementation identity
-    this module has always asserted, with the NamedSharding idiom as
-    the third implementation."""
+    """The 1D Mesh(jax.devices(), ("batch",)) +
+    NamedSharding(P(None, "batch")) staging path (ops.staging, what
+    parity_lazy ships) against the 2D shard_map path and the CPU twin
+    — the same cross-implementation identity this module has always
+    asserted, with the NamedSharding idiom as the third
+    implementation (ROADMAP D13: two mesh placements)."""
+    from seaweedfs_tpu.ops import staging
     from seaweedfs_tpu.ops.rs_jax import ReedSolomonJax
 
-    monkeypatch.setenv("SEAWEEDFS_TPU_ENCODE_MESH", "1")
-    monkeypatch.setenv("SEAWEEDFS_TPU_H2D_WINDOW_MB", "0.004")
+    asked = []
+    shardings = staging.encode_shardings
+
+    def seen():
+        asked.append(shardings())
+        return asked[-1]
+    monkeypatch.setattr(staging, "encode_shardings", seen)
     rng = np.random.default_rng(5)
     d, p, nbytes = 10, 4, 4096 * 8
     data = rng.integers(0, 256, size=(d, nbytes), dtype=np.uint8)
     want = rs_cpu.ReedSolomonCPU(d, p).parity(data)
     staged = ReedSolomonJax(d, p).parity_lazy(data)
-    assert hasattr(staged, "windows")  # the staged mesh path ran
+    # the staged mesh path ran: 8 devices, and 8192 words divide them
+    assert [a[2] for a in asked] == [8] and asked[0][0] is not None
     np.testing.assert_array_equal(staged.materialize(), want)
     mat = rs_matrix.parity_matrix(d, p)
     got32 = ec_sharded.encode_sharded(mesh, mat, pack_words(data))

@@ -1,12 +1,10 @@
-"""Windowed double-buffered h2d staging + mesh-sharded encode:
-byte-identity and plumbing (ROADMAP item 2 tentpole).
+"""Staged h2d + mesh-sharded encode: byte-identity and plumbing.
 
 Tier-1 on the conftest's 8 virtual CPU devices
-(XLA_FLAGS=--xla_force_host_platform_device_count=8): the mesh-sharded
-and windowed paths must be byte-identical to the single-device,
-single-shot `device_put` path — and to the CPU twin — for every window
-geometry, including uneven tails and batch axes that don't divide the
-device count."""
+(XLA_FLAGS=--xla_force_host_platform_device_count=8): a lazy launch —
+placed across the mesh where its words divide the device count, plain
+where they do not — must be byte-identical to the one-call `parity()`
+path and to the CPU twin, whatever the batch's size."""
 
 import numpy as np
 import pytest
@@ -21,12 +19,25 @@ D, P = 10, 4
 
 @pytest.fixture
 def knobs(monkeypatch):
-    """Baseline knob state: tiny windows (so even small test arrays
-    span many), mesh ON (the 8-device conftest mesh), depth 2."""
-    monkeypatch.setenv("SEAWEEDFS_TPU_H2D_WINDOW_MB", "0.002")
-    monkeypatch.setenv("SEAWEEDFS_TPU_H2D_INFLIGHT", "2")
-    monkeypatch.setenv("SEAWEEDFS_TPU_ENCODE_MESH", "1")
+    """A tiny window (so a toy volume is many work items) on the
+    8-device conftest mesh."""
+    monkeypatch.setattr(staging, "WINDOW_BYTES", 2048)
+    staging.reset_aggregate()
     return monkeypatch
+
+
+@pytest.fixture
+def placed(monkeypatch):
+    """The shardings every device_put of a [D, W] batch was given."""
+    seen = []
+    put = jax.device_put
+
+    def spy(x, device=None, **kw):
+        if getattr(x, "ndim", 0) == 2 and x.shape[0] == D:
+            seen.append(device)
+        return put(x, device, **kw)
+    monkeypatch.setattr(jax, "device_put", spy)
+    return seen
 
 
 def _data(nbytes: int, rows: int = D, seed: int = 0) -> np.ndarray:
@@ -34,41 +45,7 @@ def _data(nbytes: int, rows: int = D, seed: int = 0) -> np.ndarray:
         0, 256, size=(rows, nbytes), dtype=np.uint8)
 
 
-# -- unit: window planner + knobs -----------------------------------------
-
-def test_knob_parsing(monkeypatch):
-    monkeypatch.setenv("SEAWEEDFS_TPU_H2D_WINDOW_MB", "0.5")
-    assert staging.window_bytes() == 512 * 1024
-    monkeypatch.setenv("SEAWEEDFS_TPU_H2D_WINDOW_MB", "0")
-    assert staging.window_bytes() == 0
-    monkeypatch.setenv("SEAWEEDFS_TPU_H2D_WINDOW_MB", "junk")
-    assert staging.window_bytes() == \
-        int(staging.DEFAULT_WINDOW_MB * (1 << 20))
-    monkeypatch.setenv("SEAWEEDFS_TPU_H2D_INFLIGHT", "0")
-    assert staging.inflight_depth() == 1  # floor: one slot
-    monkeypatch.setenv("SEAWEEDFS_TPU_H2D_INFLIGHT", "3")
-    assert staging.inflight_depth() == 3
-    monkeypatch.setenv("SEAWEEDFS_TPU_ENCODE_MESH", "0")
-    assert not staging.mesh_enabled()
-    assert staging.encode_shardings() == (None, None, 1)
-    monkeypatch.delenv("SEAWEEDFS_TPU_ENCODE_MESH")
-    assert staging.mesh_enabled()
-
-
-def test_plan_windows_tiles_exactly(monkeypatch):
-    monkeypatch.setenv("SEAWEEDFS_TPU_H2D_WINDOW_MB", "0.001")
-    for w, ndev in ((1, 8), (7, 8), (1000, 8), (1024, 8), (333, 3),
-                    (26, 1)):
-        plan = staging.plan_windows(D, w, ndev)
-        pos = 0
-        for (w0, n, npad) in plan:
-            assert w0 == pos and n >= 1
-            assert npad % ndev == 0 and npad >= n
-            pos += n
-        assert pos == w, (w, ndev)
-    monkeypatch.setenv("SEAWEEDFS_TPU_H2D_WINDOW_MB", "0")
-    assert staging.plan_windows(D, 1024, 8) == []  # disabled
-
+# -- unit: the mesh's shardings --------------------------------------------
 
 def test_mesh_shardings_on_conftest_mesh():
     assert len(jax.devices()) == 8, "conftest must force 8 devices"
@@ -83,66 +60,76 @@ def test_mesh_shardings_on_conftest_mesh():
 
 def test_windowed_matches_single_shot_and_cpu(knobs):
     """Uneven everything: payload not a multiple of 4 (pack padding),
-    word count spanning many windows with a short tail."""
+    a batch many times the window, put whole: the lazy launch against
+    the one-call parity() and the CPU twin."""
     nbytes = 40_003
     data = _data(nbytes, seed=1)
     want = rs_cpu.ReedSolomonCPU(D, P).parity(data)
     codec = ReedSolomonJax(D, P)
     pend = codec.parity_lazy(data)
-    assert hasattr(pend, "windows")  # the staged handle
     got = pend.materialize()
+    assert got.shape == (P, nbytes) and got.dtype == np.uint8
     np.testing.assert_array_equal(got, want)
-    # single-shot reference: windowing disabled, mesh off
-    knobs.setenv("SEAWEEDFS_TPU_H2D_WINDOW_MB", "0")
-    knobs.setenv("SEAWEEDFS_TPU_ENCODE_MESH", "0")
-    one_shot = codec.parity_lazy(data)
-    assert not hasattr(one_shot, "windows")
-    np.testing.assert_array_equal(one_shot.materialize(), want)
+    np.testing.assert_array_equal(np.asarray(codec.parity(data)), want)
 
 
-def test_mesh_sharded_matches_single_device(knobs):
-    """Batch axis NOT divisible by the 8-device mesh (1001 words),
-    exercising the pad-then-slice path."""
-    nbytes = 4 * 1001
-    data = _data(nbytes, seed=2)
+def test_mesh_sharded_matches_single_device(knobs, placed):
+    """The same batch placed across the 8-device mesh and, with one
+    device to be seen, plain: the same bytes."""
+    data = _data(4 * 1000, seed=2)
     codec = ReedSolomonJax(D, P)
     mesh_out = codec.parity_lazy(data).materialize()
-    knobs.setenv("SEAWEEDFS_TPU_ENCODE_MESH", "0")
+    knobs.setattr(staging, "encode_shardings", lambda: (None, None, 1))
     single_out = codec.parity_lazy(data).materialize()
+    assert placed[0] is not None and placed[1] is None
     np.testing.assert_array_equal(mesh_out, single_out)
     np.testing.assert_array_equal(
         mesh_out, rs_cpu.ReedSolomonCPU(D, P).parity(data))
 
 
-def test_windows_stream_in_order_with_stats(knobs):
-    nbytes = 16_000
-    data = _data(nbytes, seed=3)
-    codec = ReedSolomonJax(D, P)
-    pend = codec.parity_lazy(data)
-    got = np.empty((P, nbytes), dtype=np.uint8)
-    covered = 0
-    n_windows = 0
-    for byte0, chunk in pend.windows():
-        assert byte0 == covered  # strict launch order
-        got[:, byte0:byte0 + chunk.shape[1]] = chunk
-        covered += chunk.shape[1]
-        n_windows += 1
-    assert covered == nbytes and n_windows > 1
+@pytest.mark.parametrize("words", [1024, 1001])
+def test_a_window_is_placed_by_whether_its_words_divide_the_mesh(
+        placed, words):
+    """ISSUE 29: 1024 words divide the 8 devices and are split across
+    them; 1001 do not and go plain to the default device, unpadded."""
+    staging.reset_aggregate()
+    data = _data(4 * words, seed=words)
+    got = ReedSolomonJax(D, P).parity_lazy(data).materialize()
     np.testing.assert_array_equal(
         got, rs_cpu.ReedSolomonCPU(D, P).parity(data))
+    if words % 8:
+        assert placed == [None]
+    else:
+        assert placed == [staging.encode_shardings()[0]]
+    assert staging.snapshot()["h2d_bytes"] == D * 4 * words
+
+
+def test_windows_stream_in_order_with_stats(knobs):
+    """One launch, its ledger: one window, put as it stood, every byte
+    of it sent and every parity byte fetched, fetched once."""
+    nbytes = 16_000
+    data = _data(nbytes, seed=3)
+    pend = ReedSolomonJax(D, P).parity_lazy(data)
     s = pend.stats
-    assert s.windows == n_windows
-    assert 0.0 <= s.overlap_fraction <= 1.0
-    assert s.h2d_bytes > 0 and s.d2h_bytes > 0
+    assert s.windows == s.direct_windows == 1
+    assert s.h2d_bytes == s.payload_bytes == D * nbytes
+    assert s.h2d_seconds > 0 and s.d2h_bytes == 0   # not yet fetched
+    assert staging.snapshot()["launches"] == 0      # nor counted
+    np.testing.assert_array_equal(
+        pend.materialize(), rs_cpu.ReedSolomonCPU(D, P).parity(data))
+    assert s.d2h_bytes == P * nbytes and s.d2h_seconds > 0
+    assert s.start < s.end and 0.0 <= s.overlap_fraction <= 1.0
+    assert s.pack_seconds == s.slot_wait_seconds == \
+        s.ready_wait_seconds == 0.0
+    assert staging.snapshot()["launches"] == 1
     with pytest.raises(RuntimeError):
-        list(pend.windows())  # single-consumer contract
+        pend.materialize()  # single-consumer contract
 
 
 def test_apply_matrix_lazy_windowed_rebuild_path(knobs):
     """The rebuild pipeline's generic apply takes the same staged
-    path: reconstruction-matrix apply, windowed + mesh-sharded, equals
-    the CPU twin's."""
-    nbytes = 12_289  # odd tail
+    path: reconstruction-matrix apply equals the CPU twin's."""
+    nbytes = 12_289  # odd tail: word padding, 3073 words placed plain
     cpu = rs_cpu.ReedSolomonCPU(D, P)
     data = _data(nbytes, seed=4)
     full = np.asarray(cpu.encode(np.concatenate(
@@ -152,7 +139,6 @@ def test_apply_matrix_lazy_windowed_rebuild_path(knobs):
     coeffs, rows = rs_matrix.reconstruction_matrix(D, P, present, lost)
     codec = ReedSolomonJax(D, P)
     pend = codec.apply_matrix_lazy(coeffs, full[list(rows)])
-    assert hasattr(pend, "windows")
     np.testing.assert_array_equal(pend.materialize(), full[lost])
 
 
@@ -162,7 +148,8 @@ def test_aggregate_snapshot(knobs):
     codec.parity_lazy(_data(8_192, seed=5)).materialize()
     codec.parity_lazy(_data(8_192, seed=6)).materialize()
     snap = staging.snapshot()
-    assert snap["launches"] == 2 and snap["windows"] >= 4
+    assert snap["launches"] == snap["windows"] == 2
+    assert snap["h2d_bytes"] == 2 * D * 8_192
     assert snap["h2d_gbps"] > 0
     assert 0.0 <= snap["overlap_fraction"] <= 1.0
 
@@ -171,16 +158,15 @@ def test_aggregate_snapshot(knobs):
 
 def test_generate_ec_files_windowed_byte_identical(knobs, tmp_path,
                                                    monkeypatch):
-    """Full encode pipeline (reader -> windowed staged codec -> sink
-    drain pushing parity windows as they land) vs the CPU reference
-    files, with a ragged tail volume."""
+    """Full encode pipeline (reader -> staged codec -> sinks) vs the
+    CPU reference files, with a ragged tail volume."""
     from seaweedfs_tpu.storage.erasure_coding import (ec_context,
                                                       ec_encoder)
     from seaweedfs_tpu.storage.erasure_coding.ec_context import ECContext
 
     # shrink geometry: 4KB "small rows"; the knobs' 2KB window holds
-    # no whole row, so a work item is one row, wider than a window:
-    # every launch is cut and packed
+    # no whole row, so a work item is one row, wider than the window's
+    # size: put whole all the same
     monkeypatch.setattr(ec_encoder, "SMALL_BLOCK_SIZE", 4096)
     monkeypatch.setattr(ec_context, "SMALL_BLOCK_SIZE", 4096)
     staging.reset_aggregate()
@@ -200,24 +186,17 @@ def test_generate_ec_files_windowed_byte_identical(knobs, tmp_path,
         assert a == b, f"shard {i} differs under windowed staging"
     snap = staging.snapshot()
     assert snap["launches"] == 5            # 200,001 B: 5 rows of 40KB
-    assert snap["windows"] > 5 * 10 and snap["direct_windows"] == 0
+    assert snap["windows"] == snap["direct_windows"] == 5
+    assert snap["h2d_bytes"] == 5 * D * 4096
 
 
-@pytest.mark.parametrize("window_mb", ["0", "64"])
-def test_generate_ec_files_one_shot_fallback(tmp_path, monkeypatch,
-                                             window_mb):
-    """Review regression: with staging switched off ("0") the codec
-    hands the pipeline the LEGACY _PendingParity handle — the
-    accepts_lazy writer must materialize it itself instead of
-    subscripting the handle (TypeError at the parity write).  With a
-    window that holds the whole toy volume ("64") the pipeline's one
-    work item is one window, staged as it stands."""
+def test_generate_ec_files_one_shot_fallback(tmp_path, monkeypatch):
+    """A window that holds the whole toy volume (the default 32 MiB
+    against 4KB rows): the pipeline's one work item is one launch."""
     from seaweedfs_tpu.storage.erasure_coding import (ec_context,
                                                       ec_encoder)
     from seaweedfs_tpu.storage.erasure_coding.ec_context import ECContext
 
-    monkeypatch.setenv("SEAWEEDFS_TPU_ENCODE_MESH", "0")
-    monkeypatch.setenv("SEAWEEDFS_TPU_H2D_WINDOW_MB", window_mb)
     monkeypatch.setattr(ec_encoder, "SMALL_BLOCK_SIZE", 4096)
     monkeypatch.setattr(ec_context, "SMALL_BLOCK_SIZE", 4096)
     staging.reset_aggregate()
@@ -234,20 +213,5 @@ def test_generate_ec_files_one_shot_fallback(tmp_path, monkeypatch,
         assert (tmp_path / f"j.ec{i:02d}").read_bytes() == \
             (tmp_path / f"c.ec{i:02d}").read_bytes(), f"shard {i}"
     snap = staging.snapshot()
-    assert snap["windows"] == snap["direct_windows"] == \
-        (1 if window_mb == "64" else 0)
-
-
-# -- bench: predictive roofline stays honest ------------------------------
-
-def test_bench_ceiling_never_raised_to_observed():
-    import bench
-    out = {}
-    bench._apply_ceiling(out, "k", 5.0, {"a": 2.0, "b": 3.0})
-    assert out["k_bound_by"] == "a"
-    assert out["k_ceiling_gbps"] == 2.0  # NOT raised to 5.0
-    assert out["k_of_ceiling"] == 2.5    # >1.0 reported honestly
-    assert "exceeds the predicted ceiling" in out["k_ceiling_note"]
-    out = {}
-    bench._apply_ceiling(out, "k", 1.5, {"a": 2.0})
-    assert out["k_of_ceiling"] == 0.75 and "k_ceiling_note" not in out
+    assert snap["launches"] == snap["windows"] == 1
+    assert snap["payload_bytes"] == 60_000
