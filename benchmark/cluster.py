@@ -52,6 +52,12 @@ def free_bytes(path: str) -> int:
     return st.f_bavail * st.f_frsize
 
 
+def tree_bytes(path: str) -> int:
+    """Bytes the files under `path` hold, as their blocks count them."""
+    return sum(os.lstat(os.path.join(d, name)).st_blocks * 512
+               for d, _dirs, names in os.walk(path) for name in names)
+
+
 MEMORY_FS = ("tmpfs", "ramfs")
 
 
@@ -66,6 +72,34 @@ def choose_data_root(need_bytes: int) -> "tuple[str, str]":
                 free_bytes(cand) >= need_bytes:
             return cand, fs_type(cand)
     return tmp, fs_type(tmp)
+
+
+def memory_now() -> "dict[str, int]":
+    """{"total", "available", "shmem"} in bytes, from /proc/meminfo;
+    where a cgroup holds this process to less memory than the machine
+    has, "total" is that limit and "available" no more than what is
+    left under it."""
+    kb = {}
+    with open("/proc/meminfo") as f:
+        for ln in f:
+            name, _, rest = ln.partition(":")
+            if name in ("MemTotal", "MemAvailable", "Shmem"):
+                kb[name] = int(rest.split()[0]) * 1024
+    got = {"total": kb["MemTotal"], "available": kb["MemAvailable"],
+           "shmem": kb["Shmem"]}
+    for limit, used in (("memory.max", "memory.current"),
+                        ("memory/memory.limit_in_bytes",
+                         "memory/memory.usage_in_bytes")):
+        try:
+            with open("/sys/fs/cgroup/" + limit) as f:
+                cap = int(f.read())        # "max" where there is none
+            with open("/sys/fs/cgroup/" + used) as f:
+                left = cap - int(f.read())
+        except (OSError, ValueError):
+            continue
+        if cap < got["total"]:
+            got.update(total=cap, available=min(got["available"], left))
+    return got
 
 
 def make_data_root(parent: str) -> str:
@@ -522,14 +556,24 @@ class Cluster:
                         for s, ps in sorted(found.items())}}
         return faults
 
-    def watch_alive(self, stop, every: float = 1.0) -> "list[tuple]":
+    def watch_alive(self, stop, every: float = 1.0,
+                    memory: "dict | None" = None) -> "list[tuple]":
         """Until `stop` is set, asks the master once a second which
         volume servers it holds alive, the question a job asks before
         it places its shards; keeps [(time, [urls])] at each change.  A
-        server the master drops is one that a job would leave out."""
+        server the master drops is one that a job would leave out.  In
+        the same turn it reads the machine's memory into `memory`: the
+        least "available" and the most "shmem" seen."""
         from seaweedfs_tpu.server.httpd import http_json
         seen: "list[tuple]" = []
         while not stop.wait(every):
+            if memory is not None:
+                now = memory_now()
+                memory.update(
+                    available=min(now["available"],
+                                  memory.get("available", now["available"])),
+                    shmem=max(now["shmem"], memory.get("shmem", 0)),
+                    reads=memory.get("reads", 0) + 1)
             try:
                 alive = sorted(http_json(
                     "GET", f"{self.master}/cluster/status",

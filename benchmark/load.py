@@ -42,16 +42,22 @@ def summarize(parts: "list[dict]", start: float, stop: float) -> dict:
     over every request sent in the window; a failed or wrong one
     counts at the larger of what it took and the request timeout, so
     it misses any limit.  The rate is correct responses completed
-    inside the window over the window."""
+    inside the window over the window.  Where the window closes before
+    the clients stop (`stop` earlier than the stop they were given),
+    rate, latency and lateness leave out what was sent after it;
+    "requests", "wrong" and "failed" count every request made."""
     sent = np.concatenate([p["sent"] for p in parts])
     lat = np.concatenate([p["latency"] for p in parts])
     status = np.concatenate([p["status"] for p in parts])
     late = np.concatenate([p["late"] for p in parts])
     timeout = float(parts[0]["timeout"])
+    inside = sent <= stop
     cost = np.where(status == OK, lat, np.maximum(lat, timeout))
-    ordered = np.sort(cost)
-    done_in = (status == OK) & (sent + lat <= stop)
+    ordered = np.sort(cost[inside])
+    done_in = inside & (status == OK) & (sent + lat <= stop)
+    late = late[inside]
     return {"requests": int(len(sent)),
+            "requests_in_window": int(inside.sum()),
             "wrong": int((status == WRONG).sum()),
             "failed": int((status == FAILED).sum()),
             "completed_in_window": int(done_in.sum()),

@@ -160,6 +160,61 @@ def volumes_for(seconds: float, job_seconds: float, margin: float) -> int:
     return max(1, math.ceil(seconds / (job_seconds * margin)))
 
 
+# The share of the machine's memory that a run's data root may come to
+# hold.  Every volume of a window rests in memory until the comparison
+# after it, so a faster program asks for more of them; the machine has
+# no more to give.  Two thirds of the 45.0 GiB chip host is 32.2 GB,
+# what the accepted runs were seen to peak at (31.8 GB of Shmem with
+# 8.3 GB left available, PR 28); the third left is the roles', the
+# chip's owner's (it pins some 4.7 GB while it initialises) and the
+# comparison's.  One number for every cell: no flag, no variable.
+MEMORY_SHARE = 2 / 3
+SHORT_WINDOW_S = 20.0         # a window the budget closes under this is
+#                               too short a measure: said loudly
+
+
+def set_bytes(cfg: dict, vol_bytes: int, n: int) -> int:
+    """What `n` job volumes of `vol_bytes` bring the data root to hold
+    at the worst moment a window can reach: all but the last encoded
+    and at rest as (k+r)/k of a volume of shards, the last in flight at
+    the end of its distribute: its source, the worker's copy, the
+    worker's shard files and the targets'."""
+    if n <= 0:
+        return 0
+    grow = (cfg["data_shards"] + cfg["parity_shards"]) / cfg["data_shards"]
+    return math.ceil(vol_bytes * (grow * (n - 1) + 2 + 2 * grow))
+
+
+def volumes_within(cfg: dict, vol_bytes: int, room: float) -> int:
+    """`n_budget`: the most job volumes whose `set_bytes` fit `room`."""
+    n = 0
+    while set_bytes(cfg, vol_bytes, n + 1) <= room:
+        n += 1
+    return n
+
+
+def check_room(what: str, n: int, need: int, have: int) -> None:
+    """Set-up ends here, with both numbers, rather than the machine
+    running out of memory under a window."""
+    if have < need:
+        raise BenchFailure(
+            f"{n} job volumes need {need} bytes ({need / 1e9:.2f} GB) of "
+            f"{what} and {have} bytes ({have / 1e9:.2f} GB) are there")
+
+
+def close_of(wanted: int, n_budget: int, started: int,
+             dry: float) -> "tuple[str, float]":
+    """("seconds" | "budget", `chain_dry_s`).  Seconds of the window
+    left with no job are a fault when the run loaded what it asked for
+    and still ran out.  They are the rule only when the memory budget
+    cut the volumes and the chain started a job on every one it left:
+    then the budget's last job closed the window, jobs back to back
+    until then."""
+    if dry > 0 and wanted > n_budget and started == n_budget:
+        return "budget", 0.0
+    return "seconds", dry
+
+
 PHASE_MARKS = (("pull", "marked readonly", "copied volume files"),
                ("encode", "copied volume files", "encoded "),
                ("distribute", "encoded ", "distributed shards"),
@@ -193,20 +248,15 @@ def job_phases(log: "list[list]") -> "dict[str, dict]":
 @dataclasses.dataclass
 class Hooks:
     """Where the tests and the control break the timed path: each a
-    callable (cluster, state) -> None."""
+    callable (cluster, state) -> None.  `memory_total` stands in for
+    the machine's memory, so that a toy run can meet its budget."""
     before_window: "object | None" = None
     before_verify: "object | None" = None
+    memory_total: "int | None" = None
 
 
 def say(msg: str) -> None:
     print(msg, flush=True)
-
-
-def need_bytes(cfg: dict, shapes: "list[tuple[int, int]]") -> int:
-    k, r = cfg["data_shards"], cfg["parity_shards"]
-    # every volume as .dat and as shards, and the worker's copy of one
-    return int(sum(n * size for n, size in shapes) * (1 + (k + r) / k)
-               + 3 * max(n * size for n, size in shapes))
 
 
 def verify_needles(cluster, vols: "list[dict]", per_volume: int,
@@ -322,25 +372,49 @@ class Run:
             if j["status"] != "done":
                 raise BenchFailure(f"set-up job failed: {j['message']}")
             took -= compiling
-        self.job_vols = []
+        self.job_vols, self.sizing = [], None
         if self.jobs_t:
-            # as many volumes as the window can start jobs on, reckoned
-            # from the set-up's own job of that shape: none loaded idle,
-            # and more of them when a later PR makes a job shorter
-            n = volumes_for(self.seconds, took,
-                            self.jobs_t["job_seconds_margin"])
-            shapes = [self.job_shape] * n
-            if cl.free_bytes(root) < need_bytes(cfg, shapes):
-                raise BenchFailure(
-                    f"{n} volumes for a {self.seconds:.0f}s window of "
-                    f"{took:.1f}s jobs do not fit the data root")
-            t0 = time.perf_counter()
-            self.job_vols = self.cluster.load_volumes(
-                args.seed, shapes, first_index=len(first))
-            say(f"loaded {n} volumes for the window's jobs in "
-                f"{time.perf_counter() - t0:.2f}s")
+            self.job_vols = self.load_job_volumes(took, first[-1]["bytes"],
+                                                  len(first))
         self.state.update(read_vols=self.read_vols, job_vols=self.job_vols)
         self.loaders = self.start_loaders() if self.reads else []
+
+    def load_job_volumes(self, took: float, vol_bytes: int,
+                         first_index: int) -> "list[dict]":
+        """As many volumes as the window can start jobs on, reckoned
+        from the set-up's own job of that shape: none loaded idle, more
+        of them when a later PR makes a job shorter, and never more
+        than the memory budget holds: past that the window closes at
+        the budget's last job (`close_of`)."""
+        root, margin = self.root, self.jobs_t["job_seconds_margin"]
+        mem = cl.memory_now()
+        total = self.hooks.memory_total or mem["total"]
+        budget, resident = int(total * MEMORY_SHARE), cl.tree_bytes(root)
+        wanted = volumes_for(self.seconds, took, margin)
+        n_budget = volumes_within(self.cfg, vol_bytes, budget - resident)
+        n = min(wanted, n_budget)
+        need = set_bytes(self.cfg, vol_bytes, max(n, 1))
+        free = cl.free_bytes(root)
+        self.sizing = {"wanted": wanted, "n_budget": n_budget, "loaded": n,
+                       "budget": budget, "total": total}
+        say(f"window's volumes: wanted {wanted} ({self.seconds:.0f}s of "
+            f"{took:.2f}s jobs at margin {margin}), budget {n_budget} "
+            f"({budget / 1e9:.2f} GB, {MEMORY_SHARE:.3f} of "
+            f"{total / 1e9:.2f} GB, {resident / 1e9:.2f} GB resident, "
+            f"{vol_bytes} bytes a volume), loading {n}: at most "
+            f"{need / 1e9:.2f} GB more; MemAvailable "
+            f"{mem['available'] / 1e9:.2f} GB, Shmem "
+            f"{mem['shmem'] / 1e9:.2f} GB, data root free "
+            f"{free / 1e9:.2f} GB")
+        check_room("the memory budget", max(n, 1), resident + need, budget)
+        check_room("available memory", n, need, mem["available"])
+        check_room("the data root", n, need, free)
+        t0 = time.perf_counter()
+        vols = self.cluster.load_volumes(
+            self.args.seed, [self.job_shape] * n, first_index=first_index)
+        say(f"loaded {n} volumes for the window's jobs in "
+            f"{time.perf_counter() - t0:.2f}s")
+        return vols
 
     def start_loaders(self) -> list:
         reads, root = self.reads, self.root
@@ -390,13 +464,19 @@ class Run:
                     self.job_vols, self.seconds, t_open,
                     cluster.submit_encode,
                     lambda jid: cluster.wait_job(jid, JOB_TIMEOUT_S))
+                if reads and time.time() < self.t_stop:
+                    # the chain ended beside the readers: the servers'
+                    # counters as they stand at its last finish
+                    chain["vreq"] = cluster.volume_counters()
             except Exception as e:  # noqa: BLE001 — carried to the parent
                 chain["error"] = e
         th = threading.Thread(target=drive_chain, daemon=True)
         watched: dict = {}
         unwatch = threading.Event()
+        self.memory_seen: dict = {}
         watcher = threading.Thread(target=lambda: watched.update(
-            alive=cluster.watch_alive(unwatch)), daemon=True)
+            alive=cluster.watch_alive(unwatch, memory=self.memory_seen)),
+            daemon=True)
         if self.jobs_t:
             th.start()
             watcher.start()
@@ -411,7 +491,14 @@ class Run:
                 raise BenchFailure("the job chain did not end: "
                                    f"{chain.get('error', 'still running')}")
         self.jobs = jobs = chain["jobs"]
-        self.t_close = max([self.t_stop if reads else time.time()]
+        sz = self.sizing or {"wanted": 0, "n_budget": 0}
+        self.closed_by, self.chain_dry_s = close_of(
+            sz["wanted"], sz["n_budget"], len(jobs), chain["dry"])
+        # closed by the budget, the window is the seconds the chain ran:
+        # what is read beside the jobs is read over those
+        self.t_end = max(j["finish"] for j in jobs) \
+            if self.closed_by == "budget" else self.t_stop
+        self.t_close = max([self.t_end if reads else time.time()]
                            + [j["finish"] for j in jobs])
         unwatch.set()
         if self.jobs_t:
@@ -423,7 +510,8 @@ class Run:
         vreq1 = cluster.volume_counters() if reads else None
         self.report = report = cluster.wire.ask("report")
         self.check_one_owner()
-        self.chain_dry_s = chain["dry"]
+        if self.closed_by == "budget" and "vreq" in chain:
+            vreq1 = chain["vreq"]
         phases = job_phases(report["log"])
         for j in jobs:
             j.update(phases.get(j["id"], {"phases": {}}))
@@ -436,7 +524,7 @@ class Run:
             for _proc, out in self.loaders:
                 with np.load(out) as z:
                     parts.append({k: z[k] for k in z.files})
-            self.read_sum = ld.summarize(parts, t_open, self.t_stop)
+            self.read_sum = ld.summarize(parts, t_open, self.t_end)
 
         def delta(key):
             return {k: mark1[key][k] - mark0[key][k] for k in mark1[key]
@@ -460,10 +548,32 @@ class Run:
 
     def say_window(self) -> None:
         jobs, rs, m = self.jobs, self.read_sum, self.mark1
+        if self.sizing:
+            sz, ran = self.sizing, self.t_close - self.t_open
+            say(f"window's volumes: wanted {sz['wanted']}, budget "
+                f"{sz['n_budget']}, loaded {sz['loaded']}, started "
+                f"{len(jobs)}; closed by " + (
+                    f"budget after {ran:.3f}s" if self.closed_by == "budget"
+                    else f"seconds ({self.seconds:.0f}s)"
+                    + (f", {self.chain_dry_s:.3f}s of them with no job: "
+                       "A FAULT" if self.chain_dry_s else "")))
+            if self.closed_by == "budget" and ran < SHORT_WINDOW_S:
+                say(f"WARNING: THE MEMORY BUDGET CLOSED THE WINDOW AFTER "
+                    f"{ran:.1f}s, UNDER {SHORT_WINDOW_S:.0f}s: THE CELL NEEDS "
+                    "A WAY TO RECYCLE ITS VOLUMES OR A LARGER HOST")
+            seen = self.memory_seen
+            if seen:
+                say(f"memory in the window ({seen['reads']} readings): "
+                    f"least MemAvailable {seen['available'] / 1e9:.2f} GB, "
+                    f"peak Shmem {seen['shmem'] / 1e9:.2f} GB; budget "
+                    f"{sz['budget'] / 1e9:.2f} GB of "
+                    f"{sz['total'] / 1e9:.2f} GB")
         say(f"window: {self.t_close - self.t_open:.3f}s, {len(jobs)} jobs "
             f"({sum(not j['ok'] for j in jobs)} failed)"
             + (f", {rs['requests']} requests ({rs['failed']} failed, "
-               f"{rs['wrong']} wrong), generator late mean "
+               f"{rs['wrong']} wrong; {rs['requests_in_window']} of them "
+               f"sent in the window's {self.t_end - self.t_open:.3f}s), "
+               f"generator late mean "
                f"{rs['late_mean_ms']:.3f} ms max {rs['late_max_ms']:.3f} ms"
                if rs else ""))
         for j in jobs:
@@ -605,9 +715,13 @@ def run_cell(args, hooks: "Hooks | None" = None) -> dict:
     run = Run(args, hooks or Hooks())
     # a guess for choosing the root; what the window needs is reckoned
     # and checked once the set-up's job has been timed
-    parent, kind = cl.choose_data_root(need_bytes(
-        run.cfg, run.first_shapes() + [run.job_shape] * (
-            volumes_for(run.seconds, 3.0, 1.0) if run.jobs_t else 0)))
+    vol = run.job_shape[0] * run.job_shape[1]
+    n = min(volumes_for(run.seconds, 3.0, 1.0), volumes_within(
+        run.cfg, vol, cl.memory_now()["total"] * MEMORY_SHARE)
+    ) if run.jobs_t else 0
+    parent, kind = cl.choose_data_root(
+        2 * sum(count * size for count, size in run.first_shapes())
+        + set_bytes(run.cfg, vol, n))
     root = cl.make_data_root(parent)
     say(f"data root: {root} on {kind}, "
         f"{cl.free_bytes(root) / 2**30:.1f} GiB free before")

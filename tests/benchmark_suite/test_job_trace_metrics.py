@@ -37,10 +37,12 @@ def reader(name):
 
 def test_the_new_entries_stand_last_each_with_reader_cells_and_moves():
     spec = run.load_spec()
-    tail = spec["per_layer"][-len(NEW):]
+    # found by name and in their order: every later PR appends entries
+    tail = [m for m in spec["per_layer"] if m["name"] in NEW]
     assert [m["name"] for m in tail] == list(NEW)
     e2e = {m["name"] for m in run.metrics_of(spec, "end_to_end", CELL)}
-    layers = {m["layer"] for m in spec["per_layer"][:-len(NEW)]} | \
+    layers = {m["layer"] for m in spec["per_layer"]
+              if m["name"] not in NEW} | \
         {"serving planes"}          # PERF.md 3's name for the volume roles
     for m in tail:
         unit, better, source, layer = NEW[m["name"]]
@@ -253,9 +255,11 @@ def test_traced_rehearsal_finds_its_roles_and_prints_the_new_metrics(
     assert m["job_distribute_s"] > 0 and m["push_GBps"] > 0
     assert 0 < m["push_sender_cpu_share"] < 1.5
     assert 0 < m["push_receiver_cpu_share"] < 1.5
-    assert 0 < m["staging_pack_share"] <= 1
     assert 0 < m["staging_pad_share"] < 1
-    assert m["staging_slot_wait_s"] >= 0 and m["staging_ready_wait_s"] > 0
+    # constants since PR 29: no copy before a put, no semaphore, no
+    # hand-off queue inside a launch
+    assert m["staging_pack_share"] == 0
+    assert m["staging_slot_wait_s"] == 0 and m["staging_ready_wait_s"] == 0
     # the program's span agrees with the phase the harness cuts out of
     # the progress messages, job by job
     both = [ln for ln in out.splitlines() if "by progress marks" in ln]
