@@ -18,8 +18,11 @@ worker/tasks/erasure_coding/ec_task.go:59):
 
 from __future__ import annotations
 
+import contextvars
 import os
+import threading
 import time
+from concurrent.futures import ThreadPoolExecutor
 
 from ... import tracing
 from ...operation import master_json
@@ -394,7 +397,9 @@ class EcEncodeHandler(JobHandler):
                               ctx: ECContext, base: str,
                               started: "tuple[list[str], float]") -> dict:
         """Round-robin shard spread over the servers the job started
-        under (:532) + mount (shard_distribution.go:209)."""
+        under (:532), pushed to all of them at once, + mount
+        (shard_distribution.go:209): nothing is mounted unless every
+        file reached every target."""
         with tracing.span("ec.distribute", role="worker") as sp:
             servers, waited_at_start = started
             sp.set("serversAtStart", len(servers))
@@ -404,21 +409,17 @@ class EcEncodeHandler(JobHandler):
             placement: dict[str, list[int]] = {t: [] for t in targets}
             for sid in range(ctx.total):
                 placement[targets[sid % len(targets)]].append(sid)
-            pushed = 0
-            for target, sids in placement.items():
-                if not sids:
-                    continue
-                for sid in sids:
-                    pushed += _push_file(target, vid, collection,
-                                         to_ext(sid), base + to_ext(sid))
-                for ext in (".ecx", ".vif"):
-                    pushed += _push_file(target, vid, collection, ext,
-                                         base + ext)
-            sp.set("servers", sum(1 for s in placement.values() if s))
+            holders = {t: sids for t, sids in placement.items() if sids}
+            pushed, push_seconds = _push_to_each(holders, vid, collection,
+                                                 base)
+            sp.set("servers", len(holders))
             sp.set("bytes", pushed)
-            for target, sids in placement.items():
-                if sids:
-                    _mount_shards(target, vid, collection, sids)
+            # Σ seconds of the ec.push spans over pushSeconds is how
+            # many streams really ran at once
+            sp.set("streams", len(holders))
+            sp.set("pushSeconds", round(push_seconds, 6))
+            for target, sids in holders.items():
+                _mount_shards(target, vid, collection, sids)
         return placement
 
     # -- batch execute: N volumes through ONE mesh launch per step -----
@@ -700,3 +701,42 @@ def _push_file(target: str, vid: int, collection: str, ext: str,
             raise RuntimeError(f"push {ext} to {target}: {status} "
                                f"{body[:200]!r}")
     return size
+
+
+def _push_to_each(holders: "dict[str, list[int]]", vid: int,
+                  collection: str, base: str) -> "tuple[int, float]":
+    """(bytes pushed, seconds from the first push's start to the last
+    one's end): one pusher thread a target, each sending its own
+    target's files one at a time (its shards ascending, then .ecx,
+    .vif), so that a receiver never sees two pushes of one job at
+    once while the receivers, a process each, all work.  A push that
+    fails stops the others before their next file; once all have
+    ended the first failure in target order is raised."""
+    failed = threading.Event()
+
+    def push(target: str, sids: "list[int]") -> int:
+        sent = 0
+        for ext in [to_ext(sid) for sid in sids] + [".ecx", ".vif"]:
+            if failed.is_set():
+                break
+            try:
+                sent += _push_file(target, vid, collection, ext,
+                                   base + ext)
+            except BaseException:
+                failed.set()
+                raise
+        return sent
+
+    t0 = time.monotonic()
+    with ThreadPoolExecutor(len(holders),
+                            thread_name_prefix="ec-push") as pool:
+        # the current span, the request id and an armed deadline are
+        # contextvars and do not follow a thread: each pusher runs in
+        # its own copy of this context (a Context cannot be entered
+        # twice at once), so ec.push hangs under ec.distribute and the
+        # receiver under the push
+        pushers = [pool.submit(contextvars.copy_context().run, push,
+                               target, sids)
+                   for target, sids in holders.items()]
+    seconds = time.monotonic() - t0
+    return sum(p.result() for p in pushers), seconds

@@ -133,8 +133,11 @@ def test_pull_and_pushes_carry_their_bytes(job):
         for p in glob.glob(os.path.join(d, "*" + ext)))
     assert sum(s["attrs"]["bytes"] for s in pushes) == on_disk
     dist = _one(spans, "ec.distribute")
+    push_seconds = dist["attrs"]["pushSeconds"]
     assert dist["attrs"] == {"serversAtStart": 3, "servers": 3,
-                             "bytes": on_disk}
+                             "bytes": on_disk, "streams": 3,
+                             "pushSeconds": push_seconds}
+    assert 0 < push_seconds <= dist["durationMs"] / 1e3
     exts = sorted(s["attrs"]["ext"] for s in pushes)
     assert exts == sorted([f".ec{i:02d}" for i in range(14)]
                           + [".ecx", ".vif"] * 3)
@@ -208,6 +211,8 @@ def test_trace_show_renders_one_tree_down_to_the_receivers(job):
     assert len(pushes) == FILES_PUSHED
     assert all(" via=sendfile " in ln and " ext=." in ln and
                " cpuSeconds=" in ln for ln in pushes)
+    (dist,) = [ln for ln in lines if "ms ec.distribute  " in ln]
+    assert " streams=3 " in dist and " pushSeconds=" in dist
 
 
 # -- quiet routes -------------------------------------------------------------
