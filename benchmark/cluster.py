@@ -10,7 +10,6 @@ from __future__ import annotations
 import hashlib
 import json
 import os
-import random
 import signal
 import socket
 import subprocess
@@ -247,19 +246,32 @@ class Procs:
                       + tail.decode("utf-8", "replace") + "\n")
 
 
+PORTS = range(20000, 32000)   # below what the kernel hands to outgoing
+#                                 connections: a port got by binding to 0
+#                                 can be some client's source port by the
+#                                 time the role binds it
+PORT_SLICE = 40
+_next_port = [0]
+
+
 def free_port() -> int:
-    """A port no one listens on, taken below the range the kernel hands
-    to outgoing connections: a port got by binding to 0 can be some
-    client's source port by the time the role binds it (seen once in a
-    tier-1 run of six test workers at a time)."""
-    while True:
-        port = random.randrange(20000, 32000)
+    """A port no one listens on, from a slice of `PORTS` that is this
+    process's own (by its pid), taken in turn: two harnesses at work at
+    once (six test workers rehearse side by side) drew the same random
+    port now and then, each found it free, and the second role to bind
+    it died with "Address already in use" (seen in tier-1 runs)."""
+    slices = len(PORTS) // PORT_SLICE
+    base = PORTS.start + os.getpid() % slices * PORT_SLICE
+    for _ in range(PORT_SLICE):
+        port = base + _next_port[0] % PORT_SLICE
+        _next_port[0] += 1
         with socket.socket() as s:
             try:
                 s.bind(("127.0.0.1", port))
             except OSError:
                 continue
             return port
+    raise BenchFailure(f"no free port in {base}-{base + PORT_SLICE - 1}")
 
 
 def wait_for(what: str, fn, timeout: float, every: float = 0.1):
@@ -490,12 +502,17 @@ class Cluster:
 
     # -- jobs ---------------------------------------------------------------
 
-    def submit_encode(self, vol: dict, timeout: float = 180.0) -> str:
+    def submit_encode(self, vols: "list[dict]",
+                      timeout: float = 180.0) -> str:
+        """One `ec.encode` job of one volume (`volumeId`) or of several
+        of one collection (`volumeIds`: the worker's batch path)."""
         from seaweedfs_tpu.server.httpd import http_json
-        body = {"jobType": "erasure_coding", "params": {
-            "volumeId": vol["vid"], "collection": vol["collection"],
-            "dataShards": self.cfg["data_shards"],
-            "parityShards": self.cfg["parity_shards"]}}
+        which = {"volumeId": vols[0]["vid"]} if len(vols) == 1 else \
+            {"volumeIds": [v["vid"] for v in vols]}
+        body = {"jobType": "erasure_coding", "params": dict(
+            which, collection=vols[0]["collection"],
+            dataShards=self.cfg["data_shards"],
+            parityShards=self.cfg["parity_shards"])}
         return wait_for("the admin to take a job", lambda: http_json(
             "POST", f"{self.admin}/maintenance/submit_job", body
         ).get("jobId"), timeout, every=0.2)

@@ -21,7 +21,7 @@ def flip_parity_byte() -> Hooks:
     """One byte of one parity shard of the window's first job, altered
     where the job left it (an answer altered where it is produced)."""
     def before_verify(cluster, state):
-        vol = next(j["vol"] for j in state["jobs"] if j["ok"])
+        vol = next(j["vols"][0] for j in state["jobs"] if j["ok"])
         paths = cluster.shard_paths(vol)
         last = max(paths)
         _flip(paths[last][0], os.path.getsize(paths[last][0]) // 2)
@@ -33,7 +33,7 @@ def lose_shard() -> Hooks:
     configuration's placement guarantee broken."""
     def before_verify(cluster, state):
         from seaweedfs_tpu.server.httpd import http_json
-        vol = [j["vol"] for j in state["jobs"] if j["ok"]][-1]
+        vol = [j["vols"][-1] for j in state["jobs"] if j["ok"]][-1]
         paths = cluster.shard_paths(vol)
         holder = cluster.vol_urls[cluster.vol_dirs.index(
             os.path.dirname(paths[0][0]))]

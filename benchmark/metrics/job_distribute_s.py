@@ -1,6 +1,5 @@
 """Seconds of the `ec.distribute` span (placement, every push, every
-mount), mean over the window's jobs: the program's own span, where
-`job_copy_share` cuts the phase out of the progress messages."""
+mount), mean over the window's jobs: the program's own span."""
 
 from benchmark import job_trace
 

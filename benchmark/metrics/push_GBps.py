@@ -1,5 +1,7 @@
 """GB/s of the shard pushes: the bytes of the window's `ec.push` spans
-over their seconds (one span a pushed file, one after another)."""
+over the sum of their seconds (one span a pushed file): one stream's
+rate where a job's pushes run on several streams at once;
+`push_phase_GBps` has the phase's."""
 
 from benchmark import job_trace
 

@@ -134,21 +134,23 @@ def job_rate_GBps(jobs: "list[dict]") -> "float | None":
     return done / span / 1e9 if span > 0 and done > 0 else None
 
 
-def run_chain(volumes: "list[dict]", seconds: float, t_open: float,
-              submit, wait, now=time.time) -> "tuple[list[dict], bool]":
-    """Jobs back to back: the first at the window's opening, each next
-    as its predecessor ends, none after `seconds` have passed; the one
-    in flight then runs to its end.  Returns the jobs and the seconds
-    of the window left with no job because the volumes ran out."""
+def run_chain(groups: "list[list[dict]]", seconds: float, t_open: float,
+              submit, wait, now=time.time) -> "tuple[list[dict], float]":
+    """Jobs back to back, one job a group of volumes: the first at the
+    window's opening, each next as its predecessor ends, none after
+    `seconds` have passed; the one in flight then runs to its end.
+    Returns the jobs and the seconds of the window left with no job
+    because the groups ran out."""
     jobs = []
-    for vol in volumes:
+    for group in groups:
         t = now()
         if t >= t_open + seconds:
             return jobs, 0.0
-        job_id = submit(vol)
+        job_id = submit(group)
         state = wait(job_id)
-        jobs.append({"id": job_id, "vid": vol["vid"], "vol": vol,
-                     "bytes": vol["bytes"], "submit": t, "finish": now(),
+        jobs.append({"id": job_id, "vids": [v["vid"] for v in group],
+                     "vols": group, "bytes": sum(v["bytes"] for v in group),
+                     "submit": t, "finish": now(),
                      "ok": state["status"] == "done",
                      "message": state.get("message", "")})
     return jobs, max(0.0, t_open + seconds - now())
@@ -163,14 +165,26 @@ def volumes_for(seconds: float, job_seconds: float, margin: float) -> int:
 # The share of the machine's memory that a run's data root may come to
 # hold.  Every volume of a window rests in memory until the comparison
 # after it, so a faster program asks for more of them; the machine has
-# no more to give.  Two thirds of the 45.0 GiB chip host is 32.2 GB,
-# what the accepted runs were seen to peak at (31.8 GB of Shmem with
-# 8.3 GB left available, PR 28); the third left is the roles', the
-# chip's owner's (it pins some 4.7 GB while it initialises) and the
-# comparison's.  One number for every cell: no flag, no variable.
+# no more to give.  The machine is the least of `MemTotal`, a cgroup
+# limit and what the chip tool holds its machine to from outside, which
+# no file on it says: "ran out of memory. It met the machine's limit
+# of 40 GiB" is the tool's own message where it ended a run on a host
+# whose /proc/meminfo says 45.0 GiB (PERF.md 7, PR 32).  Two thirds of
+# that is 28.63 GB; the third left is the roles', the chip's owner's
+# (it pins some 4.7 GB while it initialises), the comparison's, and
+# the tmpfs pages of the run before, which come back over seconds.
+# One number for every cell: no flag, no variable.
+MACHINE_LIMIT_BYTES = 40 * 2**30
 MEMORY_SHARE = 2 / 3
 SHORT_WINDOW_S = 20.0         # a window the budget closes under this is
 #                               too short a measure: said loudly
+
+
+def memory_budget(total: int) -> int:
+    """Bytes a run's data root may come to hold on a machine whose
+    memory (`MemTotal`, or a cgroup's limit where that is less) is
+    `total`."""
+    return int(min(total, MACHINE_LIMIT_BYTES) * MEMORY_SHARE)
 
 
 def set_bytes(cfg: dict, vol_bytes: int, n: int) -> int:
@@ -193,6 +207,21 @@ def volumes_within(cfg: dict, vol_bytes: int, room: float) -> int:
     return n
 
 
+def burst_of(cfg: dict, traffic_jobs: dict) -> "int | None":
+    """The stated number of jobs of a traffic file whose `jobs` name,
+    under `count_from`, the configuration's key that holds it: that
+    many back to back from the window's opening and no more, whatever a
+    job takes.  None where the jobs fill the window."""
+    key = traffic_jobs.get("count_from")
+    if key is None:
+        return None
+    count = cfg.get(key)
+    if not isinstance(count, int) or count < 1:
+        raise BenchFailure(f"jobs.count_from {key!r}: the configuration "
+                           f"has {count!r} there, not a count of jobs")
+    return count
+
+
 def check_room(what: str, n: int, need: int, have: int) -> None:
     """Set-up ends here, with both numbers, rather than the machine
     running out of memory under a window."""
@@ -202,14 +231,18 @@ def check_room(what: str, n: int, need: int, have: int) -> None:
             f"{what} and {have} bytes ({have / 1e9:.2f} GB) are there")
 
 
-def close_of(wanted: int, n_budget: int, started: int,
-             dry: float) -> "tuple[str, float]":
+def close_of(wanted: int, n_budget: int, started: int, dry: float,
+             burst: bool = False) -> "tuple[str, float]":
     """("seconds" | "budget", `chain_dry_s`).  Seconds of the window
     left with no job are a fault when the run loaded what it asked for
-    and still ran out.  They are the rule only when the memory budget
-    cut the volumes and the chain started a job on every one it left:
-    then the budget's last job closed the window, jobs back to back
-    until then."""
+    and still ran out.  They are the rule in two cases, each only when
+    the chain started a job on every volume it was meant to have.  The
+    memory budget cut the volumes: then the budget's last job closed
+    the window, jobs back to back until then.  Or the traffic states a
+    burst of `wanted` jobs: then the window stays open to its seconds,
+    for what runs beside the jobs."""
+    if dry > 0 and burst and started == wanted:
+        return "seconds", 0.0
     if dry > 0 and wanted > n_budget and started == n_budget:
         return "budget", 0.0
     return "seconds", dry
@@ -249,10 +282,13 @@ def job_phases(log: "list[list]") -> "dict[str, dict]":
 class Hooks:
     """Where the tests and the control break the timed path: each a
     callable (cluster, state) -> None.  `memory_total` stands in for
-    the machine's memory, so that a toy run can meet its budget."""
+    the machine's `MemTotal`, so that a toy run can meet its budget;
+    `root` for the checkout whose BENCHMARK.json and benchmark/ files
+    name the cell, so that a test can bring entries of its own."""
     before_window: "object | None" = None
     before_verify: "object | None" = None
     memory_total: "int | None" = None
+    root: "str | None" = None
 
 
 def say(msg: str) -> None:
@@ -310,8 +346,8 @@ class Run:
 
     def __init__(self, args, hooks: Hooks):
         self.args, self.hooks = args, hooks
-        self.spec = load_spec(held=args.held)
-        files = cell_files(self.spec, args.workload)
+        self.spec = load_spec(hooks.root or REPO, held=args.held)
+        files = cell_files(self.spec, args.workload, hooks.root or REPO)
         self.cell, self.bench_dir = files["cell"], files["bench_dir"]
         cfg, traffic = files["cfg"], files["traffic"]
         if args.rehearse:
@@ -319,6 +355,7 @@ class Run:
             traffic = dict(traffic, **traffic.get("rehearse", {}))
         self.cfg, self.traffic = cfg, traffic
         self.reads, self.jobs_t = traffic.get("reads"), traffic.get("jobs")
+        self.burst = burst_of(cfg, self.jobs_t) if self.jobs_t else None
         self.seconds = float(args.seconds)
         self.job_shape = (cfg["needles_per_volume"], cfg["needle_bytes"])
         # the objects clients read may be other than what a sealed
@@ -361,7 +398,7 @@ class Run:
         for vol in first:
             c0 = self.cluster.wire.ask("mark")["compile"]["seconds"]
             t0 = time.perf_counter()
-            j = self.cluster.wait_job(self.cluster.submit_encode(vol),
+            j = self.cluster.wait_job(self.cluster.submit_encode([vol]),
                                       JOB_TIMEOUT_S)
             took = time.perf_counter() - t0
             compiling = self.cluster.wire.ask(
@@ -381,31 +418,46 @@ class Run:
 
     def load_job_volumes(self, took: float, vol_bytes: int,
                          first_index: int) -> "list[dict]":
-        """As many volumes as the window can start jobs on, reckoned
-        from the set-up's own job of that shape: none loaded idle, more
-        of them when a later PR makes a job shorter, and never more
-        than the memory budget holds: past that the window closes at
-        the budget's last job (`close_of`)."""
-        root, margin = self.root, self.jobs_t["job_seconds_margin"]
+        """As many volumes as the window can start jobs on.  Where the
+        jobs fill the window that is reckoned from the set-up's own job
+        of that shape: none loaded idle, more of them when a later PR
+        makes a job shorter, and never more than the memory budget
+        holds: past that the window closes at the budget's last job
+        (`close_of`).  A burst is its stated count, whatever a job
+        takes, and one the budget cannot hold is not cut: set-up
+        fails."""
+        root = self.root
         mem = cl.memory_now()
         total = self.hooks.memory_total or mem["total"]
-        budget, resident = int(total * MEMORY_SHARE), cl.tree_bytes(root)
-        wanted = volumes_for(self.seconds, took, margin)
+        budget, resident = memory_budget(total), cl.tree_bytes(root)
+        if self.burst:
+            wanted = self.burst
+            why = f"a burst of {wanted}, the configuration's " \
+                f"{self.jobs_t['count_from']}"
+        else:
+            margin = self.jobs_t["job_seconds_margin"]
+            wanted = volumes_for(self.seconds, took, margin)
+            why = f"{took:.2f}s a job at margin {margin}"
         n_budget = volumes_within(self.cfg, vol_bytes, budget - resident)
         n = min(wanted, n_budget)
         need = set_bytes(self.cfg, vol_bytes, max(n, 1))
         free = cl.free_bytes(root)
         self.sizing = {"wanted": wanted, "n_budget": n_budget, "loaded": n,
                        "budget": budget, "total": total}
-        say(f"window's volumes: wanted {wanted} ({self.seconds:.0f}s of "
-            f"{took:.2f}s jobs at margin {margin}), budget {n_budget} "
-            f"({budget / 1e9:.2f} GB, {MEMORY_SHARE:.3f} of "
-            f"{total / 1e9:.2f} GB, {resident / 1e9:.2f} GB resident, "
-            f"{vol_bytes} bytes a volume), loading {n}: at most "
+        say(f"window's volumes: wanted {wanted} ({self.seconds:.0f}s, {why}), "
+            f"budget {n_budget} ({budget / 1e9:.2f} GB, "
+            f"{MEMORY_SHARE:.3f} of the least of MemTotal "
+            f"{total / 1e9:.2f} GB and the machine's limit "
+            f"{MACHINE_LIMIT_BYTES / 1e9:.2f} GB; {resident / 1e9:.2f} GB "
+            f"resident, {vol_bytes} bytes a volume), loading {n}: at most "
             f"{need / 1e9:.2f} GB more; MemAvailable "
             f"{mem['available'] / 1e9:.2f} GB, Shmem "
             f"{mem['shmem'] / 1e9:.2f} GB, data root free "
             f"{free / 1e9:.2f} GB")
+        if self.burst and wanted > n_budget:
+            raise BenchFailure(
+                f"the burst is {wanted} job volumes ({why}) and the memory "
+                f"budget holds {n_budget}: a burst is not cut")
         check_room("the memory budget", max(n, 1), resident + need, budget)
         check_room("available memory", n, need, mem["available"])
         check_room("the data root", n, need, free)
@@ -461,7 +513,7 @@ class Run:
             try:
                 time.sleep(max(0.0, t_open - time.time()))
                 chain["jobs"], chain["dry"] = run_chain(
-                    self.job_vols, self.seconds, t_open,
+                    [[v] for v in self.job_vols], self.seconds, t_open,
                     cluster.submit_encode,
                     lambda jid: cluster.wait_job(jid, JOB_TIMEOUT_S))
                 if reads and time.time() < self.t_stop:
@@ -492,8 +544,10 @@ class Run:
                                    f"{chain.get('error', 'still running')}")
         self.jobs = jobs = chain["jobs"]
         sz = self.sizing or {"wanted": 0, "n_budget": 0}
+        self.started = sum(len(j["vols"]) for j in jobs)     # volumes
         self.closed_by, self.chain_dry_s = close_of(
-            sz["wanted"], sz["n_budget"], len(jobs), chain["dry"])
+            sz["wanted"], sz["n_budget"], self.started, chain["dry"],
+            burst=bool(self.burst))
         # closed by the budget, the window is the seconds the chain ran:
         # what is read beside the jobs is read over those
         self.t_end = max(j["finish"] for j in jobs) \
@@ -531,7 +585,8 @@ class Run:
                     if isinstance(mark1[key][k], (int, float))}
         self.ctx = {
             "cfg": self.cfg, "traffic": self.traffic, "device": self.dev,
-            "window": {"open": t_open, "close": self.t_close},
+            "window": {"open": t_open, "close": self.t_close,
+                       "end": self.t_end},
             "jobs": jobs, "staging": delta("staging"),
             "compile": delta("compile"), "reads": self.read_sum,
             "volume_counters": None if vreq0 is None else
@@ -552,7 +607,7 @@ class Run:
             sz, ran = self.sizing, self.t_close - self.t_open
             say(f"window's volumes: wanted {sz['wanted']}, budget "
                 f"{sz['n_budget']}, loaded {sz['loaded']}, started "
-                f"{len(jobs)}; closed by " + (
+                f"{self.started}; closed by " + (
                     f"budget after {ran:.3f}s" if self.closed_by == "budget"
                     else f"seconds ({self.seconds:.0f}s)"
                     + (f", {self.chain_dry_s:.3f}s of them with no job: "
@@ -566,8 +621,14 @@ class Run:
                 say(f"memory in the window ({seen['reads']} readings): "
                     f"least MemAvailable {seen['available'] / 1e9:.2f} GB, "
                     f"peak Shmem {seen['shmem'] / 1e9:.2f} GB; budget "
-                    f"{sz['budget'] / 1e9:.2f} GB of "
+                    f"{sz['budget'] / 1e9:.2f} GB, MemTotal "
                     f"{sz['total'] / 1e9:.2f} GB")
+            if self.burst:
+                last = max([j["finish"] for j in jobs] + [self.t_open])
+                say(f"burst: {len(jobs)} of {self.burst} jobs started back "
+                    f"to back from the opening, the last ended at "
+                    f"+{last - self.t_open:.3f}s of the window's "
+                    f"{self.seconds:.0f}s")
         say(f"window: {self.t_close - self.t_open:.3f}s, {len(jobs)} jobs "
             f"({sum(not j['ok'] for j in jobs)} failed)"
             + (f", {rs['requests']} requests ({rs['failed']} failed, "
@@ -577,7 +638,7 @@ class Run:
                f"{rs['late_mean_ms']:.3f} ms max {rs['late_max_ms']:.3f} ms"
                if rs else ""))
         for j in jobs:
-            say(f"  job {j['id']} vol {j['vid']}: "
+            say(f"  job {j['id']} vol {','.join(map(str, j['vids']))}: "
                 f"{j['finish'] - j['submit']:.3f}s ok={j['ok']} " +
                 " ".join(f"{n}={e - s:.3f}"
                          for n, (s, e) in j["phases"].items())
@@ -621,7 +682,7 @@ class Run:
         if self.hooks.before_verify:
             self.hooks.before_verify(cluster, self.state)
         t0 = time.perf_counter()
-        done = [j["vol"] for j in jobs if j["ok"]]
+        done = [v for j in jobs if j["ok"] for v in j["vols"]]
         compared = {}
         if self.jobs_t:
             k, total = cfg["data_shards"], \
@@ -677,8 +738,7 @@ class Run:
         if self.jobs_t and self.jobs_t["role"] == "foreground":
             values["ec_GBps"] = job_rate_GBps(jobs)
         if rs:
-            values.update({k: rs[k] for k in (
-                "read_rps", "read_p50_ms", "read_p99_ms")})
+            values.update({k: rs[k] for k in ("read_rps", "read_p99_ms")})
         metrics = {}
         for m in metrics_of(self.spec, "per_layer" if args.trace
                             else "end_to_end", args.workload):
@@ -717,7 +777,8 @@ def run_cell(args, hooks: "Hooks | None" = None) -> dict:
     # and checked once the set-up's job has been timed
     vol = run.job_shape[0] * run.job_shape[1]
     n = min(volumes_for(run.seconds, 3.0, 1.0), volumes_within(
-        run.cfg, vol, cl.memory_now()["total"] * MEMORY_SHARE)
+        run.cfg, vol, memory_budget(
+            run.hooks.memory_total or cl.memory_now()["total"]))
     ) if run.jobs_t else 0
     parent, kind = cl.choose_data_root(
         2 * sum(count * size for count, size in run.first_shapes())

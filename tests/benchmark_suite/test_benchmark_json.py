@@ -8,6 +8,7 @@ import shutil
 
 import pytest
 
+from bench_tree import bench_tree  # noqa: F401 — a fixture
 from benchmark import run
 
 ROOT = run.REPO
@@ -154,9 +155,9 @@ def test_a_new_cell_config_and_metric_are_files_and_entries(tmp_path, spec):
     new["workloads"].append({"name": "dummy_cfg.dummy_mix",
                              "config": "dummy_cfg", "traffic": "dummy_mix",
                              "chips": 1, "why": "test"})
-    held = json.load(open(tmp_path / "benchmark/held_cells.json"))
-    for m in held["end_to_end"]:       # the read metrics come with it
-        new["end_to_end"].append(dict(m, workloads=["dummy_cfg.dummy_mix"]))
+    for m in new["end_to_end"]:        # the read metrics come with it
+        if m["name"].startswith("read_"):
+            m["workloads"].append("dummy_cfg.dummy_mix")
     new["per_layer"].append({
         "name": "dummy_metric", "unit": "ms", "better": "lower",
         "source": "host_clock", "layer": "benchmark generator",
@@ -169,34 +170,141 @@ def test_a_new_cell_config_and_metric_are_files_and_entries(tmp_path, spec):
     assert got["traffic"]["what"] == "reads alone"
     assert {m["name"] for m in run.metrics_of(
         got_spec, "end_to_end", "dummy_cfg.dummy_mix")} == {
-        "read_rps", "read_p50_ms", "read_p99_ms", "setup_s"}
+        "read_rps", "read_p99_ms", "setup_s"}
     layer = run.metrics_of(got_spec, "per_layer", "dummy_cfg.dummy_mix")
     assert [m["name"] for m in layer] == ["dummy_metric"]
     read = run.metric_reader(got["bench_dir"], "dummy_metric")
     assert read({"reads": {"late_max_ms": 1.5}}) == 1.5
 
 
-def test_held_cells_are_whole_and_stand_outside_the_benchmark(spec):
+HELD = "ec6_3_serve.read_under_encode"
+
+
+def test_held_cells_are_whole_and_stand_outside_the_benchmark(bench_tree):
     """What benchmark/held_cells.json keeps is well-formed as it
-    stands, collides with nothing, and is found only when asked for."""
-    with open(os.path.join(ROOT, "benchmark", "held_cells.json")) as f:
-        held = json.load(f)
-    both = run.load_spec(held=True)
+    stands, collides with nothing, and is found only when asked for:
+    shown on a checkout under `tmp_path` in which a cell is held, since
+    the repository's own file holds none."""
+    moved = bench_tree.hold(HELD, "a test holds it")
+    root = bench_tree.root
+    spec, held = run.load_spec(root), bench_tree.read(
+        "benchmark/held_cells.json")
+    assert [len(moved[k]) for k in ("configs", "workloads", "end_to_end")] \
+        == [1, 1, 2] and len(moved["per_layer"]) >= 9
+    both = run.load_spec(root, held=True)
     for key in ("configs", "workloads", "end_to_end", "per_layer"):
         names = [e["name"] for e in both[key]]
         assert len(names) == len(set(names))
         assert len(both[key]) == len(spec[key]) + len(held[key])
         assert not {e["name"] for e in held[key]} & \
             {e["name"] for e in spec[key]}
+        assert sorted(names) == sorted(
+            e["name"] for e in run.load_spec()[key])
     assert set(held["why_held"]) == {w["name"] for w in held["workloads"]}
     test_names_units_and_lines(both)
     test_every_cell_reports_enough(both)
     test_per_layer_moves_one_metric_its_cells_report(both)
     for w in held["workloads"]:
-        got = run.cell_files(both, w["name"])
+        got = run.cell_files(both, w["name"], root)
         assert got["cfg"]["name"] == w["config"]
+        assert got["bench_dir"] == bench_tree.path("benchmark")
         with pytest.raises(run.BenchFailure):
-            run.cell_files(spec, w["name"])
+            run.cell_files(spec, w["name"], root)
     for m in held["per_layer"]:
         assert callable(run.metric_reader(
-            os.path.join(ROOT, "benchmark"), m["name"]))
+            bench_tree.path("benchmark"), m["name"]))
+
+
+def test_the_repositorys_own_held_file_keeps_its_form_and_holds_nothing(
+        spec):
+    with open(os.path.join(ROOT, "benchmark", "held_cells.json")) as f:
+        held = json.load(f)
+    assert set(held) == {"what", "why_held", "configs", "workloads",
+                         "end_to_end", "per_layer"}
+    assert held["why_held"] == {} and "--held" in held["what"]
+    assert [held[k] for k in ("configs", "workloads", "end_to_end",
+                              "per_layer")] == [[], [], [], []]
+    assert run.load_spec(held=True) == spec
+
+
+PRUNED = ["job_copy_share", "staging_pack_share.live",
+          "staging_slot_wait_s.live", "staging_ready_wait_s.live"]
+
+
+@pytest.mark.parametrize("name", PRUNED)
+def test_an_entry_that_said_nothing_is_gone(spec, name):
+    """Constants since PR 29, or another entry said again (PERF.md 6,
+    PR 33).  The three constants' entries in the encode cell, and
+    `staging_launch_ratio`, wait for a PR that may edit
+    tests/test_window_work_items.py, which indexes them (PERF.md 7)."""
+    assert name not in {m["name"] for m in spec["per_layer"]}
+    stem = name[:-len(".live")] if name.endswith(".live") else name
+    twin = stem in {m["name"] for m in spec["per_layer"]}
+    assert os.path.exists(os.path.join(ROOT, "benchmark", "metrics",
+                                       stem + ".py")) == twin
+
+
+def test_three_cells_one_chip_each_and_the_guard_is_end_to_end(spec):
+    cells = {w["name"]: w for w in spec["workloads"]}
+    assert sorted(cells) == ["ec10_4_live.encode_under_read",
+                             "ec10_4_vol1g.encode", HELD]
+    assert {w["chips"] for w in cells.values()} == {1}
+    assert len(spec["configs"]) == 3 and spec["run_seconds"] == 50
+    e2e = {m["name"]: m for m in spec["end_to_end"]}
+    assert sorted(e2e) == ["ec_GBps", "read_p99_ms", "read_rps", "setup_s"]
+    assert e2e["ec_GBps"]["bound"] == 0.1 and \
+        e2e["setup_s"]["bound"] == 0.25 and "workloads" not in e2e["setup_s"]
+    assert sorted(e2e["ec_GBps"]["workloads"]) == sorted(set(cells) - {HELD})
+    for name, better in (("read_rps", "higher"), ("read_p99_ms", "lower")):
+        m = e2e[name]
+        assert m["workloads"] == [HELD] and m["better"] == better
+        assert m["source"] == "host_clock"
+    assert {m["name"] for m in run.metrics_of(spec, "end_to_end", HELD)} \
+        == {"read_rps", "read_p99_ms", "setup_s"}
+    # every per-layer entry says where it has something to read
+    assert all(m.get("workloads") for m in spec["per_layer"])
+    t = run.cell_files(spec, HELD)["traffic"]
+    # the background is a burst of the configuration's count, which is
+    # a cut of scale and listed as one; nothing in the traffic is assumed
+    assert t["jobs"] == {"role": "background", "order": "back_to_back",
+                         "count_from": "encode_burst_volumes"}
+    assert "assumed" not in t and "period_s" not in json.dumps(t)
+    cfg = run.cell_files(spec, HELD)["cfg"]
+    assert run.burst_of(cfg, t["jobs"]) == 14
+    conf = {c["name"]: c for c in spec["configs"]}["ec6_3_serve"]
+    assert conf["reduced"] == cfg["reduced"] == ["read_objects",
+                                                 "encode_burst_volumes"]
+
+
+GUARD_LAYER = {   # name: (unit, better, source, layer, moves)
+    "device_idle_share.rd": ("share", "lower", "device_trace", "device",
+                             "read_p99_ms"),
+    "compiles_in_window.rd": ("count", "lower", "program_counter",
+                              "device selection", "read_p99_ms"),
+    "bg_encode_GBps": ("GB/s", "higher", "host_clock", "maintenance plane",
+                       "read_p99_ms"),
+    "rd_volume_request_ms": ("ms", "lower", "program_counter",
+                             "serving planes", "read_p99_ms"),
+    "rd_needle_cache_hit_share": ("share", "higher", "program_counter",
+                                  "serving planes", "read_p99_ms"),
+    "rd_generator_late_ms": ("ms", "lower", "host_clock",
+                             "benchmark generator", "read_p99_ms"),
+    "bg_busy_share": ("share", "lower", "host_clock", "maintenance plane",
+                      "read_p99_ms"),
+    "rd_p50_ms": ("ms", "lower", "host_clock", "serving planes",
+                  "read_rps"),
+    "hb_errors.rd": ("count", "lower", "program_counter", "serving planes",
+                     "read_p99_ms"),
+    "rd_remote_interval_share.rd": ("share", "lower", "program_counter",
+                                    "serving planes", "read_p99_ms"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(GUARD_LAYER))
+def test_the_guards_per_layer_entries(spec, name):
+    m = {e["name"]: e for e in spec["per_layer"]}[name]
+    assert (m["unit"], m["better"], m["source"], m["layer"], m["moves"]) \
+        == GUARD_LAYER[name]
+    assert m["workloads"] == [HELD]
+    assert callable(run.metric_reader(os.path.join(ROOT, "benchmark"), name))
+    assert m in run.metrics_of(spec, "per_layer", HELD)

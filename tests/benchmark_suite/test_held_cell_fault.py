@@ -1,22 +1,18 @@
-"""The fault of the program that holds `ec6_3_serve.read_under_encode`
-out of BENCHMARK.json (benchmark/held_cells.json, PERF.md 7): a volume
-server's heartbeat thread runs `Store.collect_heartbeat()`, which walks
+"""The fault of the program that held `ec6_3_serve.read_under_encode`
+out of BENCHMARK.json from PR 24 to PR 33 (PERF.md 6): a volume
+server's heartbeat thread ran `Store.collect_heartbeat()`, which walked
 the volume tables without the store's lock, and `_heartbeat_loop`
-catches nothing; a volume deleted or mounted under its feet raises
-"dictionary changed size during iteration" and the thread is gone.  The
-test asks only that collecting survives volumes coming and going; it is
-expected to fail until the program is mended, and the cell goes back
-when it passes."""
+caught nothing; a volume deleted or mounted under its feet raised
+"dictionary changed size during iteration" and the thread was gone.
+PR 27 mended it (the tables are copied under the lock), `hb_errors` has
+read 0 on every ledger line since, and the cell is back: this test
+asks that collecting goes on surviving volumes coming and going."""
 
 import collections
 import threading
 import time
 
-import pytest
 
-
-@pytest.mark.xfail(strict=False, reason="program fault: collect_heartbeat "
-                   "iterates the volume tables without the store's lock")
 def test_collecting_a_heartbeat_survives_volumes_coming_and_going(tmp_path):
     from seaweedfs_tpu.storage.store import Store
     store = Store([str(tmp_path)], ip="127.0.0.1", port=1)

@@ -25,6 +25,8 @@ NEW = {
     "staging_pad_share": ("share", "lower", "program_counter", "staging"),
     "staging_slot_wait_s": ("s", "lower", "program_counter", "staging"),
     "staging_ready_wait_s": ("s", "lower", "program_counter", "staging"),
+    "push_phase_GBps": ("GB/s", "higher", "program_span",
+                        "maintenance plane"),
 }
 BENCH = os.path.join(run.REPO, "benchmark")
 
@@ -135,6 +137,64 @@ def test_span_readers_on_the_recording(recorded):
         sum(s["attrs"]["cpuSeconds"] for s in recv) / took)
     assert 0.5 < reader("push_sender_cpu_share")(ctx) <= 1.02
     assert 0.2 < reader("push_receiver_cpu_share")(ctx) <= 1.02
+    # PR 25's program has no `pushSeconds` on its distribute span
+    assert reader("push_phase_GBps")(ctx) is None
+
+
+def without_receivers(traces, lost):
+    """The traces as a volume role's ring leaves them once it has
+    turned over: the receiver spans of the first `lost` jobs gone."""
+    return [[s for s in t if i >= lost
+             or s["name"] != "POST /admin/receive_file"]
+            for i, t in enumerate(traces)]
+
+
+@pytest.mark.parametrize("lost", [0, 1, 3])
+def test_the_receivers_share_counts_only_the_pushes_whose_span_was_found(
+        recorded, lost):
+    """Divided by the seconds of every push, a rolled-over ring read
+    as a receiver that waits (0.61 printed where 0.94 was true, PR 32)."""
+    ctx, traces = recorded
+    job_trace.preload(ctx, traces)
+    whole = reader("push_receiver_cpu_share")(ctx)
+    job_trace.preload(ctx, without_receivers(traces, lost))
+    got = reader("push_receiver_cpu_share")(ctx)
+    kept = traces[lost:]
+    assert got == pytest.approx(
+        sum(s["attrs"]["cpuSeconds"] for s in spans_of(
+            kept, "POST /admin/receive_file", "volume"))
+        / (sum(s["durationMs"] for s in spans_of(kept, "ec.push")) / 1e3))
+    assert got == pytest.approx(whole, rel=0.25)
+    # the sender's share and the stream's rate still count every push
+    assert reader("push_GBps")(ctx) == pytest.approx(
+        sum(s["attrs"]["bytes"] for s in spans_of(traces, "ec.push"))
+        / (sum(s["durationMs"] for s in spans_of(traces, "ec.push")) / 1e3)
+        / 1e9)
+
+
+def test_no_receiver_span_found_is_nothing_not_nought(recorded):
+    ctx, traces = recorded
+    job_trace.preload(ctx, without_receivers(traces, len(traces)))
+    assert reader("push_receiver_cpu_share")(ctx) is None
+    assert reader("push_sender_cpu_share")(ctx) is not None
+
+
+@pytest.mark.parametrize("push_seconds,want", [
+    ([0.5, 0.5], 2.0), ([0.25, 1.0], 1.6), ([None, 0.5], 2.0),
+    ([None, None], None)])
+def test_the_phases_rate_is_the_bytes_over_push_seconds(push_seconds, want):
+    """`ec.distribute` {bytes, pushSeconds}: first push's start to the
+    last one's end; a span without the attribute (an older program's)
+    is left out, bytes and all."""
+    ctx = {"jobs": [{"id": f"j{i}", "ok": True}
+                    for i in range(len(push_seconds))]}
+    job_trace.preload(ctx, [[{
+        "spanId": f"d{i}", "name": "ec.distribute", "role": "worker",
+        "start": float(i), "durationMs": 900.0, "attrs": dict(
+            {"bytes": 10**9}, **({} if p is None else {"pushSeconds": p}))}]
+        for i, p in enumerate(push_seconds)])
+    got = reader("push_phase_GBps")(ctx)
+    assert got == (None if want is None else pytest.approx(want))
 
 
 def test_enc_idle_h2d_share_on_the_recording(recorded):
@@ -234,8 +294,10 @@ def test_traced_rehearsal_finds_its_roles_and_prints_the_new_metrics(
         seen["roles"] = job_trace.child_roles()
         seen["cluster"] = {"admin": [cluster.admin],
                            "volume": sorted(cluster.vol_urls)}
+    # 4 s, not 2: beside five other test workers a toy job has taken
+    # over 2 s, and one job is no second line to compare
     code = run.main(["--workload", CELL, "--seed", "2147484025",
-                     "--seconds", "2", "--trace", "1", "--rehearse"],
+                     "--seconds", "4", "--trace", "1", "--rehearse"],
                     run.Hooks(before_verify=look))
     out = capfd.readouterr().out
     assert code == 0, out[-3000:]
@@ -260,6 +322,8 @@ def test_traced_rehearsal_finds_its_roles_and_prints_the_new_metrics(
     # hand-off queue inside a launch
     assert m["staging_pack_share"] == 0
     assert m["staging_slot_wait_s"] == 0 and m["staging_ready_wait_s"] == 0
+    # the phase's rate is no less than one stream's
+    assert m["push_phase_GBps"] >= m["push_GBps"] * 0.99
     # the program's span agrees with the phase the harness cuts out of
     # the progress messages, job by job
     both = [ln for ln in out.splitlines() if "by progress marks" in ln]

@@ -61,20 +61,15 @@ NEW = {
                                    "push_sender_cpu_share"),
     "enc_idle_h2d_share.live": ("share", "lower", "device_trace",
                                 "EC file pipeline", "enc_idle_h2d_share"),
-    "staging_pack_share.live": ("share", "lower", "program_counter",
-                                "staging", "staging_pack_share"),
     "staging_pad_share.live": ("share", "lower", "program_counter",
                                "staging", "staging_pad_share"),
-    "staging_slot_wait_s.live": ("s", "lower", "program_counter",
-                                 "staging", "staging_slot_wait_s"),
-    "staging_ready_wait_s.live": ("s", "lower", "program_counter",
-                                  "staging", "staging_ready_wait_s"),
+    "push_phase_GBps.live": ("GB/s", "higher", "program_span",
+                             "maintenance plane", "push_phase_GBps"),
 }
-# what `ec10_4_vol1g.encode` reported before this cell came (PR 24's
-# nine, PR 25's nine): name: (unit, better, source, layer)
+# what `ec10_4_vol1g.encode` reports (PR 24's nine and PR 25's nine,
+# less `job_copy_share`, which PR 33 pruned, and PR 33's one):
+# name: (unit, better, source, layer)
 OLDER = {
-    "job_copy_share": ("share", "lower", "program_span",
-                       "maintenance plane"),
     "job_encode_s": ("s", "lower", "program_span", "EC file pipeline"),
     "enc_write_busy_s": ("s", "lower", "program_span", "EC file pipeline"),
     "staged_h2d_GBps": ("GB/s", "higher", "program_counter", "staging"),
@@ -97,13 +92,16 @@ OLDER = {
     "staging_pad_share": ("share", "lower", "program_counter", "staging"),
     "staging_slot_wait_s": ("s", "lower", "program_counter", "staging"),
     "staging_ready_wait_s": ("s", "lower", "program_counter", "staging"),
+    "push_phase_GBps": ("GB/s", "higher", "program_span",
+                        "maintenance plane"),
 }
 # the twin's metrics of layers this cell runs too that it does not
-# report, each to be retired (PERF.md 7): the first is the spans' sum
-# said again, the other two say what staging_pack_share and
-# staging_pad_share say
-LEFT_TO_THE_TWIN = {"job_copy_share", "staged_h2d_GBps",
-                    "staging_launch_ratio"}
+# report, each to be retired (PERF.md 7): two say what
+# `staging_pad_share` says, three are constants since PR 29 whose
+# `.live` entries PR 33 pruned
+LEFT_TO_THE_TWIN = {"staged_h2d_GBps", "staging_launch_ratio",
+                    "staging_pack_share", "staging_slot_wait_s",
+                    "staging_ready_wait_s"}
 
 
 def reader(name):
@@ -118,7 +116,7 @@ def named(spec: dict, key: str) -> dict:
     return dict(zip(names, spec[key]))
 
 
-def test_the_cells_entries_are_all_there_and_use_no_held_name():
+def test_the_cells_entries_are_all_there_and_use_no_name_of_the_guards():
     spec = run.load_spec()
     per_layer = named(spec, "per_layer")
     e2e = {m["name"] for m in run.metrics_of(spec, "end_to_end", CELL)}
@@ -141,19 +139,16 @@ def test_the_cells_entries_are_all_there_and_use_no_held_name():
         == ("ec10_4_live", "encode_under_read", 1)
     # what the counters cover and what nothing guards is said up front
     assert "unguarded" in cell["why"] and "cover the run" in cell["why"]
-    with open(os.path.join(BENCH, "held_cells.json")) as f:
-        held = json.load(f)
-    taken = {e["name"] for key in ("configs", "workloads", "end_to_end",
-                                   "per_layer") for e in held[key]}
-    ours = set(NEW) | {CELL, "ec10_4_live"}
-    assert not ours & taken
-    run.load_spec(held=True)          # and the two files still merge
+    # nor a name of the guard's, which moves another end-to-end metric
+    guard = {m["name"] for m in run.metrics_of(
+        spec, "per_layer", "ec6_3_serve.read_under_encode")}
+    assert not (set(NEW) | {CELL, "ec10_4_live"}) & guard
 
 
 def test_the_twins_entries_stand_as_they_were():
     """What the idle cluster's cell reported it still reports, under
     the same names, units and readers; and this cell reports each of
-    them under `.live`, but for the three to be retired."""
+    them under `.live`, but for those left to the twin."""
     spec = run.load_spec()
     per_layer = named(spec, "per_layer")
     for name, (unit, better, source, layer) in OLDER.items():
