@@ -74,10 +74,10 @@ def choose_data_root(need_bytes: int) -> "tuple[str, str]":
 
 
 def memory_now() -> "dict[str, int]":
-    """{"total", "available", "shmem"} in bytes, from /proc/meminfo;
-    where a cgroup holds this process to less memory than the machine
-    has, "total" is that limit and "available" no more than what is
-    left under it."""
+    """{"total", "available", "shmem", "mem_total"} in bytes, from
+    /proc/meminfo; where a cgroup holds this process to less memory
+    than the machine has ("mem_total"), "total" is that limit and
+    "available" no more than what is left under it."""
     kb = {}
     with open("/proc/meminfo") as f:
         for ln in f:
@@ -85,7 +85,7 @@ def memory_now() -> "dict[str, int]":
             if name in ("MemTotal", "MemAvailable", "Shmem"):
                 kb[name] = int(rest.split()[0]) * 1024
     got = {"total": kb["MemTotal"], "available": kb["MemAvailable"],
-           "shmem": kb["Shmem"]}
+           "shmem": kb["Shmem"], "mem_total": kb["MemTotal"]}
     for limit, used in (("memory.max", "memory.current"),
                         ("memory/memory.limit_in_bytes",
                          "memory/memory.usage_in_bytes")):
@@ -99,6 +99,25 @@ def memory_now() -> "dict[str, int]":
         if cap < got["total"]:
             got.update(total=cap, available=min(got["available"], left))
     return got
+
+
+def rss_bytes(pid: "int | None") -> "int | None":
+    """Resident size of process `pid`, from /proc."""
+    try:
+        with open(f"/proc/{pid}/status") as f:
+            return next(int(ln.split()[1]) * 1024 for ln in f
+                        if ln.startswith("VmRSS:"))
+    except (OSError, StopIteration):
+        return None
+
+
+def machine_now(pid: "int | None" = None) -> dict:
+    """What the machine says of itself, for the run's record: its
+    memory (`memory_now`), the CPUs this process may use, and the
+    resident size of process `pid` (the chips' owner pins host memory
+    while it initialises)."""
+    return dict(memory_now(), cpus=len(os.sched_getaffinity(0)),
+                rss=rss_bytes(pid))
 
 
 def make_data_root(parent: str) -> str:
@@ -349,8 +368,9 @@ CHUNK_NEEDLES = 8192   # one loader task uploads at most this many
 
 
 def grow_volume(master: str, index: int) -> "tuple[int, str, str]":
-    """(vid, collection, url) of a new volume in a collection of its
-    own."""
+    """(vid, collection, url) of a new volume in the collection that
+    seeded volume `index` opens: its own, or its group's where it is
+    that group's first."""
     from seaweedfs_tpu.server.httpd import http_json
     collection = f"bench{index}"
     vids = http_json("POST", f"{master}/vol/grow", {
@@ -452,9 +472,12 @@ class Cluster:
     # -- data ---------------------------------------------------------------
 
     def load_volumes(self, seed: int, shapes: "list[tuple[int, int]]",
-                     first_index: int = 0) -> "list[dict]":
+                     first_index: int = 0, group: int = 1,
+                     grouped_from: int = 0) -> "list[dict]":
         """One sealed volume's worth of seeded needles for each (count,
-        bytes) of `shapes`, each volume in a collection of its own:
+        bytes) of `shapes`, each volume in a collection of its own, or,
+        from shape `grouped_from` on, each `group` of them in one (a
+        job of several volumes states one collection):
         [{"vid", "collection", "index", "fids": {fid: digest}, "order":
         [fid of needle 0, 1, ...], "bytes": size of the .dat}].  A few
         processes upload at once (one interpreter makes and sends about
@@ -468,7 +491,9 @@ class Cluster:
         vols, chunks = [], []
         for j, (n, size) in enumerate(shapes):
             index = first_index + j
-            vid, collection, url = grow_volume(self.master, index)
+            # the collection is named for the first volume of the group
+            vid, collection, url = grow_volume(
+                self.master, index - max(0, j - grouped_from) % group)
             vols.append({"vid": vid, "collection": collection,
                          "index": index, "fids": {}, "order": []})
             parts = min(servers, -(-n // CHUNK_NEEDLES))

@@ -18,10 +18,11 @@ def _flip(path: str, offset: int) -> None:
 
 
 def flip_parity_byte() -> Hooks:
-    """One byte of one parity shard of the window's first job, altered
-    where the job left it (an answer altered where it is produced)."""
+    """One byte of one parity shard of the window's first job (of its
+    last volume, where it has several), altered where the job left it
+    (an answer altered where it is produced)."""
     def before_verify(cluster, state):
-        vol = next(j["vols"][0] for j in state["jobs"] if j["ok"])
+        vol = next(j["vols"][-1] for j in state["jobs"] if j["ok"])
         paths = cluster.shard_paths(vol)
         last = max(paths)
         _flip(paths[last][0], os.path.getsize(paths[last][0]) // 2)

@@ -141,8 +141,8 @@ def job_traces(ctx: dict) -> "list[list[dict]]":
             dist = [s["durationMs"] / 1e3 for s in spans
                     if s["name"] == "ec.distribute"]
             mark = phases[job_id].get("distribute")
-            both = f"ec.distribute {dist[0]:.3f}s against " \
-                f"{mark[1] - mark[0]:.3f}s by progress marks; " \
+            both = f"ec.distribute {sum(dist):.3f}s ({len(dist)} spans) " \
+                f"against {mark[1] - mark[0]:.3f}s by progress marks; " \
                 if dist and mark else ""
             print(f"  trace of job {job_id}: {both}{len(spans)} spans"
                   + (f" {dict(sorted(names.items()))}"

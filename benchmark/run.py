@@ -187,23 +187,28 @@ def memory_budget(total: int) -> int:
     return int(min(total, MACHINE_LIMIT_BYTES) * MEMORY_SHARE)
 
 
-def set_bytes(cfg: dict, vol_bytes: int, n: int) -> int:
-    """What `n` job volumes of `vol_bytes` bring the data root to hold
-    at the worst moment a window can reach: all but the last encoded
-    and at rest as (k+r)/k of a volume of shards, the last in flight at
-    the end of its distribute: its source, the worker's copy, the
-    worker's shard files and the targets'."""
+def set_bytes(cfg: dict, vol_bytes: int, n: int, group: int = 1) -> int:
+    """What `n` job volumes of `vol_bytes`, `group` of them a job, bring
+    the data root to hold at the worst moment a window can reach: all
+    but the last job's encoded and at rest as (k+r)/k of a volume of
+    shards, the last job's in flight at the end of its distribute: for
+    each its source, the worker's copy, the worker's shard files and
+    the targets' (a job of several volumes pulls them all before it
+    encodes and keeps them all until it ends)."""
     if n <= 0:
         return 0
     grow = (cfg["data_shards"] + cfg["parity_shards"]) / cfg["data_shards"]
-    return math.ceil(vol_bytes * (grow * (n - 1) + 2 + 2 * grow))
+    return math.ceil(vol_bytes * (grow * (n - group)
+                                  + group * (2 + 2 * grow)))
 
 
-def volumes_within(cfg: dict, vol_bytes: int, room: float) -> int:
-    """`n_budget`: the most job volumes whose `set_bytes` fit `room`."""
+def volumes_within(cfg: dict, vol_bytes: int, room: float,
+                   group: int = 1) -> int:
+    """`n_budget`: the most job volumes, in whole jobs of `group`,
+    whose `set_bytes` fit `room`."""
     n = 0
-    while set_bytes(cfg, vol_bytes, n + 1) <= room:
-        n += 1
+    while set_bytes(cfg, vol_bytes, n + group, group) <= room:
+        n += group
     return n
 
 
@@ -219,6 +224,21 @@ def burst_of(cfg: dict, traffic_jobs: dict) -> "int | None":
     if not isinstance(count, int) or count < 1:
         raise BenchFailure(f"jobs.count_from {key!r}: the configuration "
                            f"has {count!r} there, not a count of jobs")
+    return count
+
+
+def group_of(cfg: dict, traffic_jobs: dict) -> int:
+    """Volumes a job: the configuration's count under the key that a
+    traffic file's `jobs` name as `group_from` (one `ec.encode` job of
+    that many sealed volumes of one collection, the worker's batch
+    path); 1 where none is named, one volume a job."""
+    key = traffic_jobs.get("group_from")
+    if key is None:
+        return 1
+    count = cfg.get(key)
+    if not isinstance(count, int) or isinstance(count, bool) or count < 1:
+        raise BenchFailure(f"jobs.group_from {key!r}: the configuration "
+                           f"has {count!r} there, not a count of volumes")
     return count
 
 
@@ -252,6 +272,13 @@ PHASE_MARKS = (("pull", "marked readonly", "copied volume files"),
                ("encode", "copied volume files", "encoded "),
                ("distribute", "encoded ", "distributed shards"),
                ("finish", "distributed shards", "end"))
+# a job of several volumes reports "pulled volume v (i/n)" once a
+# volume, "batch-encoded n volumes" once, "distributed volume v (i/n)"
+# once a volume: a phase runs to the last report of its kind
+BATCH_MARKS = (("pull", "start", "pulled volume"),
+               ("encode", "pulled volume", "batch-encoded"),
+               ("distribute", "batch-encoded", "distributed volume"),
+               ("finish", "distributed volume", "end"))
 
 
 def job_phases(log: "list[list]") -> "dict[str, dict]":
@@ -263,11 +290,14 @@ def job_phases(log: "list[list]") -> "dict[str, dict]":
             by_job.setdefault(job_id, []).append((label, t))
     out = {}
     for job_id, marks in by_job.items():
+        batch = any(lb.startswith(("pulled volume", "batch-encoded"))
+                    for lb, _t in marks)
+
         def at(prefix):
-            return next((t for lb, t in marks if lb.startswith(prefix)),
-                        None)
+            got = [t for lb, t in marks if lb.startswith(prefix)]
+            return (got[-1] if batch else got[0]) if got else None
         phases = {}
-        for name, a, b in PHASE_MARKS:
+        for name, a, b in (BATCH_MARKS if batch else PHASE_MARKS):
             s, e = at(a), at(b)
             if s is not None and e is not None:
                 phases[name] = (s, e)
@@ -356,8 +386,16 @@ class Run:
         self.cfg, self.traffic = cfg, traffic
         self.reads, self.jobs_t = traffic.get("reads"), traffic.get("jobs")
         self.burst = burst_of(cfg, self.jobs_t) if self.jobs_t else None
+        self.group = group_of(cfg, self.jobs_t) if self.jobs_t else 1
         self.seconds = float(args.seconds)
         self.job_shape = (cfg["needles_per_volume"], cfg["needle_bytes"])
+        # the program a job of several volumes compiles follows their
+        # count, not their size (short volumes are zero-padded to the
+        # step), so the set-up's group is of the configuration's toy
+        # volumes and the memory budget is left to the window
+        self.warm_shape = self.job_shape if self.group == 1 else (
+            files["cfg"]["rehearse"]["needles_per_volume"],
+            cfg["needle_bytes"])
         # the objects clients read may be other than what a sealed
         # volume for the maintenance job holds
         self.read_shapes = [(cfg["read_objects"] // cfg["read_volumes"],
@@ -366,9 +404,10 @@ class Run:
         self.state: dict = {"seed": args.seed}
 
     def first_shapes(self) -> "list[tuple[int, int]]":
-        """The read set, and one volume of the jobs' shape whose set-up
-        job warms every program the window's jobs will use."""
-        return self.read_shapes + ([self.job_shape] if self.jobs_t else [])
+        """The read set, and one job's volumes whose set-up job warms
+        every program the window's jobs will use."""
+        return self.read_shapes + (
+            [self.warm_shape] * self.group if self.jobs_t else [])
 
     # -- set-up -------------------------------------------------------------
 
@@ -379,12 +418,15 @@ class Run:
         self.cluster = cl.Cluster(self.procs, root, cfg)
         self.cluster.start()
         t0 = time.perf_counter()   # the worker child reaches the chip meanwhile
-        first = self.cluster.load_volumes(args.seed, self.first_shapes())
+        first = self.cluster.load_volumes(
+            args.seed, self.first_shapes(), group=self.group,
+            grouped_from=len(self.read_shapes))
         say(f"loaded {[(len(v['order']), v['bytes']) for v in first]} "
             f"(needles, .dat bytes) in {time.perf_counter() - t0:.2f}s")
         ready = self.cluster.wait_worker()
         self.dev = ready["device"]
         say(f"worker child: {json.dumps(ready)}")
+        say(f"machine: {json.dumps(cl.machine_now(ready.get('pid')))}")
         if not args.rehearse:
             if self.dev["platform"] != "tpu":
                 raise BenchFailure(f"no chip: JAX runs on {self.dev}")
@@ -393,25 +435,33 @@ class Run:
                                    f"asks for {self.cell['chips']}")
             trace_reduce.peaks_for(self.dev["kind"])   # unknown kind: error
         self.read_vols = first[:len(self.read_shapes)]
-        # every program the window will use is compiled or fetched here
+        # every program the window will use is compiled or fetched
+        # here: the read set's volumes one a job, then a job of the
+        # window's shape
         took = 0.0
-        for vol in first:
+        n_read = len(self.read_shapes)
+        for vols in [[v] for v in first[:n_read]] + (
+                [first[n_read:]] if self.jobs_t else []):
             c0 = self.cluster.wire.ask("mark")["compile"]["seconds"]
             t0 = time.perf_counter()
-            j = self.cluster.wait_job(self.cluster.submit_encode([vol]),
+            j = self.cluster.wait_job(self.cluster.submit_encode(vols),
                                       JOB_TIMEOUT_S)
             took = time.perf_counter() - t0
             compiling = self.cluster.wire.ask(
                 "mark")["compile"]["seconds"] - c0
-            say(f"set-up job on volume {vol['vid']}: {j['status']} in "
-                f"{took:.2f}s ({compiling:.2f}s of it compiling): "
+            say(f"set-up job on volume "
+                f"{','.join(str(v['vid']) for v in vols)}: {j['status']} "
+                f"in {took:.2f}s ({compiling:.2f}s of it compiling): "
                 f"{j['message']}")
             if j["status"] != "done":
                 raise BenchFailure(f"set-up job failed: {j['message']}")
             took -= compiling
         self.job_vols, self.sizing = [], None
         if self.jobs_t:
-            self.job_vols = self.load_job_volumes(took, first[-1]["bytes"],
+            # a window's volume by the set-up's: the same needles, more
+            vol_bytes = -(-first[-1]["bytes"] * self.job_shape[0]
+                          // self.warm_shape[0])
+            self.job_vols = self.load_job_volumes(took, vol_bytes,
                                                   len(first))
         self.state.update(read_vols=self.read_vols, job_vols=self.job_vols)
         self.loaders = self.start_loaders() if self.reads else []
@@ -426,21 +476,24 @@ class Run:
         (`close_of`).  A burst is its stated count, whatever a job
         takes, and one the budget cannot hold is not cut: set-up
         fails."""
-        root = self.root
+        root, g = self.root, self.group
         mem = cl.memory_now()
         total = self.hooks.memory_total or mem["total"]
         budget, resident = memory_budget(total), cl.tree_bytes(root)
         if self.burst:
-            wanted = self.burst
-            why = f"a burst of {wanted}, the configuration's " \
+            wanted = self.burst * g
+            why = f"a burst of {self.burst}, the configuration's " \
                 f"{self.jobs_t['count_from']}"
         else:
             margin = self.jobs_t["job_seconds_margin"]
-            wanted = volumes_for(self.seconds, took, margin)
+            wanted = volumes_for(self.seconds, took, margin) * g
             why = f"{took:.2f}s a job at margin {margin}"
-        n_budget = volumes_within(self.cfg, vol_bytes, budget - resident)
+        if g > 1:
+            why += f", {g} volumes a job, the configuration's " \
+                f"{self.jobs_t['group_from']}"
+        n_budget = volumes_within(self.cfg, vol_bytes, budget - resident, g)
         n = min(wanted, n_budget)
-        need = set_bytes(self.cfg, vol_bytes, max(n, 1))
+        need = set_bytes(self.cfg, vol_bytes, max(n, g), g)
         free = cl.free_bytes(root)
         self.sizing = {"wanted": wanted, "n_budget": n_budget, "loaded": n,
                        "budget": budget, "total": total}
@@ -458,12 +511,13 @@ class Run:
             raise BenchFailure(
                 f"the burst is {wanted} job volumes ({why}) and the memory "
                 f"budget holds {n_budget}: a burst is not cut")
-        check_room("the memory budget", max(n, 1), resident + need, budget)
+        check_room("the memory budget", max(n, g), resident + need, budget)
         check_room("available memory", n, need, mem["available"])
         check_room("the data root", n, need, free)
         t0 = time.perf_counter()
         vols = self.cluster.load_volumes(
-            self.args.seed, [self.job_shape] * n, first_index=first_index)
+            self.args.seed, [self.job_shape] * n, first_index=first_index,
+            group=g)
         say(f"loaded {n} volumes for the window's jobs in "
             f"{time.perf_counter() - t0:.2f}s")
         return vols
@@ -512,8 +566,10 @@ class Run:
         def drive_chain():
             try:
                 time.sleep(max(0.0, t_open - time.time()))
+                vols, g = self.job_vols, self.group
                 chain["jobs"], chain["dry"] = run_chain(
-                    [[v] for v in self.job_vols], self.seconds, t_open,
+                    [vols[i:i + g] for i in range(0, len(vols), g)],
+                    self.seconds, t_open,
                     cluster.submit_encode,
                     lambda jid: cluster.wait_job(jid, JOB_TIMEOUT_S))
                 if reads and time.time() < self.t_stop:
@@ -585,6 +641,7 @@ class Run:
                     if isinstance(mark1[key][k], (int, float))}
         self.ctx = {
             "cfg": self.cfg, "traffic": self.traffic, "device": self.dev,
+            "chips": self.cell["chips"],
             "window": {"open": t_open, "close": self.t_close,
                        "end": self.t_end},
             "jobs": jobs, "staging": delta("staging"),
@@ -650,6 +707,10 @@ class Run:
                 say(f"  at +{t - self.t_open:.1f}s the master held alive "
                     f"{len(alive)} of {self.cfg['volume_servers']} volume "
                     f"servers: {alive}")
+        rss = cl.rss_bytes(self.cluster.ready.get("pid"))
+        if rss:
+            say(f"worker child resident after the window: "
+                f"{rss / 1e9:.2f} GB")
         say(f"worker ledgers: staging {json.dumps(m['staging'])} "
             f"compile {json.dumps(m['compile'])} "
             f"peak_bytes {json.dumps(m['peak_bytes'])}")
@@ -776,13 +837,13 @@ def run_cell(args, hooks: "Hooks | None" = None) -> dict:
     # a guess for choosing the root; what the window needs is reckoned
     # and checked once the set-up's job has been timed
     vol = run.job_shape[0] * run.job_shape[1]
-    n = min(volumes_for(run.seconds, 3.0, 1.0), volumes_within(
+    n = min(volumes_for(run.seconds, 3.0, 1.0) * run.group, volumes_within(
         run.cfg, vol, memory_budget(
-            run.hooks.memory_total or cl.memory_now()["total"]))
+            run.hooks.memory_total or cl.memory_now()["total"]), run.group)
     ) if run.jobs_t else 0
     parent, kind = cl.choose_data_root(
         2 * sum(count * size for count, size in run.first_shapes())
-        + set_bytes(run.cfg, vol, n))
+        + set_bytes(run.cfg, vol, n, run.group))
     root = cl.make_data_root(parent)
     say(f"data root: {root} on {kind}, "
         f"{cl.free_bytes(root) / 2**30:.1f} GiB free before")

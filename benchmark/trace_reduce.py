@@ -179,11 +179,14 @@ def encode_min_bytes(volume_bytes: float, k: int, r: int) -> float:
 
 
 def roofline_share(min_bytes: float, busy_s: "float | None",
-                   device_kind: str) -> "float | None":
-    """Percent: least time at the table's HBM bytes/s over the time the
-    device was busy.  Bandwidth-bound: the encode does ~(k+r)/k bytes of
-    traffic for a handful of byte operations each."""
-    peak = peaks_for(device_kind)["hbm_bytes_per_s"]
+                   device_kind: str, chips: int = 1) -> "float | None":
+    """Percent: least time at the table's HBM bytes/s, on the cell's
+    `chips` together, over the time a device was busy (`busy_seconds`:
+    the mean over the chips).  Bandwidth-bound: the encode does ~(k+r)/k
+    bytes of traffic for a handful of byte operations each.  Chips that
+    split the bytes perfectly read 100, never `chips` times that,
+    wherever the program placed the work."""
+    peak = peaks_for(device_kind)["hbm_bytes_per_s"] * chips
     if not busy_s or min_bytes <= 0:
         return None
     return 100.0 * (min_bytes / peak) / busy_s

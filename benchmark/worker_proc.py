@@ -93,6 +93,7 @@ def main() -> int:
     say({"event": "ready", "device": dev, "init_s": init_s,
          "probe": ec_context.probe_backend() if dev["platform"] != "cpu"
          else None,
+         "devices": [str(d) for d in jax.local_devices()],
          "compile_cache_dir": ec_context.compile_cache_dir(),
          "worker_id": worker.worker_id, "pid": os.getpid()})
 

@@ -244,17 +244,28 @@ def test_an_entry_that_said_nothing_is_gone(spec, name):
                                        stem + ".py")) == twin
 
 
-def test_three_cells_one_chip_each_and_the_guard_is_end_to_end(spec):
+BATCH = "ec10_4_batch.encode_4chip"
+
+
+def test_four_cells_three_on_one_chip_one_on_four(spec):
+    """Three cells on one chip and the batch job on four (the one path
+    that exists only across chips); `ec_GBps` on the three encode
+    cells, the guard on the readers end to end in the fourth."""
     cells = {w["name"]: w for w in spec["workloads"]}
-    assert sorted(cells) == ["ec10_4_live.encode_under_read",
+    assert sorted(cells) == [BATCH, "ec10_4_live.encode_under_read",
                              "ec10_4_vol1g.encode", HELD]
-    assert {w["chips"] for w in cells.values()} == {1}
-    assert len(spec["configs"]) == 3 and spec["run_seconds"] == 50
+    assert {n: w["chips"] for n, w in cells.items()} == {
+        BATCH: 4, "ec10_4_live.encode_under_read": 1,
+        "ec10_4_vol1g.encode": 1, HELD: 1}
+    assert cells[BATCH]["traffic"] == "encode_4chip"
+    assert len(spec["configs"]) == 4 and spec["run_seconds"] == 50
     e2e = {m["name"]: m for m in spec["end_to_end"]}
     assert sorted(e2e) == ["ec_GBps", "read_p99_ms", "read_rps", "setup_s"]
     assert e2e["ec_GBps"]["bound"] == 0.1 and \
         e2e["setup_s"]["bound"] == 0.25 and "workloads" not in e2e["setup_s"]
     assert sorted(e2e["ec_GBps"]["workloads"]) == sorted(set(cells) - {HELD})
+    assert e2e["read_rps"]["bound"] == 0.09 and \
+        e2e["read_p99_ms"]["bound"] == 0.12
     for name, better in (("read_rps", "higher"), ("read_p99_ms", "lower")):
         m = e2e[name]
         assert m["workloads"] == [HELD] and m["better"] == better

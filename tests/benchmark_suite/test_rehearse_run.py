@@ -150,7 +150,7 @@ def test_a_job_names_one_volume_or_several_of_one_collection(
         vids, which, monkeypatch):
     """`Cluster.submit_encode` takes a list: `volumeId` for one, as
     every cell sends today, `volumeIds` (the worker's batch path) for
-    several.  No traffic file asks for several yet (PERF.md 7)."""
+    several, which `traffic/encode_4chip.json` asks for (`group_from`)."""
     from benchmark import cluster as cl
     from seaweedfs_tpu.server import httpd
     sent = []

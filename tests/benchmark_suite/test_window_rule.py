@@ -277,8 +277,8 @@ class SizingCluster:
     def __init__(self):
         self.asked = None
 
-    def load_volumes(self, seed, shapes, first_index=0):
-        self.asked = (len(shapes), first_index)
+    def load_volumes(self, seed, shapes, first_index=0, group=1):
+        self.asked, self.group = (len(shapes), first_index), group
         return [{"vid": i} for i in range(len(shapes))]
 
 
@@ -307,7 +307,13 @@ def sizing_run(monkeypatch, tmp_path, workload, seconds, memtotal,
     # the burst's count whatever a job takes
     ("ec6_3_serve.read_under_encode", HOST, 3.0, 14, 14),
     ("ec6_3_serve.read_under_encode", HOST, 9.0, 14, 14),
-    ("ec6_3_serve.read_under_encode", LIMIT, 0.5, 14, 14)])
+    ("ec6_3_serve.read_under_encode", LIMIT, 0.5, 14, 14),
+    # whole jobs of the configuration's four volumes, whatever the
+    # set-up's toy job took
+    ("ec10_4_batch.encode_4chip", HOST, 1.5, 8, 8),
+    ("ec10_4_batch.encode_4chip", HOST, 40.0, 8, 8),    # wants 3 jobs
+    ("ec10_4_batch.encode_4chip", HOST, 90.0, 4, 8),    # wants 1
+    ("ec10_4_batch.encode_4chip", 32 * 2**30, 1.5, 4, 4)])
 def test_set_up_sizes_the_window_by_budget_by_job_or_by_burst(
         monkeypatch, tmp_path, capsys, workload, memtotal, took, loaded,
         n_budget):
@@ -317,6 +323,8 @@ def test_set_up_sizes_the_window_by_budget_by_job_or_by_burst(
     assert r.sizing["n_budget"] == n_budget
     assert r.sizing["budget"] == run.memory_budget(memtotal)
     assert r.cluster.asked == (loaded, 4)
+    assert r.cluster.group == r.group == (4 if "batch" in workload else 1)
+    assert loaded % r.group == n_budget % r.group == 0
     assert f"budget {n_budget} (" in capsys.readouterr().out
 
 
