@@ -30,6 +30,7 @@ from ...server.httpd import http_download, http_json, http_upload
 from ...storage.erasure_coding import ECContext
 from ...storage.erasure_coding import ec_context, ec_decoder, ec_encoder
 from ...storage.erasure_coding.ec_context import to_ext
+from ...storage.erasure_coding.shard_sink import DatShardView
 from ...topology import iter_volume_list_volumes
 from ..worker import JobHandler
 
@@ -347,7 +348,9 @@ class EcEncodeHandler(JobHandler):
         version = _read_dat_version(base)
         _sort_index(base)
         with tracing.span("ec.encode", role="worker") as sp:
-            ec_encoder.write_ec_files(
+            # the .dat stays here until the job's end, so a data shard
+            # is not written a second time: it is sent as ranges of it
+            views = ec_encoder.write_parity_files(
                 base, ctx, progress=_encode_progress(worker, job_id))
             # the result names where it ran, so /maintenance/job and
             # the job's trace can tell a TPU encode from anything else
@@ -359,12 +362,14 @@ class EcEncodeHandler(JobHandler):
 
         # consistency check (:638 verifyDatIdxConsistency analog):
         # decode geometry must reproduce the source size
-        if ec_decoder.find_dat_file_size(base, base) > dat_size:
+        if ec_decoder.find_dat_file_size(base, base, version) > dat_size:
             raise RuntimeError("ecx entries exceed dat size")
 
         # 4+5. distribute + mount
+        where = _pushed_files(base, ctx)
+        where.update((to_ext(v.shard_id), v) for v in views)
         placement = self._distribute_and_mount(worker, vid, collection,
-                                               ctx, base, started)
+                                               ctx, where, started)
         worker.report_progress(job_id, 0.8, "distributed shards")
         return placement
 
@@ -394,12 +399,14 @@ class EcEncodeHandler(JobHandler):
         return targets, waited
 
     def _distribute_and_mount(self, worker, vid: int, collection: str,
-                              ctx: ECContext, base: str,
+                              ctx: ECContext, where: dict,
                               started: "tuple[list[str], float]") -> dict:
         """Round-robin shard spread over the servers the job started
         under (:532), pushed to all of them at once, + mount
         (shard_distribution.go:209): nothing is mounted unless every
-        file reached every target."""
+        file reached every target.  `where` says for each ext pushed
+        where its bytes are: a file's path, or a data shard's
+        DatShardView of the `.dat` (_pushed_files)."""
         with tracing.span("ec.distribute", role="worker") as sp:
             servers, waited_at_start = started
             sp.set("serversAtStart", len(servers))
@@ -411,9 +418,12 @@ class EcEncodeHandler(JobHandler):
                 placement[targets[sid % len(targets)]].append(sid)
             holders = {t: sids for t, sids in placement.items() if sids}
             pushed, push_seconds = _push_to_each(holders, vid, collection,
-                                                 base)
+                                                 where)
             sp.set("servers", len(holders))
             sp.set("bytes", pushed)
+            sp.set("bytesFromDat", sum(
+                src.size for src in where.values()
+                if isinstance(src, DatShardView)))
             # Σ seconds of the ec.push spans over pushSeconds is how
             # many streams really ran at once
             sp.set("streams", len(holders))
@@ -474,8 +484,11 @@ class EcEncodeHandler(JobHandler):
                 f"batch-encoded {n} volumes ({_ran_on(ctx)})")
 
             for i, vid in enumerate(vids):
-                self._distribute_and_mount(worker, vid, collection,
-                                           ctx, bases[vid], started)
+                # encode_volume_files_batch wrote all of a volume's
+                # shards as files
+                self._distribute_and_mount(
+                    worker, vid, collection, ctx,
+                    _pushed_files(bases[vid], ctx), started)
                 worker.report_progress(
                     job_id, 0.6 + 0.3 * (i + 1) / n,
                     f"distributed volume {vid} ({i + 1}/{n})")
@@ -673,26 +686,42 @@ def _mount_shards(target: str, vid: int, collection: str,
               f"mount shards on {target}")
 
 
+def _pushed_files(base: str, ctx: ECContext) -> "dict[str, str]":
+    """{ext: path} of what a volume's targets are sent, every shard a
+    file of the work dir; a job whose encode wrote no data shards puts
+    their views in the paths' place."""
+    return {ext: base + ext for ext in
+            [to_ext(sid) for sid in range(ctx.total)] + [".ecx", ".vif"]}
+
+
 def _push_file(target: str, vid: int, collection: str, ext: str,
-               path: str) -> int:
-    """Streamed push (http_upload): shard files go from disk to the
+               source: "str | DatShardView") -> int:
+    """Streamed push (http_upload) of a file of the work dir, or of a
+    data shard that is a view of the `.dat` there: from disk to the
     socket by sendfile, or under TLS through one 1 MiB buffer
     (shard_distribution.go:101 target side).  Returns the bytes sent.
-    The `ec.push` span carries them, which of the two it was (`via`,
-    as http_upload reports it) and this thread's CPU for the push:
-    against the span's wall and the receiver's own `POST
-    /admin/receive_file` span beneath it, that says whether the
-    sender, the receiver or neither was busy."""
+    The `ec.push` span carries them, where they came from (`source`
+    "file" or "dat", and `ranges`, how many of it were sent), which of
+    the two ways they went (`via`, as http_upload reports it) and this
+    thread's CPU for the push: against the span's wall and the
+    receiver's own `POST /admin/receive_file` span beneath it, that
+    says whether the sender, the receiver or neither was busy."""
     with tracing.span("ec.push", role="worker") as sp:
-        size = os.path.getsize(path)
+        if isinstance(source, DatShardView):
+            path, pieces, size = source.dat_path, source.pieces, source.size
+        else:
+            path, pieces, size = source, None, os.path.getsize(source)
         sp.set("target", target)
         sp.set("ext", ext)
         sp.set("bytes", size)
+        sp.set("source", "file" if pieces is None else "dat")
+        sp.set("ranges", 1 if pieces is None else len(pieces))
         cpu0 = time.thread_time()
         try:
             sent = http_upload(
                 "POST", f"{target}/admin/receive_file?volumeId={vid}"
-                f"&collection={collection}&ext={ext}", path, timeout=600)
+                f"&collection={collection}&ext={ext}", path, timeout=600,
+                pieces=pieces)
             sp.set("via", sent.via)
             status, body, _ = sent
         finally:
@@ -704,12 +733,13 @@ def _push_file(target: str, vid: int, collection: str, ext: str,
 
 
 def _push_to_each(holders: "dict[str, list[int]]", vid: int,
-                  collection: str, base: str) -> "tuple[int, float]":
+                  collection: str, where: dict) -> "tuple[int, float]":
     """(bytes pushed, seconds from the first push's start to the last
     one's end): one pusher thread a target, each sending its own
     target's files one at a time (its shards ascending, then .ecx,
-    .vif), so that a receiver never sees two pushes of one job at
-    once while the receivers, a process each, all work.  A push that
+    .vif; each from `where[ext]`), so that a receiver never sees two
+    pushes of one job at once while the receivers, a process each, all
+    work.  A push that
     fails stops the others before their next file; once all have
     ended the first failure in target order is raised."""
     failed = threading.Event()
@@ -721,7 +751,7 @@ def _push_to_each(holders: "dict[str, list[int]]", vid: int,
                 break
             try:
                 sent += _push_file(target, vid, collection, ext,
-                                   base + ext)
+                                   where[ext])
             except BaseException:
                 failed.set()
                 raise

@@ -1213,15 +1213,80 @@ class UploadResult(tuple):
 _UPLOAD_BLOCK = 1 << 20
 
 
+def _send_pieces(sock, pieces, send_some) -> int:
+    """`pieces` [(offset, count, zeros)] of an open file to `sock`:
+    `send_some(offset, count)` sends some of a range and returns how
+    much (0 where the file has ended), then the piece's zero bytes go.
+    Returns the bytes sent, fewer than asked for where the file ended
+    inside a piece."""
+    sent = 0
+    for offset, count, zeros in pieces:
+        while count:
+            n = send_some(offset, count)
+            if not n:
+                return sent
+            offset += n
+            count -= n
+            sent += n
+        fill = memoryview(bytes(min(zeros, _UPLOAD_BLOCK)))
+        while zeros:
+            part = fill[:zeros]
+            sock.sendall(part)
+            zeros -= len(part)
+            sent += len(part)
+    return sent
+
+
+def _sendfile_to(sock, fd: int):
+    """send_some for a plain socket: `os.sendfile`, the kernel copying.
+    A socket with a timeout is non-blocking underneath, so a full send
+    buffer is waited out, on one poller for the whole body
+    (`socket.sendfile` builds a selector, and selects before every
+    call, once a range)."""
+    import os as _os
+    import select as _select
+    out = sock.fileno()
+    timeout = sock.gettimeout()
+    wait_ms = None if timeout is None else timeout * 1e3
+    poller = _select.poll()
+    poller.register(out, _select.POLLOUT)
+
+    def send_some(offset: int, count: int) -> int:
+        while True:
+            try:
+                return _os.sendfile(out, fd, offset, count)
+            except BlockingIOError:
+                if not poller.poll(wait_ms):
+                    raise TimeoutError("timed out") from None
+    return send_some
+
+
+def _blocks_to(sock, fd: int):
+    """send_some for a TLS socket (where sendfile would fall back to
+    8 KiB sends): through one reused 1 MiB buffer."""
+    import os as _os
+    block = memoryview(bytearray(_UPLOAD_BLOCK))
+
+    def send_some(offset: int, count: int) -> int:
+        n = _os.preadv(fd, [block[:count]], offset)
+        sock.sendall(block[:n])
+        return n
+    return send_some
+
+
 def http_upload(method: str, url: str, src_path: str,
-                headers: dict | None = None, timeout: float = 60.0
+                headers: dict | None = None, timeout: float = 60.0,
+                pieces: "list[tuple[int, int, int]] | None" = None
                 ) -> UploadResult:
     """Send a file as the request body WITHOUT buffering it in memory
-    (the worker's bulk shard push): Content-Length is the open file's
-    size, and the body goes to a plain socket by `socket.sendfile` (the
-    kernel copies) and to a TLS one, where sendfile would fall back to
-    8 KiB sends, through one reused 1 MiB buffer.  A body that ends
-    short of its Content-Length raises; a receiver that answers
+    (the worker's bulk shard push): the whole of it, or with `pieces`
+    [(offset, count, zeros)] those ranges of it in their order, each
+    followed by that many zero bytes (a data shard as the blocks of the
+    `.dat` it is made of, ec_locate.data_shard_ranges).  Content-Length
+    is the open file's size, or the pieces' sum, and the body goes to a
+    plain socket by `os.sendfile` (the kernel copies) and to a TLS one
+    through one reused 1 MiB buffer; `via` says which.  A body that
+    ends short of its Content-Length raises; a receiver that answers
     mid-body and closes gets its status and body returned, not the
     sender's broken pipe.  `timeout` is a per-socket-operation stall
     bound (see http_download), deadline-derived when a budget is
@@ -1234,27 +1299,20 @@ def http_upload(method: str, url: str, src_path: str,
     full_url, ctx = _dial(url)
     conn, target = _open_conn(full_url, ctx, timeout)
     via = "blocks" if full_url.startswith("https") else "sendfile"
+    send_to = _blocks_to if via == "blocks" else _sendfile_to
     try:
         with open(src_path, "rb") as f:
-            size = _os.fstat(f.fileno()).st_size
+            if pieces is None:
+                pieces = [(0, _os.fstat(f.fileno()).st_size, 0)]
+            size = sum(count + zeros for _, count, zeros in pieces)
             conn.putrequest(method, target, skip_accept_encoding=True)
             for hk, hv in up_headers.items():
                 conn.putheader(hk, hv)
             conn.putheader("Content-Length", str(size))
             conn.endheaders()
-            sent = 0
             try:
-                if via == "sendfile":
-                    if size:
-                        sent = conn.sock.sendfile(f, 0, size)
-                else:
-                    block = memoryview(bytearray(_UPLOAD_BLOCK))
-                    while sent < size:
-                        n = f.readinto(block[:size - sent])
-                        if not n:
-                            break
-                        conn.sock.sendall(block[:n])
-                        sent += n
+                sent = _send_pieces(conn.sock, pieces,
+                                    send_to(conn.sock, f.fileno()))
             except TimeoutError:
                 raise           # a stalled receiver has said nothing
             except OSError:

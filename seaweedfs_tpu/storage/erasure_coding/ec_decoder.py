@@ -67,10 +67,14 @@ def read_ec_volume_version(base_file_name: str) -> int:
 
 
 def find_dat_file_size(data_base_file_name: str,
-                       index_base_file_name: str) -> int:
+                       index_base_file_name: str,
+                       version: "int | None" = None) -> int:
     """Max (offset + record size) over live .ecx entries
-    (ec_decoder.go:65); at least the superblock size."""
-    version = read_ec_volume_version(data_base_file_name)
+    (ec_decoder.go:65); at least the superblock size.  `version` is
+    the volume's, for a caller that holds it and no `.ec00` (the worker
+    reads it from the `.dat`'s superblock: the same bytes)."""
+    if version is None:
+        version = read_ec_volume_version(data_base_file_name)
     dat_size = SUPER_BLOCK_SIZE
     for _, stored_off, size in iterate_ecx_file(index_base_file_name):
         if types.size_is_deleted(size):

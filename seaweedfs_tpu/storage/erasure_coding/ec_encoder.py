@@ -60,6 +60,28 @@ def write_ec_files(base_file_name: str, ctx: ECContext | None = None,
     _generate_ec_files(base_file_name, ctx, progress=progress)
 
 
+def write_parity_files(base_file_name: str, ctx: ECContext,
+                       progress=None) -> list:
+    """The encode of a caller that keeps the `.dat` until the shards
+    are sent (the worker's single-volume job): the parity shards go to
+    their `.ecNN` files as in write_ec_files, and no data shard is
+    written at all.  Returns one shard_sink.DatShardView a data shard,
+    by shard id: where in the `.dat` that shard's bytes already lie."""
+    from .shard_sink import DatShardView, LocalShardSink
+    dat_path = base_file_name + ".dat"
+    dat_size = os.path.getsize(dat_path)
+    d = ctx.data_shards
+    views = [DatShardView(dat_path, dat_size, d, i, LARGE_BLOCK_SIZE,
+                          SMALL_BLOCK_SIZE) for i in range(d)]
+    parity = [LocalShardSink(base_file_name + ctx.to_ext(i))
+              for i in range(d, ctx.total)]
+    _generate_ec_files(base_file_name, ctx, sinks=views + parity,
+                       progress=progress)
+    for sink in parity:
+        sink.commit()
+    return views
+
+
 def _encode_work_items(dat_size: int, ctx: ECContext
                        ) -> "list[tuple[int, int, int, int, int]]":
     """The exact batch schedule of ec_encoder.go:280 encodeDatFile
@@ -337,7 +359,9 @@ def _generate_ec_files(base_file_name: str, ctx: ECContext,
     `sinks` (shard_sink.ShardSink, one per shard id) parameterizes the
     write stage: None keeps the seed semantics (LocalShardSink per
     `.ecNN` file on this node), the scatter path passes RemoteShardSink
-    streams to each shard's placement target.  Ownership transfers
+    streams to each shard's placement target, write_parity_files a
+    DatShardView for each data shard, which is handed no rows: the
+    shard is read out of the `.dat` itself.  Ownership transfers
     either way: on success every sink is finish()ed (delivery
     verified), on failure every sink is abort()ed (staged bytes
     discarded — a failed encode leaves no partial shard for discovery
@@ -415,11 +439,15 @@ def _generate_ec_files(base_file_name: str, ctx: ECContext,
         if progress is not None:
             progress(min(done, dat_size), dat_size)
 
+    # a data shard that is read out of the .dat itself (DatShardView)
+    # is handed no rows
+    row_sinks = [(i, s) for i, s in enumerate(sinks[:d]) if s.stores_rows]
+
     def write_item(payload, parity):
         nonlocal written
         buf, real, _from_dat = payload
-        for i in range(d):
-            sinks[i].write(buf[i, :real].data)
+        for i, sink in row_sinks:
+            sink.write(buf[i, :real].data)
         # the data rows first: a device launch's fetch runs under them
         parity = _on_host(parity)
         for j in range(ctx.total - d):

@@ -82,3 +82,31 @@ def _locate_offset(large_block_size: int, small_block_size: int,
     offset -= n_large_rows * large_row_size
     return (offset // small_block_size, False, n_large_rows,
             offset % small_block_size)
+
+
+def data_shard_ranges(large_block_size: int, small_block_size: int,
+                      dat_size: int, data_shards: int, shard_id: int
+                      ) -> "list[tuple[int, int, int]]":
+    """Data shard `shard_id`'s file as pieces of the `.dat` it is
+    encoded from, in the file's order: (offset in the .dat, bytes from
+    there, zero bytes after them), one piece a row.  The shard is block
+    `shard_id` of every row (ec_encoder.go:280 encodeDatFile: large
+    rows while a whole one is left, then small rows over the rest), so
+    a block the `.dat` ends inside is its bytes and then zeros, and a
+    block wholly past the end is zeros alone.  The pieces' lengths sum
+    to what the encode writes for the shard, and for every parity
+    shard."""
+    pieces = []
+    large_row = large_block_size * data_shards
+    small_row = small_block_size * data_shards
+    row_start = 0
+    while dat_size - row_start >= large_row:
+        pieces.append((row_start + shard_id * large_block_size,
+                       large_block_size, 0))
+        row_start += large_row
+    while row_start < dat_size:
+        offset = row_start + shard_id * small_block_size
+        got = max(0, min(small_block_size, dat_size - offset))
+        pieces.append((offset, got, small_block_size - got))
+        row_start += small_row
+    return pieces

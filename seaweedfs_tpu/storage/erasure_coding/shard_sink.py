@@ -127,6 +127,9 @@ class ShardSink:
     `with` / close-in-finally is always safe (SWFS008)."""
 
     label = "?"
+    # False for a sink whose bytes are already where it will be read
+    # from (DatShardView): the encode then hands it no rows
+    stores_rows = True
 
     def write(self, data) -> None:
         """Append one window (bytes/memoryview).  The buffer may be
@@ -221,6 +224,34 @@ class LocalShardSink(ShardSink):
             self._closed = True
         else:
             self.abort()
+
+
+class DatShardView(ShardSink):
+    """A data shard that is never written: block `shard_id` of every
+    row of the `.dat` the encode reads, plus zeros past its end
+    (ec_locate.data_shard_ranges), for a caller that keeps the `.dat`
+    beside the parity files until the shards have been sent.  As a sink
+    it takes no rows (`stores_rows`), so the encode's writer leaves a
+    window's data rows where the reader put them, and it has no `file`
+    for the flusher; as a source it is `dat_path`, `pieces` and `size`,
+    which `httpd.http_upload` sends as ranges of the one file."""
+
+    label = "dat"
+    stores_rows = False
+
+    def __init__(self, dat_path: str, dat_size: int, data_shards: int,
+                 shard_id: int, large_block_size: int,
+                 small_block_size: int):
+        from .ec_locate import data_shard_ranges
+        self.dat_path = dat_path
+        self.shard_id = shard_id
+        self.pieces = data_shard_ranges(
+            large_block_size, small_block_size, dat_size, data_shards,
+            shard_id)
+        self.size = sum(got + zeros for _, got, zeros in self.pieces)
+
+    def write(self, data) -> None:
+        pass
 
 
 class _SinkAborted(Exception):

@@ -134,9 +134,16 @@ def test_pull_and_pushes_carry_their_bytes(job):
     assert sum(s["attrs"]["bytes"] for s in pushes) == on_disk
     dist = _one(spans, "ec.distribute")
     push_seconds = dist["attrs"]["pushSeconds"]
+    from_dat = sum(s["attrs"]["bytes"] for s in pushes
+                   if s["attrs"]["source"] == "dat")
     assert dist["attrs"] == {"serversAtStart": 3, "servers": 3,
                              "bytes": on_disk, "streams": 3,
-                             "pushSeconds": push_seconds}
+                             "pushSeconds": push_seconds,
+                             "bytesFromDat": from_dat}
+    # the ten data shards are sent out of the .dat (ISSUE 35)
+    assert 0 < from_dat == sum(
+        os.path.getsize(p) for d in dirs
+        for p in glob.glob(os.path.join(d, "*.ec0[0-9]")))
     assert 0 < push_seconds <= dist["durationMs"] / 1e3
     exts = sorted(s["attrs"]["ext"] for s in pushes)
     assert exts == sorted([f".ec{i:02d}" for i in range(14)]

@@ -1,5 +1,5 @@
 """`httpd.http_upload` (ISSUE 26): a file goes to the socket as a file —
-`socket.sendfile` on a plain connection, one reused 1 MiB buffer under
+`os.sendfile` on a plain connection, one reused 1 MiB buffer under
 TLS — with everything the old urllib path did kept: exact
 Content-Length, auth / trace / deadline headers, the per-operation
 timeout, and the receiver's own verdict when it rejects mid-body.
@@ -208,16 +208,18 @@ def test_a_stalled_receiver_trips_the_per_operation_timeout(
 
 def test_a_file_cut_short_while_it_goes_fails_the_upload(
         receiver, tmp_path, monkeypatch):
-    """A push that ends short must fail: the body stops at fewer bytes
-    than the Content-Length promised."""
+    """A push that ends short must fail: the file is cut to half under
+    the sender, and the body stops at fewer bytes than the
+    Content-Length promised."""
     http, seen = receiver
     path = _file(tmp_path, 4 * MIB, sparse=True)
-    whole = socket.socket.sendfile
+    real = os.sendfile
 
-    def half(self, f, offset=0, count=None):
-        return whole(self, f, offset, count // 2)
+    def cut_then_send(out, fd, offset, count):
+        os.truncate(path, 2 * MIB)
+        return real(out, fd, offset, count)
 
-    monkeypatch.setattr(socket.socket, "sendfile", half)
+    monkeypatch.setattr(os, "sendfile", cut_then_send)
     with pytest.raises(OSError, match="ended at 2097152 of 4194304"):
         http_upload("POST", f"{http.url}/admin/take", path, timeout=5)
     assert "sha256" not in seen

@@ -17,6 +17,7 @@ from seaweedfs_tpu.util import mem, wlog
 from seaweedfs_tpu.util.limiter import BoundedExecutor, bounded_parallel
 from seaweedfs_tpu.util.request_id import (ensure_request_id,
                                            get_request_id,
+                                           reset_request_id,
                                            set_request_id)
 from seaweedfs_tpu.util.skiplist import SkipList
 
@@ -93,10 +94,17 @@ def test_wlog_file_rotation(tmp_path):
 
 
 def test_request_id_adopt_and_mint():
-    rid = ensure_request_id("abc123")
-    assert rid == "abc123" and get_request_id() == "abc123"
-    rid2 = ensure_request_id(None)
-    assert rid2 and rid2 != "abc123"
+    # the id lives in this thread's context: given back at the end, or
+    # every later test of this process would run under it (a job's
+    # spans are told apart by request id, tests/test_ec_push_streams.py)
+    before = set_request_id(get_request_id())
+    try:
+        rid = ensure_request_id("abc123")
+        assert rid == "abc123" and get_request_id() == "abc123"
+        rid2 = ensure_request_id(None)
+        assert rid2 and rid2 != "abc123"
+    finally:
+        reset_request_id(before)
 
 
 def test_request_id_propagates_through_cluster(tmp_path):
