@@ -170,6 +170,29 @@ def run_chain(groups: "list[list[dict]]", seconds: float, t_open: float,
     return jobs, dry + max(0.0, end - now())
 
 
+def device_work(before: dict, after: dict) -> "int | None":
+    """Values the worker child read back from the chip between two of
+    its marks (`worker_proc.count_reads`): what the chip computed for
+    the program.  None where nothing is counted (JAX on the CPU)."""
+    a, b = before.get("reads"), after.get("reads")
+    return None if a is None or b is None else b - a
+
+
+def work_said(work: "int | None") -> str:
+    return "not counted" if work is None else f"{work:+d}"
+
+
+def check_device_work(what: str, work: "int | None", chip: bool) -> None:
+    """Every cell's path runs on the chip: a set-up job or a window that
+    did no work there fails the run at once, before it prints a line
+    whose device numbers would read a path the cell does not have.
+    Nothing is checked off the chip or where nothing is counted."""
+    if chip and work is not None and work <= 0:
+        raise BenchFailure(f"{what} did no work on the chip (values read "
+                           f"back from it {work_said(work)}): this cell's "
+                           "path does not run on the device")
+
+
 def volumes_for(seconds: float, job_seconds: float, margin: float) -> int:
     """Volumes a window of `seconds` can start jobs on, when a job
     takes `margin` of what the set-up's own job took."""
@@ -632,18 +655,22 @@ class Run:
 
     def set_up_job(self, vols: "list[dict]", submit) -> float:
         """One job before the window; the seconds it took, compiling
-        left out."""
-        c0 = self.cluster.wire.ask("mark")["compile"]["seconds"]
+        left out.  A job that did no work on the chip fails the run."""
+        m0 = self.cluster.wire.ask("mark")
         t0 = time.perf_counter()
         j = self.cluster.wait_job(submit(vols), JOB_TIMEOUT_S)
         took = time.perf_counter() - t0
-        compiling = self.cluster.wire.ask("mark")["compile"]["seconds"] - c0
-        say(f"set-up job on volume "
-            f"{','.join(str(v['vid']) for v in vols)}: {j['status']} "
-            f"in {took:.2f}s ({compiling:.2f}s of it compiling): "
-            f"{j['message']}")
+        m1 = self.cluster.wire.ask("mark")
+        compiling = m1["compile"]["seconds"] - m0["compile"]["seconds"]
+        work = device_work(m0, m1)
+        on = ",".join(str(v["vid"]) for v in vols)
+        say(f"set-up job on volume {on}: {j['status']} in {took:.2f}s "
+            f"({compiling:.2f}s of it compiling): {j['message']}; values "
+            f"read back from the chip {work_said(work)}")
         if j["status"] != "done":
             raise BenchFailure(f"set-up job failed: {j['message']}")
+        check_device_work(f"the set-up's {j.get('jobType', 'job')} job on "
+                          f"volume {on}", work, not self.args.rehearse)
         return took - compiling
 
     def break_rotation(self) -> None:
@@ -830,6 +857,7 @@ class Run:
 
         self.mark1 = mark1 = cluster.wire.ask("trace_stop", timeout=240) \
             if args.trace else cluster.wire.ask("mark")
+        self.work = device_work(mark0, mark1)
         vreq1 = cluster.volume_counters() if reads else None
         self.report = report = cluster.wire.ask("report")
         self.check_one_owner()
@@ -862,6 +890,7 @@ class Run:
             "volume_counters": None if vreq0 is None else
             {k: vreq1[k] - vreq0[k] for k in vreq1}, "trace": None}
         self.say_window()
+        check_device_work("the window", self.work, not args.rehearse)
         if self.rotation:
             self.mend()
 
@@ -954,20 +983,28 @@ class Run:
                 f"{rss / 1e9:.2f} GB")
         say(f"worker ledgers: staging {json.dumps(m['staging'])} "
             f"compile {json.dumps(m['compile'])} "
-            f"peak_bytes {json.dumps(m['peak_bytes'])}")
+            f"peak_bytes {json.dumps(m['peak_bytes'])} values read back "
+            f"from the chip {work_said(self.work)}")
 
     # -- after the window -----------------------------------------------------
 
     def read_trace(self, device: dict) -> "dict | None":
-        """Device times into `device` and the context; the breakdown."""
+        """Device times into `device` and the context; the breakdown.
+        On the chip a trace with no operation in the window fails the
+        run."""
         ev, lo, hi = self.mark1["events"], self.t_open, self.t_close
         busy = trace_reduce.busy_by_device(ev, lo, hi)
         busy_s = trace_reduce.busy_seconds(busy)
-        say(f"trace: {self.mark1['trace_bytes']} bytes, "
-            f"{sum(len(v) for v in ev['devices'].values())} device events "
-            f"on {sorted(ev['devices'])}, {len(ev['sync'])} sync marks")
-        if busy_s is None:
-            return None
+        seen = f"{sum(len(v) for v in ev['devices'].values())} device " \
+            f"events on {sorted(ev['devices'])}, {len(ev['sync'])} sync marks"
+        say(f"trace: {self.mark1['trace_bytes']} bytes, {seen}")
+        if self.args.rehearse:
+            return None         # no device, no device time
+        if not busy_s:
+            # a traced line's device numbers need an operation in the
+            # window: none is no line, never one without them
+            raise BenchFailure(f"the trace holds no device operation in the "
+                               f"window ({seen}): busy_s {busy_s}")
         self.ctx["trace"] = {"busy_s": busy_s, "busy": busy,
                              "window_s": hi - lo}
         device.update(busy_s=busy_s, window_s=hi - lo)

@@ -5,10 +5,11 @@ It does what `python -m seaweedfs_tpu worker -capabilities
 erasure_coding -backend jax` does (`ec_context.own_device()`, then a
 `PluginWorker` hosting `EcEncodeHandler(backend=...)` and
 `EcRebuildHandler()`), and besides answers the harness over its stdin
-and stdout, one JSON object a line each way: ledgers at a mark, the
-profiler started and stopped around the window, the trace reduced to
-lists, and the time of every progress report of every job.  The
-program's own prints go to stderr (the role's log).
+and stdout, one JSON object a line each way: ledgers and the count of
+values read back from the chip at a mark, the profiler started and
+stopped around the window, the trace reduced to lists, and the time of
+every progress report of every job.  The program's own prints go to
+stderr (the role's log).
 """
 
 from __future__ import annotations
@@ -20,6 +21,29 @@ import os
 import sys
 import threading
 import time
+
+
+def count_reads():
+    """A reader of the count of values the process has read back from
+    its JAX arrays, each `np.asarray` of one: whatever a chip computes
+    for the program comes back to the host this way, and a path that
+    computed nothing there reads nothing back.  The runtime keeps no
+    count that moves on every launch (its `num_allocs` counts only what
+    its buffer cache cannot serve: PERF.md 3), nor does the program:
+    its staging ledger misses the batch path, its compile ledger a
+    program already compiled.  On the CPU an array is read through the
+    buffer protocol and passes this by, so there the count is not
+    taken."""
+    from jax._src.array import ArrayImpl
+    value = ArrayImpl.__dict__["_value"].fget
+    lock, n = threading.Lock(), [0]
+
+    def counted(self):
+        with lock:
+            n[0] += 1
+        return value(self)
+    ArrayImpl._value = property(counted)
+    return lambda: n[0]
 
 
 def main() -> int:
@@ -81,10 +105,13 @@ def main() -> int:
                 (d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                 for d in jax.local_devices()}
 
+    reads = count_reads() if dev["platform"] != "cpu" else None
+
     def mark() -> dict:
         return {"t": time.time(), "staging": staging.snapshot(),
                 "compile": ec_context.compile_ledger(),
-                "peak_bytes": peaks()}
+                "peak_bytes": peaks(),
+                "reads": None if reads is None else reads()}
 
     worker = Worker(args.admin, args.master, args.dir,
                     [EcEncodeHandler(backend=args.backend),
