@@ -432,15 +432,17 @@ class EcEncodeHandler(JobHandler):
                 _mount_shards(target, vid, collection, sids)
         return placement
 
-    # -- batch execute: N volumes through ONE mesh launch per step -----
-    # (BASELINE config 3; VERDICT r2 Next #9 — volumes ride the
-    # data-parallel "stripe" axis, parallel/ec_batch.py)
+    # -- batch execute: N volumes in one job ---------------------------
+    # (BASELINE config 3; VERDICT r2 Next #9) — each volume through the
+    # single job's staged pipeline, one after another; on a worker with
+    # several devices every window is split over all of them
+    # (ops/staging.encode_shardings)
 
     def execute_batch(self, worker, job_id: str, params: dict) -> str:
-        from ...parallel.ec_batch import encode_volume_files_batch
+        from ...ops import staging
 
-        # dedupe while preserving order: a repeated id would append the
-        # same volume's rows twice into one set of shard files
+        # dedupe while preserving order: a repeated id would pull and
+        # encode the same volume twice into one set of shard files
         vids = list(dict.fromkeys(int(v) for v in params["volumeIds"]))
         collection = params.get("collection", "")
         ctx = self._make_ctx(params, collection, 0)
@@ -464,19 +466,26 @@ class EcEncodeHandler(JobHandler):
                     job_id, 0.05 + 0.25 * (i + 1) / n,
                     f"pulled volume {vid} ({i + 1}/{n})")
 
-            # one mesh-batched encode for the whole set: volumes ride
-            # the data-parallel stripe axis (parallel/ec_batch.py)
             for vid in vids:
                 _sort_index(bases[vid])
+            # one span for the job's encodes: job_encode_s.batch is a
+            # job's, and `windows` the launches all its volumes made
             with tracing.span("ec.encode", role="worker") as sp:
                 sp.set("volumes", n)
-                encode_volume_files_batch([bases[v] for v in vids], ctx)
+                launches = staging.snapshot()["launches"]
+                views = _encode_volumes(
+                    [bases[v] for v in vids], ctx,
+                    progress=_encode_progress(worker, job_id))
+                sp.set("windows", staging.snapshot()["launches"] - launches)
+                sp.set("windowDevices",
+                       _window_devices([bases[v] for v in vids], ctx))
             for vid in vids:
                 base = bases[vid]
                 dat_size = os.path.getsize(base + ".dat")
-                ec_encoder.save_ec_volume_info(
-                    base, ctx, dat_size, _read_dat_version(base))
-                if ec_decoder.find_dat_file_size(base, base) > dat_size:
+                version = _read_dat_version(base)
+                ec_encoder.save_ec_volume_info(base, ctx, dat_size, version)
+                if ec_decoder.find_dat_file_size(base, base,
+                                                 version) > dat_size:
                     raise RuntimeError(
                         f"volume {vid}: ecx entries exceed dat size")
             worker.report_progress(
@@ -484,11 +493,13 @@ class EcEncodeHandler(JobHandler):
                 f"batch-encoded {n} volumes ({_ran_on(ctx)})")
 
             for i, vid in enumerate(vids):
-                # encode_volume_files_batch wrote all of a volume's
-                # shards as files
+                # the .dat stays here until the job's end: a data shard
+                # is sent as ranges of it, as in a single job
+                where = _pushed_files(bases[vid], ctx)
+                where.update((to_ext(v.shard_id), v)
+                             for v in views[bases[vid]])
                 self._distribute_and_mount(
-                    worker, vid, collection, ctx,
-                    _pushed_files(bases[vid], ctx), started)
+                    worker, vid, collection, ctx, where, started)
                 worker.report_progress(
                     job_id, 0.6 + 0.3 * (i + 1) / n,
                     f"distributed volume {vid} ({i + 1}/{n})")
@@ -663,6 +674,43 @@ def _encode_progress(worker, job_id: str, every: float = 2.0):
                 job_id, 0.3 + 0.3 * done / max(total, 1),
                 f"encoding {done >> 20}/{total >> 20} MiB")
     return report
+
+
+def _encode_volumes(bases: "list[str]", ctx: ECContext, progress=None
+                    ) -> "dict[str, list[DatShardView]]":
+    """A batch job's encode: each pulled volume in turn through the
+    single job's pipeline (ec_encoder.write_parity_files: the parity
+    written alone, a window at a time, put split over every device the
+    worker holds where its words divide them).  Returns each base's
+    data-shard views; `progress(done, total)` counts the bytes of all
+    the volumes, so a job's progress never runs backwards."""
+    sizes = [os.path.getsize(base + ".dat") for base in bases]
+    total = sum(sizes)
+    views = {}
+    for i, base in enumerate(bases):
+        before = sum(sizes[:i])
+        note = None if progress is None else (
+            lambda done, _size, before=before: progress(before + done,
+                                                        total))
+        views[base] = ec_encoder.write_parity_files(base, ctx,
+                                                    progress=note)
+    return views
+
+
+def _window_devices(bases: "list[str]", ctx: ECContext) -> int:
+    """How many devices every window of these volumes' encode is split
+    over: all of staging.encode_shardings()' where each window's packed
+    words divide them (as staging.WindowedLaunch puts it), else 1; 0
+    for a host codec, which puts none."""
+    if ctx.backend != "jax":
+        return 0
+    from ...ops import staging
+    ndev = staging.encode_shardings()[2]
+    words = {-(-batch // 4) for base in bases
+             for _row, _block, _off, batch, _rows in
+             ec_encoder._encode_work_items(
+                 os.path.getsize(base + ".dat"), ctx)}
+    return ndev if all(w % ndev == 0 for w in words) else 1
 
 
 def _read_dat_version(base: str) -> int:

@@ -25,10 +25,8 @@ SMALL_BLOCK_SIZE = 1024 * 1024          # 1MB
 # ec_encoder.go:61-67; any batch that divides the block size yields
 # byte-identical shard files: geometry is preserved either way).  The
 # host codecs step by CPU_BATCH_SIZE.  A device codec steps by what one
-# staging window holds (ECContext.batch_size / rows_per_launch);
-# TPU_BATCH_SIZE is what parallel/ec_batch.py's own loop sends a launch.
+# staging window holds (ECContext.batch_size / rows_per_launch).
 CPU_BATCH_SIZE = 1024 * 1024
-TPU_BATCH_SIZE = 64 * 1024 * 1024
 
 
 def to_ext(shard_id: int) -> str:

@@ -362,46 +362,91 @@ def test_a_job_pushes_its_data_shards_out_of_the_dat(cluster, watched,
                                          for s in pushes)
 
 
-def test_a_batch_job_still_pushes_files(cluster, watched):
-    """`execute_batch` hands the distribute what its encode wrote:
-    files, every one of them."""
+def run_a_batch_job(cluster, watched, collection: str) -> None:
+    """A job of two or more volumes: each volume's data shards go out
+    of its `.dat` as in a single job, its parity as files, and the
+    job's one `ec.encode` span says how many windows its volumes'
+    encodes put on the device and over how many devices each."""
     master, servers, admin, tmp = cluster
     http_json("POST", f"{master.url}/vol/grow",
-              {"count": 2, "replication": "000", "collection": "batch"})
+              {"count": 2, "replication": "000", "collection": collection})
     rng = np.random.default_rng(35)
     blobs = {}
     for _ in range(24):
         data = rng.integers(0, 256, 50_000, dtype=np.uint8).tobytes()
         blobs[operation.submit(master.url, data,
-                               collection="batch")] = data
+                               collection=collection)] = data
     vids = sorted({int(fid.split(",")[0]) for fid in blobs})
     assert len(vids) >= 2, vids
     time.sleep(0.5)                  # the master hears of their sizes
-    bases = {vid: keep_the_source(tmp, servers, vid, "batch")
+    bases = {vid: keep_the_source(tmp, servers, vid, collection)
              for vid in vids}
     detail = submit_and_wait(admin, {"volumeIds": vids,
-                                     "collection": "batch"})
+                                     "collection": collection})
     assert detail["status"] == "done", detail
     ctx = ECContext()
+    d = ctx.data_shards
     for vid in vids:
         assert settle(master, vid, [5, 5, 4]) == [5, 5, 4]
         ec_encoder.write_ec_files(bases[vid], ctx)
         for sid in range(ctx.total):
-            (there,) = on_the_targets(servers, vid, "batch",
+            (there,) = on_the_targets(servers, vid, collection,
                                       ctx.to_ext(sid))
             with open(there, "rb") as got, \
                     open(bases[vid] + ctx.to_ext(sid), "rb") as expected:
                 assert got.read() == expected.read(), (vid, sid)
     for fid, data in blobs.items():
         assert operation.read(master.url, fid) == data
+
     spans = spans_of(admin, detail)
-    assert len(spans["ec.push"]) == len(vids) * (14 + 2 * 3)
-    assert {s["attrs"]["source"] for s in spans["ec.push"]} == {"file"}
-    assert {s["attrs"]["ranges"] for s in spans["ec.push"]} == {1}
-    assert [s["attrs"]["bytesFromDat"]
-            for s in spans["ec.distribute"]] == [0] * len(vids)
-    # its work dir held every shard as a file; its check read .ec00
+    pushes = spans["ec.push"]
+    assert len(pushes) == len(vids) * (14 + 2 * 3)
+    for s in pushes:
+        ext = s["attrs"]["ext"]
+        data_shard = ext in [ctx.to_ext(i) for i in range(d)]
+        assert s["attrs"]["source"] == ("dat" if data_shard else "file")
+    dists = spans["ec.distribute"]
+    assert len(dists) == len(vids)
+    for vid, dist in zip(vids, dists):
+        shard_size = os.path.getsize(bases[vid] + ctx.to_ext(0))
+        assert dist["attrs"]["bytesFromDat"] == d * shard_size, vid
+    # its work dir held the parity as files, never a data shard; its
+    # check read the version off the .dat
     for vid in vids:
-        assert {f"{vid}.ec{sid:02d}" for sid in range(14)} <= \
+        assert {f"{vid}.ec{sid:02d}" for sid in range(d, 14)} <= \
             watched["held"]
-    assert [len(args) for args in watched["checks"]] == [2] * len(vids)
+        assert not {f"{vid}.ec{sid:02d}" for sid in range(d)} & \
+            watched["held"]
+    assert [len(args) for args in watched["checks"]] == [3] * len(vids)
+
+    (encode,) = spans["ec.encode"]
+    assert encode["attrs"]["volumes"] == len(vids)
+    reads = spans["encode.read"]
+    assert len(reads) == len(vids)
+    if ctx.backend == "jax":
+        # every volume's windows, each a put and a fetch, and each put
+        # split over the 8 virtual devices
+        windows = sum(s["attrs"]["windows"] for s in reads)
+        assert encode["attrs"]["windows"] == windows > 0
+        assert len(spans["stage.h2d"]) == len(spans["stage.d2h"]) == windows
+        assert encode["attrs"]["windowDevices"] == 8
+    else:
+        # a host codec makes no launch
+        assert encode["attrs"]["windows"] == 0
+        assert encode["attrs"]["windowDevices"] == 0
+        assert "stage.h2d" not in spans
+
+
+def test_a_batch_job_still_pushes_files(cluster, watched):
+    """On the worker's host codec."""
+    run_a_batch_job(cluster, watched, "batch")
+
+
+def test_a_batch_job_on_the_device_codec(cluster, watched, monkeypatch):
+    """On the device codec: JAX on eight virtual CPU devices, a window
+    of one 1 MiB row."""
+    from seaweedfs_tpu.ops import staging
+    monkeypatch.setenv("SEAWEEDFS_TPU_EC_BACKEND", "jax")
+    monkeypatch.setattr(staging, "WINDOW_BYTES", 10 << 20)
+    assert ECContext().backend == "jax"
+    run_a_batch_job(cluster, watched, "batchjax")

@@ -6,6 +6,8 @@ second implementation) with the distributed TPU path as the second
 implementation.
 """
 
+import os
+
 import numpy as np
 import pytest
 
@@ -131,39 +133,64 @@ def test_named_sharding_staged_encode_matches_shard_map(mesh,
         unpack_words(np.asarray(got32), nbytes), want)
 
 
-def test_encode_volume_files_batch_byte_identical(mesh, tmp_path,
-                                                  monkeypatch):
-    """The multi-volume FILE batch path (parallel/ec_batch.py — what
-    the tpu_ec worker's execute_batch runs) produces shard files
-    byte-identical to per-volume write_ec_files, across volumes of
-    DIFFERENT sizes (per-volume tails, zero-volume mesh padding)."""
-    from seaweedfs_tpu.parallel import ec_batch
+def test_a_batch_jobs_encode_splits_each_window_over_the_mesh(
+        tmp_path, monkeypatch):
+    """A batch job's encode (erasure_coding._encode_volumes, what the
+    tpu_ec worker's execute_batch runs) leaves, for volumes of
+    DIFFERENT sizes (per-volume tails), parity files and data-shard
+    views that are byte for byte write_ec_files' fourteen files, and
+    puts every window split over all eight devices."""
+    from seaweedfs_tpu.ops import staging
+    from seaweedfs_tpu.plugin.handlers import erasure_coding as ec_handler
     from seaweedfs_tpu.storage.erasure_coding import ec_encoder
     from seaweedfs_tpu.storage.erasure_coding.ec_context import ECContext
 
-    # shrink geometry so several rows/steps exercise the batching
-    monkeypatch.setattr(ec_batch, "SMALL_BLOCK_SIZE", 1024)
-    monkeypatch.setattr(ec_batch, "TPU_BATCH_SIZE", 4096)
+    # shrink geometry so several rows and windows exercise the batch:
+    # 4 rows of 1 KiB a window, 1024 words, which 8 devices divide
     monkeypatch.setattr(ec_encoder, "SMALL_BLOCK_SIZE", 1024)
+    monkeypatch.setattr(staging, "WINDOW_BYTES", 10 * 4096)
+    puts = []
+    device_put = jax.device_put
+
+    def seen(x, *args, **kw):
+        out = device_put(x, *args, **kw)
+        if getattr(x, "dtype", None) == np.uint32:
+            puts.append(out.sharding)
+        return out
+    monkeypatch.setattr(jax, "device_put", seen)
 
     rng = np.random.default_rng(11)
     sizes = [50_000, 31_000, 12_345]  # 5/4/2 rows: ragged tails
-    bases_batch, bases_ref = [], []
+    blobs, bases = [], []
     for i, size in enumerate(sizes):
-        blob = rng.integers(0, 256, size, dtype=np.uint8).tobytes()
-        for kind, acc in (("b", bases_batch), ("r", bases_ref)):
-            base = str(tmp_path / f"{kind}{i}")
-            with open(base + ".dat", "wb") as f:
-                f.write(blob)
-            acc.append(base)
+        blobs.append(rng.integers(0, 256, size, dtype=np.uint8).tobytes())
+        for kind in ("b", "r"):
+            with open(tmp_path / f"{kind}{i}.dat", "wb") as f:
+                f.write(blobs[-1])
+        bases.append(str(tmp_path / f"b{i}"))
 
-    ctx = ECContext(backend="cpu")
-    ec_batch.encode_volume_files_batch(bases_batch, ctx, mesh)
-    for base in bases_ref:
-        ec_encoder.write_ec_files(base, ctx)
+    ctx = ECContext(backend="jax")
+    launches = staging.snapshot()["launches"]
+    views = ec_handler._encode_volumes(bases, ctx)
+    # 2 + 1 + 1 windows, each put split over the 8 devices
+    assert staging.snapshot()["launches"] - launches == len(puts) == 4
+    assert all(len(sh.device_set) == 8 and
+               sh.spec == jax.sharding.PartitionSpec(None, "batch")
+               for sh in puts), puts
+    assert ec_handler._window_devices(bases, ctx) == 8
 
-    for bb, br in zip(bases_batch, bases_ref):
-        for i in range(14):
-            a = open(bb + f".ec{i:02d}", "rb").read()
-            b = open(br + f".ec{i:02d}", "rb").read()
-            assert a == b, f"{bb} shard {i} differs from per-volume"
+    for i, (base, dat) in enumerate(zip(bases, blobs)):
+        ref = str(tmp_path / f"r{i}")
+        ec_encoder.write_ec_files(ref, ECContext(backend="cpu"))
+        assert [v.shard_id for v in views[base]] == list(range(10))
+        for sid in range(14):
+            with open(ref + f".ec{sid:02d}", "rb") as f:
+                want = f.read()
+            if sid < 10:
+                got = b"".join(dat[off:off + n] + bytes(zeros) for off, n,
+                               zeros in views[base][sid].pieces)
+            else:
+                with open(base + f".ec{sid:02d}", "rb") as f:
+                    got = f.read()
+            assert got == want, f"volume {i} shard {sid}"
+        assert not os.path.exists(base + ".ec00")
