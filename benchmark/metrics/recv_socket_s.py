@@ -1,0 +1,10 @@
+"""Seconds a push's receiver spends on the socket's half of its copy:
+the `busySeconds` of the `receive.socket` span under each volume role's
+`POST /admin/receive_file` span found in the jobs' traces, mean over
+those spans (benchmark/copy_halves.py)."""
+
+from benchmark import copy_halves
+
+
+def read(ctx):
+    return copy_halves.mean_busy(ctx, "receive", "socket")

@@ -301,7 +301,7 @@ class WindowedLaunch:
             tracing.emit_span_batch([
                 dict(base, name="stage.h2d", start=self._h2d_wall,
                      duration=s.h2d_seconds,
-                     attrs={"bytes": s.h2d_bytes, "packSeconds": 0.0}),
+                     attrs={"bytes": s.h2d_bytes}),
                 dict(base, name="stage.d2h", start=wall0,
                      duration=s.d2h_seconds,
                      attrs={"bytes": s.d2h_bytes})])

@@ -149,7 +149,10 @@ class AdminServer:
         r("POST", "/worker/detection_result", self._detection_result)
         r("POST", "/worker/progress", self._progress,     # JobProgressUpdate
           quiet=True)
-        r("POST", "/worker/complete", self._complete)     # JobCompleted
+        # a job's submit and its completion (which carries the
+        # worker's spans) are job work (HttpServer.route job=)
+        r("POST", "/worker/complete", self._complete,     # JobCompleted
+          job=True)
         r("GET", "/", self._ui)
         # multi-page admin UI (weed/admin/view/app/ pages)
         r("GET", "/ui/volumes", self._ui_volumes)
@@ -160,7 +163,7 @@ class AdminServer:
         r("POST", "/ui/actions", self._ui_actions)
         r("GET", "/maintenance/queue", self._queue)
         r("POST", "/maintenance/trigger_detection", self._trigger)
-        r("POST", "/maintenance/submit_job", self._submit_job)
+        r("POST", "/maintenance/submit_job", self._submit_job, job=True)
         from ..server.debug import install_debug_routes
         install_debug_routes(self.http)  # incl. ingested job traces
         self._detect_thread: threading.Thread | None = None

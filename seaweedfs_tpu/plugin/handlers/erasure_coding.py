@@ -26,7 +26,8 @@ from concurrent.futures import ThreadPoolExecutor
 
 from ... import tracing
 from ...operation import master_json
-from ...server.httpd import http_download, http_json, http_upload
+from ...server.httpd import (CopyHalves, http_download, http_json,
+                             http_upload)
 from ...storage.erasure_coding import ECContext
 from ...storage.erasure_coding import ec_context, ec_decoder, ec_encoder
 from ...storage.erasure_coding.ec_context import to_ext
@@ -205,16 +206,20 @@ class EcEncodeHandler(JobHandler):
         with tracing.span("ec.pull", role="worker") as sp:
             sp.set("source", source)
             pulled = 0
+            # one pair of stage spans for both files: the socket's half
+            # of the copy against the tmpfs write's
+            halves = CopyHalves()
             for ext in (".dat", ".idx"):
                 status, _hdrs = http_download(
                     f"{source}/admin/volume_file?volumeId={vid}"
                     f"&collection={collection}&ext={ext}", base + ext,
-                    timeout=600)
+                    timeout=600, halves=halves)
                 if status != 200:
                     raise RuntimeError(
                         f"copy {ext} from {source}: {status}")
                 pulled += os.path.getsize(base + ext)
             sp.set("bytes", pulled)
+            tracing.emit_span_batch(halves.spans("pull"))
 
     def _unwind_volumes(self, worker, collection: str, ctx: ECContext,
                         vol_urls: "dict[int, list[str]]") -> None:

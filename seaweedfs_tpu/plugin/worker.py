@@ -186,7 +186,9 @@ class PluginWorker:
         tracing.adopt_remote_parent(trace_parent, role="worker")
         h = self.handlers.get(job_type)
         try:
-            with tracing.span(f"job:{job_type}", role="worker") as sp:
+            # a job's spans: kept in the jobs' ring (tracing.py)
+            with tracing.span(f"job:{job_type}", role="worker",
+                              job=True) as sp:
                 sp.set("jobId", job_id)
                 try:
                     if h is None:

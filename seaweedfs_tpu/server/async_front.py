@@ -331,7 +331,8 @@ class AsyncFront:
             route = outer._prefix_route(req.method, req.path)
         sp = tracing.start_span(
             f"{req.method} {req.path}", role=outer.role,
-            parent=parent_span, trace_id=rid)
+            parent=parent_span, trace_id=rid,
+            job=(req.method, req.path) in outer.job_routes)
         if dl is not None:
             sp.set("deadlineMs", int(dl.remaining() * 1e3))
         qos_release = None

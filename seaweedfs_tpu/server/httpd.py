@@ -178,6 +178,71 @@ class Request:
         self._body = b""
 
 
+class CopyHalves:
+    """Where socket-to-file copies (copy_to_file) spent their time,
+    each half apart: `socket` is the reads of the sender's bytes,
+    `file` the writes that land them, each [calls, busy seconds on the
+    wall clock, seconds on the thread's CPU, bytes].  Copies that share
+    one add up (a pull's `.dat` and `.idx`): `start` is the first
+    copy's first read on time.time(), `wall` their loops together."""
+
+    __slots__ = ("start", "wall", "socket", "file")
+
+    def __init__(self):
+        self.start = 0.0
+        self.wall = 0.0
+        self.socket = [0, 0.0, 0.0, 0]
+        self.file = [0, 0.0, 0.0, 0]
+
+    def spans(self, stage: str) -> "list[dict]":
+        """`<stage>.socket` and `<stage>.file` as tracing.emit_span_batch
+        items, under the caller's current span: the stage-span form of
+        the EC pipeline's `encode.read` / `.codec` / `.write`."""
+        return [{"name": f"{stage}.{half}", "start": self.start,
+                 "duration": self.wall,
+                 "attrs": {"calls": calls, "busySeconds": round(busy, 6),
+                           "cpuSeconds": round(cpu, 6), "bytes": n}}
+                for half, (calls, busy, cpu, n) in
+                (("socket", self.socket), ("file", self.file))]
+
+
+def copy_to_file(chunks, f, halves: CopyHalves, each=None) -> int:
+    """Write every chunk `chunks` yields (an iterator that reads a
+    socket: stream_body, a response's reads) to the open file `f`, and
+    add to `halves` what each half took: the iterator's next() is the
+    socket half, f.write and then `each(chunk)` (a running CRC) the file
+    half.  Four clock reads a chunk.  Returns the bytes copied; emits
+    no span, so a caller with no span to hang them under leaves none."""
+    sock, fil = halves.socket, halves.file
+    if not halves.start:
+        halves.start = time.time()
+    t0 = t = time.perf_counter()
+    c = time.thread_time()
+    copied = 0
+    it = iter(chunks)
+    while True:
+        chunk = next(it, None)
+        t1, c1 = time.perf_counter(), time.thread_time()
+        sock[1] += t1 - t
+        sock[2] += c1 - c
+        if chunk is None:
+            break
+        f.write(chunk)
+        if each is not None:
+            each(chunk)
+        t, c = time.perf_counter(), time.thread_time()
+        n = len(chunk)
+        copied += n
+        sock[0] += 1
+        sock[3] += n
+        fil[0] += 1
+        fil[1] += t - t1
+        fil[2] += c - c1
+        fil[3] += n
+    halves.wall += t1 - t0
+    return copied
+
+
 Route = Callable[[Request], "tuple[int, object]"]
 
 
@@ -259,6 +324,7 @@ class HttpServer:
         # fallback from tables built once at boot.
         self.prefix_routes: dict[str, list] = {}
         self.quiet_routes: set[tuple[str, str]] = set()  # route(quiet=)
+        self.job_routes: set[tuple[str, str]] = set()    # route(job=)
         self.fallback: Route | None = None
         # optional auth hook (security/guard.go Guard): returns None to
         # continue or a (status, payload) response to short-circuit
@@ -348,7 +414,8 @@ class HttpServer:
                     route = outer._prefix_route(req.method, req.path)
                 sp = tracing.start_span(
                     f"{req.method} {req.path}", role=outer.role,
-                    parent=parent_span, trace_id=rid)
+                    parent=parent_span, trace_id=rid,
+                    job=(req.method, req.path) in outer.job_routes)
                 if dl is not None:
                     sp.set("deadlineMs", int(dl.remaining() * 1e3))
                 status = 0
@@ -690,16 +757,22 @@ class HttpServer:
         self._async = None   # asyncio front, when selected (start())
 
     def route(self, method: str, path: str, fn: Route,
-              quiet: bool = False) -> None:
+              quiet: bool = False, job: bool = False) -> None:
         """`quiet` marks chatter — status, poll, scrape and heartbeat
         routes: their server spans are recorded only when they err or
         are slow (tracing.Span.quiet), so a minute of polling cannot
-        turn the ring over on the trace of the job being polled."""
+        turn the ring over on the trace of the job being polled.
+        `job` marks what a maintenance job asks of this role (a pull,
+        a push, a mount): its server span and the rest of its trace go
+        to the jobs' ring (tracing.start_span), out of reach of the
+        client traffic that fills the other."""
         self.routes[(method, path)] = fn
-        if quiet:
-            self.quiet_routes.add((method, path))
-        else:
-            self.quiet_routes.discard((method, path))
+        for flag, routes in ((quiet, self.quiet_routes),
+                             (job, self.job_routes)):
+            if flag:
+                routes.add((method, path))
+            else:
+                routes.discard((method, path))
 
     def close_server_span(self, sp, req, status: int,
                           cpu: "float | None", sent: int) -> None:
@@ -967,13 +1040,15 @@ def _receiver_verdict(conn) -> "tuple[int, bytes, dict] | None":
 
 def http_download(url: str, dest_path: str,
                   headers: dict | None = None, timeout: float = 60.0,
-                  chunk_size: int = 4 << 20) -> tuple[int, dict]:
+                  chunk_size: int = 4 << 20,
+                  halves: "CopyHalves | None" = None) -> tuple[int, dict]:
     """GET `url` streaming the response body to `dest_path` in chunks —
     bounded memory no matter the file size (the worker's bulk volume
     pull; the reference streams CopyFile the same way,
     volume_server.proto:69).  Returns (status, response headers); on a
     non-2xx status dest_path is removed and the (small) error body is
-    left unconsumed.
+    left unconsumed.  The copy's socket and file halves are added to
+    `halves` where one is given (copy_to_file).
 
     `timeout` is a per-socket-operation stall bound, not a transfer
     bound: a 30GB pull may run for hours as long as bytes keep
@@ -994,23 +1069,25 @@ def http_download(url: str, dest_path: str,
     # later mount, and an error must never clobber a pre-existing dest
     import uuid as _uuid
     tmp = f"{dest_path}.download.{_uuid.uuid4().hex}"
+
+    def chunks(resp):
+        while True:
+            if _fire_fault("httpd.download.chunk",
+                           key=full_url) is not None:
+                # truncate/drop both mean "the source died mid-body":
+                # surface it, never os.replace a short file into place
+                raise IOError(
+                    f"download {url}: fault-injected mid-body failure")
+            chunk = resp.read(chunk_size)
+            if not chunk:
+                return
+            yield chunk
+
     try:
         with urllib.request.urlopen(req, timeout=timeout,
                                     context=ctx) as resp:
             with open(tmp, "wb") as f:
-                while True:
-                    if _fire_fault("httpd.download.chunk",
-                                   key=full_url) is not None:
-                        # truncate/drop both mean "the source died
-                        # mid-body": surface it, never os.replace a
-                        # short file into place
-                        raise IOError(
-                            f"download {url}: fault-injected "
-                            f"mid-body failure")
-                    chunk = resp.read(chunk_size)
-                    if not chunk:
-                        break
-                    f.write(chunk)
+                copy_to_file(chunks(resp), f, halves or CopyHalves())
             _os.replace(tmp, dest_path)
             return resp.status, dict(resp.headers)
     except urllib.error.HTTPError as e:

@@ -29,8 +29,8 @@ from ..storage.erasure_coding.ec_context import to_ext
 from ..storage.needle import Needle
 from ..stats import PROCESS
 from ..storage.store import Store
-from .httpd import FileSlice, HttpServer, Request, http_bytes, \
-    http_download, http_json, is_admin_path
+from .httpd import CopyHalves, FileSlice, HttpServer, Request, \
+    copy_to_file, http_bytes, http_download, http_json, is_admin_path
 
 # shared request-field validator (also used by the master's assign
 # front door) lives in security.py
@@ -72,29 +72,33 @@ class VolumeServer:
                            fsync=fsync)
         for loc in self.store.locations:
             loc.max_volume_count = max_volume_count
+        # job=True: what a maintenance job asks of this role (mark
+        # readonly, the pull, the pushes, mount, the source's delete),
+        # whose spans client reads must not evict (HttpServer.route)
         r = self.http.route
         r("GET", "/status", self._status)
         # volume admin <- volume_server.proto AllocateVolume etc.
         r("POST", "/admin/allocate_volume", self._allocate_volume)
-        r("POST", "/admin/delete_volume", self._delete_volume)
+        r("POST", "/admin/delete_volume", self._delete_volume, job=True)
         r("POST", "/admin/mount_volume", self._mount_volume)
         r("POST", "/admin/unmount_volume", self._unmount_volume)
-        r("POST", "/admin/set_readonly", self._set_readonly)
+        r("POST", "/admin/set_readonly", self._set_readonly, job=True)
         r("POST", "/admin/configure_volume", self._configure_volume)
         r("POST", "/admin/vacuum", self._vacuum)
-        r("GET", "/admin/volume_file", self._read_volume_file)
-        r("POST", "/admin/receive_file", self._receive_file)
+        r("GET", "/admin/volume_file", self._read_volume_file, job=True)
+        r("POST", "/admin/receive_file", self._receive_file, job=True)
         # EC admin <- volume_server.proto:89-108
         r("POST", "/admin/ec/generate", self._ec_generate)
-        r("POST", "/admin/ec/shard_write", self._ec_shard_write)
+        r("POST", "/admin/ec/shard_write", self._ec_shard_write, job=True)
         r("POST", "/admin/ec/shard_write_commit",
           self._ec_shard_write_commit)
         r("POST", "/admin/ec/shard_write_abort",
           self._ec_shard_write_abort)
-        r("POST", "/admin/ec/mount", self._ec_mount)
-        r("POST", "/admin/ec/unmount", self._ec_unmount)
+        r("POST", "/admin/ec/mount", self._ec_mount, job=True)
+        r("POST", "/admin/ec/unmount", self._ec_unmount, job=True)
         r("POST", "/admin/ec/copy", self._ec_copy)
-        r("POST", "/admin/ec/delete_shards", self._ec_delete_shards)
+        r("POST", "/admin/ec/delete_shards", self._ec_delete_shards,
+          job=True)
         r("POST", "/admin/ec/rebuild", self._ec_rebuild)
         r("POST", "/admin/ec/to_volume", self._ec_to_volume)
         r("GET", "/admin/ec/shard_read", self._ec_shard_read)
@@ -1403,7 +1407,6 @@ class VolumeServer:
             if v is not None:
                 v.detach_native()
             self._nc_drop_volume(vid)
-        n = 0
         # temp + rename, like the gRPC ReceiveFile twin: a push that
         # dies mid-stream (or whose relay SOURCE dies — http_relay
         # starts this upload before the download completes) must never
@@ -1411,23 +1414,29 @@ class VolumeServer:
         # later resolve
         import uuid as _uuid
         from .. import faults
+
+        def chunks():
+            for chunk in req.stream_body():
+                if faults.fire("volume.receive_file.recv",
+                               key=f"{vid}{ext}") is not None:
+                    raise IOError(
+                        f"receive_file {vid}{ext}: fault-injected "
+                        f"mid-stream failure")
+                yield chunk
+
         tmp = f"{base}{ext}.recv.{_uuid.uuid4().hex}"
+        halves = CopyHalves()
         try:
             with open(tmp, "wb") as f:
-                for chunk in req.stream_body():
-                    if faults.fire("volume.receive_file.recv",
-                                   key=f"{vid}{ext}") is not None:
-                        raise IOError(
-                            f"receive_file {vid}{ext}: fault-injected "
-                            f"mid-stream failure")
-                    f.write(chunk)
-                    n += len(chunk)
+                n = copy_to_file(chunks(), f, halves)
             os.replace(tmp, base + ext)
         finally:
             try:
                 os.remove(tmp)
             except OSError:
                 pass
+        # under this request's server span: recv against the write
+        tracing.emit_span_batch(halves.spans("receive"))
         return 200, {"bytes": n}
 
     def _file_path(self, vid: int, collection: str, ext: str
@@ -1769,7 +1778,7 @@ class VolumeServer:
         base = self._base_path(vid, collection)
         tmp = f"{base}{to_ext(sid)}.scatter.{upload_id}"
         crc = 0
-        n = 0
+        halves = CopyHalves()
         ok = False
         try:
             # page-cache writes, like every other ReceiveFile surface
@@ -1777,7 +1786,8 @@ class VolumeServer:
             # contract matches the seed balance-move it replaces —
             # integrity is the CRC + commit handshake, not fsync
             from .. import faults
-            with open(tmp, "wb") as f:
+
+            def chunks():
                 for chunk in req.stream_body():
                     directive = faults.fire("volume.shard_write.recv",
                                             key=f"{vid}.{sid}")
@@ -1788,9 +1798,14 @@ class VolumeServer:
                         raise IOError(
                             f"shard_write {vid}.{sid}: fault-injected "
                             f"{directive} mid-stream")
-                    f.write(chunk)
-                    crc = zlib.crc32(chunk, crc)
-                    n += len(chunk)
+                    yield chunk
+
+            def running_crc(chunk):
+                nonlocal crc
+                crc = zlib.crc32(chunk, crc)
+
+            with open(tmp, "wb") as f:
+                n = copy_to_file(chunks(), f, halves, each=running_crc)
             ok = True
         finally:
             if not ok:
@@ -1798,6 +1813,7 @@ class VolumeServer:
                     os.remove(tmp)
                 except OSError:
                     pass
+        tracing.emit_span_batch(halves.spans("receive"))
         with self._pending_lock:
             self._pending_shard_writes[upload_id] = {
                 "path": tmp, "crc": crc, "bytes": n, "vid": vid,

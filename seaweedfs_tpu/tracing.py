@@ -28,7 +28,13 @@ Design constraints, in order:
   util/wlog at WARN with their attrs (the slow-request log);
 - chatter does not evict work: a server span of a route registered
   quiet (status, poll, scrape, heartbeat) is recorded only when it
-  errs or is slow, so an EC job's trace outlives a minute of polls.
+  errs or is slow, so an EC job's trace outlives a minute of polls;
+- nor does client traffic: a span opened with `job=True` (a route
+  registered as job work, the worker's job span) marks its trace as a
+  job's, and every later span of a marked trace, and every span
+  `ingest` re-records, goes to a second ring of the same size, so a
+  volume role's client GETs cannot turn over the trace of the job it
+  served.  The readers merge both rings by start.
 
 API shapes the SWFS007 lint understands:
 
@@ -93,6 +99,10 @@ def slow_ms() -> float:
 
 
 _buffer: "deque[dict]" = deque(maxlen=buffer_size())
+# the jobs' ring, and the traces marked as a job's (insertion-ordered,
+# at most buffer_size() of them: the oldest mark goes first)
+_job_buffer: "deque[dict]" = deque(maxlen=buffer_size())
+_job_traces: "dict[str, None]" = {}
 _buffer_lock = threading.Lock()
 
 # (trace_id, span_id, role) of the active span on this context; the
@@ -216,14 +226,32 @@ def _slow_log_and_sample(doc: dict, threshold: float,
     return not (rate < 1.0 and random.random() >= rate)
 
 
+def _resize_locked() -> None:
+    global _buffer, _job_buffer
+    size = buffer_size()
+    if _buffer.maxlen != size:
+        # env knob changed since import (tests): rebuild, keeping the
+        # newest spans
+        _buffer = deque(_buffer, maxlen=size)
+        _job_buffer = deque(_job_buffer, maxlen=size)
+
+
 def _buffer_extend(docs) -> None:
-    global _buffer
     with _buffer_lock:
-        if _buffer.maxlen != buffer_size():
-            # env knob changed since import (tests): rebuild, keeping
-            # the newest spans
-            _buffer = deque(_buffer, maxlen=buffer_size())
-        _buffer.extend(docs)
+        _resize_locked()
+        for doc in docs:
+            (_job_buffer if doc["traceId"] in _job_traces
+             else _buffer).append(doc)
+
+
+def _mark_job(trace_id: str) -> None:
+    """Record the rest of this trace's spans in the jobs' ring."""
+    with _buffer_lock:
+        if trace_id in _job_traces:
+            return
+        _job_traces[trace_id] = None
+        if len(_job_traces) > buffer_size():
+            del _job_traces[next(iter(_job_traces))]
 
 
 def _record(doc: dict) -> None:
@@ -232,7 +260,7 @@ def _record(doc: dict) -> None:
 
 
 def start_span(name: str, role: str = "", parent: "str | None" = None,
-               trace_id: "str | None" = None) -> Span:
+               trace_id: "str | None" = None, job: bool = False) -> Span:
     """Open a span and make it the context's current span.  The caller
     MUST finish() it (or use span() / the with-statement form); the
     SWFS007 lint flags call sites that do neither.
@@ -240,7 +268,9 @@ def start_span(name: str, role: str = "", parent: "str | None" = None,
     Parentage: explicit `parent` wins, else the context's current
     span.  Trace id: explicit wins, else the current span's, else the
     active request id, else a fresh id (a traced unit outside any
-    request still gets a coherent trace)."""
+    request still gets a coherent trace).  `job` marks the trace as a
+    job's: this span and the trace's later spans go to the jobs'
+    ring."""
     cur = _current.get()
     if parent is None:
         parent = cur[1] if cur else ""
@@ -249,16 +279,18 @@ def start_span(name: str, role: str = "", parent: "str | None" = None,
     if trace_id is None:
         trace_id = (cur[0] if cur else "") or get_request_id() or \
             secrets.token_hex(8)
+    if job:
+        _mark_job(trace_id)
     sp = Span(name, trace_id, new_span_id(), parent, role)
     sp._token = _current.set((sp.trace_id, sp.span_id, sp.role))
     return sp
 
 
 def span(name: str, role: str = "", parent: "str | None" = None,
-         trace_id: "str | None" = None) -> Span:
+         trace_id: "str | None" = None, job: bool = False) -> Span:
     """Context-manager form (the default way to trace a block)."""
     return start_span(name, role=role, parent=parent,
-                      trace_id=trace_id)
+                      trace_id=trace_id, job=job)
 
 
 def emit_span(name: str, start: float, duration: float,
@@ -390,13 +422,15 @@ def ingest(spans: "list[dict]") -> int:
     """Re-record span documents produced by ANOTHER process into this
     process's ring buffer (the admin ingests worker job spans from
     completion reports — workers have no HTTP listener of their own
-    for trace.show to query).  Malformed entries are dropped, span
-    ids already buffered are skipped (at-least-once reports must not
-    duplicate); returns how many were added."""
-    global _buffer
+    for trace.show to query): a job's spans, so into the jobs' ring.
+    Malformed entries are dropped, span ids already buffered are
+    skipped (at-least-once reports must not duplicate); returns how
+    many were added."""
     added = 0
     with _buffer_lock:
+        _resize_locked()
         have = {d["spanId"] for d in _buffer}
+        have.update(d["spanId"] for d in _job_buffer)
         for doc in spans or []:
             if not isinstance(doc, dict):
                 continue
@@ -410,26 +444,31 @@ def ingest(spans: "list[dict]") -> int:
             doc.setdefault("role", "")
             doc.setdefault("start", 0.0)
             doc.setdefault("durationMs", 0.0)
-            if _buffer.maxlen != buffer_size():
-                _buffer = deque(_buffer, maxlen=buffer_size())
-            _buffer.append(doc)
+            _job_buffer.append(doc)
             have.add(doc["spanId"])
             added += 1
     return added
 
 
-def spans_for(trace_id: str) -> "list[dict]":
+def _both_rings(trace_id: "str | None" = None) -> "list[dict]":
+    """Both rings' spans (of one trace, where given), merged by start."""
     with _buffer_lock:
-        return [dict(d) for d in _buffer if d["traceId"] == trace_id]
+        docs = [d for ring in (_buffer, _job_buffer) for d in ring
+                if trace_id is None or d["traceId"] == trace_id]
+    return sorted(docs, key=lambda d: d["start"])
+
+
+def spans_for(trace_id: str) -> "list[dict]":
+    return [dict(d) for d in _both_rings(trace_id)]
 
 
 def recent_spans(limit: int = 200) -> "list[dict]":
-    with _buffer_lock:
-        docs = list(_buffer)
-    return [dict(d) for d in docs[-max(1, limit):]]
+    return [dict(d) for d in _both_rings()[-max(1, limit):]]
 
 
 def reset_buffer() -> None:
-    """Tests only: empty the ring buffer."""
+    """Tests only: empty both rings and forget the jobs' marks."""
     with _buffer_lock:
         _buffer.clear()
+        _job_buffer.clear()
+        _job_traces.clear()

@@ -185,8 +185,86 @@ def test_staged_windows_hang_under_the_encode_span(job):
         assert s["attrs"]["bytes"] > 0
         assert enc["start"] - 1e-3 <= s["start"] <= \
             enc["start"] + enc["durationMs"] / 1e3 + 1e-3
-    assert all(0 <= s["attrs"]["packSeconds"] <= s["durationMs"] / 1e3
-               + 1e-6 for s in h2d)
+
+
+def _halves_under(spans, parent, stage) -> "dict[str, dict]":
+    got = [s for s in spans if s["parentId"] == parent["spanId"] and
+           s["name"].startswith(stage + ".")]
+    assert sorted(s["name"] for s in got) == [f"{stage}.file",
+                                               f"{stage}.socket"]
+    return {s["name"].split(".")[1]: s for s in got}
+
+
+def _check_halves(halves, nbytes, parent):
+    for s in halves.values():
+        a = s["attrs"]
+        assert set(a) == {"calls", "busySeconds", "cpuSeconds", "bytes"}
+        assert a["bytes"] == nbytes and a["calls"] >= 1
+        assert a["cpuSeconds"] >= 0
+        assert s["traceId"] == parent["traceId"]
+        assert s["role"] == parent["role"]
+        assert parent["start"] - 1e-3 <= s["start"] and \
+            s["start"] + s["durationMs"] / 1e3 <= \
+            parent["start"] + parent["durationMs"] / 1e3 + 1e-3
+    assert halves["socket"]["attrs"]["calls"] == \
+        halves["file"]["attrs"]["calls"]
+    assert halves["socket"]["durationMs"] == halves["file"]["durationMs"]
+    busy = sum(s["attrs"]["busySeconds"] for s in halves.values())
+    assert busy <= halves["socket"]["durationMs"] / 1e3 + 1e-3
+
+
+def test_each_receiver_span_holds_its_copy_halves(job):
+    """`receive.socket` and `receive.file`, once each, under every
+    `POST /admin/receive_file`: the push's bytes, socket against file."""
+    admin, servers, detail, _dirs = job
+    spans = _trace(admin, servers, detail)
+    received = [s for s in spans
+                if s["name"] == "POST /admin/receive_file"]
+    assert len(received) == FILES_PUSHED
+    for s in received:
+        _check_halves(_halves_under(spans, s, "receive"),
+                      s["attrs"]["bytes"], s)
+    assert len([s for s in spans if s["name"].startswith("receive.")]) \
+        == 2 * FILES_PUSHED
+
+
+def test_the_pull_holds_its_copy_halves(job):
+    """One `pull.socket` and one `pull.file` under `ec.pull`, for the
+    `.dat` and the `.idx` together."""
+    admin, servers, detail, _dirs = job
+    spans = _trace(admin, servers, detail)
+    pull = _one(spans, "ec.pull")
+    halves = _halves_under(spans, pull, "pull")
+    _check_halves(halves, pull["attrs"]["bytes"], pull)
+    served = [s for s in spans if s["name"] == "GET /admin/volume_file"]
+    assert halves["socket"]["attrs"]["bytes"] == \
+        sum(s["attrs"]["bytes"] for s in served)
+    # the .dat and the .idx are two loops: at least a read each
+    assert halves["socket"]["attrs"]["calls"] >= 2
+
+
+def test_five_thousand_client_reads_do_not_evict_the_jobs_spans(job):
+    """A volume role's client GETs fill its ring; the job's spans, the
+    receivers' copy halves among them, stand in the jobs' ring."""
+    admin, servers, detail, _dirs = job
+    rid = detail["requestId"]
+    before = {s["spanId"]: s["name"] for s in tracing.spans_for(rid)}
+    assert {"POST /admin/receive_file", "receive.socket", "receive.file",
+            "ec.pull", "pull.socket", "job:erasure_coding"} <= \
+        set(before.values())
+    assert tracing.buffer_size() < 5000
+    fid = operation.submit(servers[0].master, b"a client's needle")
+    vid = int(fid.split(",")[0])
+    (loc,) = [vs for vs in servers if vs.store.find_volume(vid)]
+    for _ in range(5000):
+        assert http_bytes("GET", f"{loc.url}/{fid}")[0] == 200
+    reads = [s for s in tracing.recent_spans(100000)
+             if s["name"] == f"GET /{fid}"]
+    # the client's spans turned their ring over ...
+    assert len(reads) >= tracing.buffer_size() - 50
+    after = {s["spanId"] for s in tracing.spans_for(rid)}
+    # ... and every span of the job is still there
+    assert set(before) <= after
 
 
 def test_five_thousand_status_polls_do_not_evict_the_jobs_spans(job):

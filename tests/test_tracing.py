@@ -4,6 +4,7 @@ codecs, the httpd middleware's server spans + request_seconds
 histogram, and the worker job-boundary adoption."""
 
 import logging
+import os
 import threading
 import time
 
@@ -330,3 +331,106 @@ def test_spans_across_threads_with_captured_context():
         t.join()
     spans = {s["name"]: s for s in tracing.spans_for("threaded")}
     assert spans["child"]["parentId"] == parent.span_id
+
+
+# -- the jobs' ring -----------------------------------------------------------
+
+def _flood(n, trace_id="client"):
+    for i in range(n):
+        with tracing.span(f"GET /c{i}", trace_id=trace_id):
+            pass
+
+
+def test_a_job_span_keeps_its_trace_out_of_reach_of_other_spans(
+        monkeypatch):
+    """Everything recorded on a trace once a `job=True` span opened on
+    it, on this thread or (by its ids) on another, outlives a flood of
+    other spans that turns the ring over; what came before the mark
+    does not."""
+    monkeypatch.setenv("SEAWEEDFS_TPU_TRACE_BUFFER", "64")
+    with tracing.span("before", trace_id="job-1"):
+        pass
+    with tracing.span("POST /admin/receive_file", trace_id="job-1",
+                      job=True) as server:
+        tracing.emit_span_batch([{"name": "receive.socket",
+                                  "start": server.start, "duration": 0.01}])
+        ctx = tracing.current_ids()
+    t = threading.Thread(target=lambda: tracing.emit_span(
+        "stage.h2d", time.time(), 0.001, role=ctx[2], parent=ctx[1],
+        trace_id=ctx[0]))
+    t.start()
+    t.join(timeout=10)
+    assert not t.is_alive()
+    _flood(200)
+    names = [s["name"] for s in tracing.spans_for("job-1")]
+    assert sorted(names) == ["POST /admin/receive_file", "receive.socket",
+                             "stage.h2d"]
+    # the other ring still turns over at its size, the jobs' ring too
+    assert len(tracing.spans_for("client")) == 64
+    for i in range(100):
+        with tracing.span(f"job {i}", trace_id=f"job-{i + 2}", job=True):
+            pass
+    assert len([s for s in tracing.recent_spans(1000)
+                if s["name"].startswith("job ")]) == 64
+
+
+def test_both_rings_read_merged_by_start():
+    with tracing.span("job", trace_id="t-job", job=True):
+        pass
+    with tracing.span("client", trace_id="t-client"):
+        pass
+    tracing.emit_span("early", 1.0, 0.0, trace_id="t-client")
+    tracing.emit_span("earlier", 0.5, 0.0, trace_id="t-job")
+    got = [s["name"] for s in tracing.recent_spans(10)]
+    assert got == ["earlier", "early", "job", "client"]
+    assert [s["name"] for s in tracing.recent_spans(2)] == ["job",
+                                                             "client"]
+
+
+def test_ingested_spans_go_to_the_jobs_ring(monkeypatch):
+    monkeypatch.setenv("SEAWEEDFS_TPU_TRACE_BUFFER", "32")
+    docs = [{"traceId": "ing-job", "spanId": f"w{i}", "name": "ec.push",
+             "start": float(i)} for i in range(20)]
+    assert tracing.ingest(docs) == 20
+    _flood(100)
+    assert len(tracing.spans_for("ing-job")) == 20
+    assert tracing.ingest(docs) == 0       # redelivery, either ring
+
+
+def test_the_marks_are_bounded_and_reset_forgets_them(monkeypatch):
+    monkeypatch.setenv("SEAWEEDFS_TPU_TRACE_BUFFER", "16")
+    for i in range(40):
+        with tracing.span("j", trace_id=f"m-{i}", job=True):
+            pass
+    assert len(tracing._job_traces) == 16
+    assert "m-39" in tracing._job_traces and "m-0" not in tracing._job_traces
+    tracing.reset_buffer()
+    assert not tracing._job_traces and tracing.recent_spans(100) == []
+
+
+def test_many_threads_lose_no_span_to_the_two_rings(monkeypatch):
+    """More threads than cores record on job and client traces at once,
+    the switch interval shortened: every span lands, in its ring."""
+    import sys
+    monkeypatch.setenv("SEAWEEDFS_TPU_TRACE_BUFFER", "100000")
+    threads, each = 4 * (os.cpu_count() or 2), 200
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        def work(k):
+            for i in range(each):
+                with tracing.span("s", trace_id=f"w{k}-{i % 3}",
+                                  job=(i % 3 == 0)):
+                    pass
+
+        ts = [threading.Thread(target=work, args=(k,))
+              for k in range(threads)]
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in ts)
+    finally:
+        sys.setswitchinterval(old)
+    assert len(tracing._job_buffer) == threads * len(range(0, each, 3))
+    assert len(tracing.recent_spans(10 ** 6)) == threads * each
