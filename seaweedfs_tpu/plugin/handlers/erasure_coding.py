@@ -19,7 +19,10 @@ worker/tasks/erasure_coding/ec_task.go:59):
 from __future__ import annotations
 
 import contextvars
+import functools
+import itertools
 import os
+import queue
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
@@ -356,7 +359,8 @@ class EcEncodeHandler(JobHandler):
             # the .dat stays here until the job's end, so a data shard
             # is not written a second time: it is sent as ranges of it
             views = ec_encoder.write_parity_files(
-                base, ctx, progress=_encode_progress(worker, job_id))
+                base, ctx, progress=_encode_progress(
+                    functools.partial(worker.report_progress, job_id)))
             # the result names where it ran, so /maintenance/job and
             # the job's trace can tell a TPU encode from anything else
             for key, val in _device_report(ctx).items():
@@ -439,9 +443,11 @@ class EcEncodeHandler(JobHandler):
 
     # -- batch execute: N volumes in one job ---------------------------
     # (BASELINE config 3; VERDICT r2 Next #9) — each volume through the
-    # single job's staged pipeline, one after another; on a worker with
-    # several devices every window is split over all of them
-    # (ops/staging.encode_shardings)
+    # single job's staged pipeline; on a worker with several devices
+    # every window is split over all of them
+    # (ops/staging.encode_shardings).  The volumes go through pull,
+    # encode and distribute as three stages, in volume order: volume
+    # i+1 is pulled and volume i-1 distributed while volume i encodes
 
     def execute_batch(self, worker, job_id: str, params: dict) -> str:
         from ...ops import staging
@@ -456,68 +462,152 @@ class EcEncodeHandler(JobHandler):
         bases = {vid: os.path.join(worker.work_dir, f"{vid}")
                  for vid in vids}
         n = len(vids)
+        # per-volume progress throughout: a 64-volume batch takes
+        # long enough that a silent job would trip the admin's
+        # stall reaper and double-execute
+        report = _forward_progress(worker, job_id)
+        # the servers the job starts under, asked once its first volume
+        # is readonly; each volume's data-shard views once encoded
+        started: "list[tuple[list[str], float]]" = []
+        views: "dict[int, list[DatShardView]]" = {}
+
+        def pull(i: int, vid: int) -> None:
+            vol_urls[vid] = self._lookup_urls(worker, vid)
+            self._mark_readonly(vol_urls[vid], vid)
+            if not started:
+                started.append(_servers_at_start(worker))
+            self._pull_volume(worker, vid, collection, vol_urls[vid][0],
+                              bases[vid])
+            _sort_index(bases[vid])
+            report(0.05 + 0.25 * (i + 1) / n,
+                   f"pulled volume {vid} ({i + 1}/{n})")
+
+        def encode(i: int, vid: int) -> None:
+            base = bases[vid]
+            views[vid] = ec_encoder.write_parity_files(
+                base, ctx, progress=_encode_progress(
+                    report, 0.3 + 0.3 * i / n, 0.3 / n))
+            # the .vif and the .ecx are pushed with the shards
+            dat_size = os.path.getsize(base + ".dat")
+            version = _read_dat_version(base)
+            ec_encoder.save_ec_volume_info(base, ctx, dat_size, version)
+            if ec_decoder.find_dat_file_size(base, base,
+                                             version) > dat_size:
+                raise RuntimeError(
+                    f"volume {vid}: ecx entries exceed dat size")
+
+        def distribute(i: int, vid: int) -> None:
+            # the .dat stays here until the job's end: a data shard
+            # is sent as ranges of it, as in a single job
+            where = _pushed_files(bases[vid], ctx)
+            where.update((to_ext(v.shard_id), v) for v in views[vid])
+            self._distribute_and_mount(worker, vid, collection, ctx,
+                                       where, started[0])
+            report(0.6 + 0.3 * (i + 1) / n,
+                   f"distributed volume {vid} ({i + 1}/{n})")
+
+        # each stage's calls, busy seconds and window, a span under the
+        # job's: their busy seconds over the job's are the stages at once
+        trace_ctx = tracing.current_ids()
+        stages = {name: ec_encoder._StageTimer(step) for name, step in
+                  (("batch.pull", pull), ("batch.encode", encode),
+                   ("batch.distribute", distribute))}
         try:
-            # per-volume progress throughout: a 64-volume batch takes
-            # long enough that a silent job would trip the admin's
-            # stall reaper and double-execute
-            started = None
-            for i, vid in enumerate(vids):
-                vol_urls[vid] = self._lookup_urls(worker, vid)
-                self._mark_readonly(vol_urls[vid], vid)
-                started = started or _servers_at_start(worker)
-                self._pull_volume(worker, vid, collection,
-                                  vol_urls[vid][0], bases[vid])
-                worker.report_progress(
-                    job_id, 0.05 + 0.25 * (i + 1) / n,
-                    f"pulled volume {vid} ({i + 1}/{n})")
-
-            for vid in vids:
-                _sort_index(bases[vid])
-            # one span for the job's encodes: job_encode_s.batch is a
-            # job's, and `windows` the launches all its volumes made
-            with tracing.span("ec.encode", role="worker") as sp:
-                sp.set("volumes", n)
-                launches = staging.snapshot()["launches"]
-                views = _encode_volumes(
-                    [bases[v] for v in vids], ctx,
-                    progress=_encode_progress(worker, job_id))
-                sp.set("windows", staging.snapshot()["launches"] - launches)
-                sp.set("windowDevices",
-                       _window_devices([bases[v] for v in vids], ctx))
-            for vid in vids:
-                base = bases[vid]
-                dat_size = os.path.getsize(base + ".dat")
-                version = _read_dat_version(base)
-                ec_encoder.save_ec_volume_info(base, ctx, dat_size, version)
-                if ec_decoder.find_dat_file_size(base, base,
-                                                 version) > dat_size:
-                    raise RuntimeError(
-                        f"volume {vid}: ecx entries exceed dat size")
-            worker.report_progress(
-                job_id, 0.6,
-                f"batch-encoded {n} volumes ({_ran_on(ctx)})")
-
-            for i, vid in enumerate(vids):
-                # the .dat stays here until the job's end: a data shard
-                # is sent as ranges of it, as in a single job
-                where = _pushed_files(bases[vid], ctx)
-                where.update((to_ext(v.shard_id), v)
-                             for v in views[bases[vid]])
-                self._distribute_and_mount(
-                    worker, vid, collection, ctx, where, started)
-                worker.report_progress(
-                    job_id, 0.6 + 0.3 * (i + 1) / n,
-                    f"distributed volume {vid} ({i + 1}/{n})")
+            with _VolumePipeline(vids, stages["batch.pull"],
+                                 stages["batch.distribute"]) as pipe:
+                pulled = iter(pipe)
+                first = next(pulled, None)
+                if first is not None:
+                    # one span for the job's encodes, from its first
+                    # volume's to its last's: job_encode_s.batch is a
+                    # job's, and `windows` the launches of its volumes
+                    with tracing.span("ec.encode", role="worker") as sp:
+                        sp.set("volumes", n)
+                        launches = staging.snapshot()["launches"]
+                        for i, vid in itertools.chain([first], pulled):
+                            stages["batch.encode"](i, vid)
+                            pipe.encoded(vid)
+                        sp.set("windows",
+                               staging.snapshot()["launches"] - launches)
+                        sp.set("windowDevices", _window_devices(
+                            [bases[v] for v in views], ctx))
+                if len(views) == n:
+                    report(0.6,
+                           f"batch-encoded {n} volumes ({_ran_on(ctx)})")
         except Exception:
             self._unwind_volumes(worker, collection, ctx, vol_urls)
             raise
         finally:
+            for name, stage in stages.items():
+                stage.emit(name, trace_ctx)
             for base in bases.values():
                 self._cleanup_local(base, ctx)
         for vid in vids:
             self._delete_originals(vol_urls[vid], vid)
         return (f"batch of {n} volumes {ctx} encoded over the "
                 f"mesh ({_ran_on(ctx)}) and distributed")
+
+
+class _VolumePipeline:
+    """The two stages round a batch job's encode.  `pull(i, vid)` runs
+    ahead through the volumes in order on one helper thread;
+    iterating gives the caller, on its own thread, each (i, vid) as its
+    pull ends; `distribute(i, vid)` follows on a second helper thread,
+    each volume as the caller hands it on (`encoded`), in the order
+    handed.  Each helper runs in its own copy of the caller's context,
+    so that its spans hang under the caller's span, as the pushers'
+    do (_push_to_each).  A stage that fails stops the other two before
+    their next volume; leaving the `with` waits for both helpers and
+    raises the first failure, unless the caller's own is on its way."""
+
+    def __init__(self, vids: "list[int]", pull, distribute):
+        self._vids = vids
+        self._stop = threading.Event()
+        self._errors: "list[BaseException]" = []
+        self._pulled: "queue.SimpleQueue" = queue.SimpleQueue()
+        self._encoded: "queue.SimpleQueue" = queue.SimpleQueue()
+        self._pool = ThreadPoolExecutor(2, thread_name_prefix="ec-batch")
+        for step, take, give in (
+                (pull, vids.__getitem__, self._pulled.put),
+                (distribute, lambda _i: self._encoded.get(),
+                 lambda _vid: None)):
+            self._pool.submit(contextvars.copy_context().run,
+                              self._stage, step, take, give)
+
+    def _stage(self, step, take, give) -> None:
+        try:
+            for i in range(len(self._vids)):
+                vid = take(i)
+                if vid is None or self._stop.is_set():
+                    return
+                step(i, vid)
+                give(vid)
+        except BaseException as e:  # noqa: BLE001 — raised by __exit__
+            self._errors.append(e)
+            self._stop.set()
+        finally:
+            give(None)
+
+    def __iter__(self):
+        for i in range(len(self._vids)):
+            vid = self._pulled.get()
+            if vid is None or self._stop.is_set():
+                return
+            yield i, vid
+
+    def encoded(self, vid: int) -> None:
+        self._encoded.put(vid)
+
+    def __enter__(self) -> "_VolumePipeline":
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        if exc is not None:
+            self._stop.set()
+        self._encoded.put(None)
+        self._pool.shutdown(wait=True)
+        if exc is None and self._errors:
+            raise self._errors[0]
 
 
 class EcRebuildHandler(JobHandler):
@@ -663,43 +753,40 @@ def _device_report(ctx: ECContext) -> dict:
     return out
 
 
-def _encode_progress(worker, job_id: str, every: float = 2.0):
-    """write_ec_files progress -> job progress 0.3..0.6, at most one
-    report per `every` seconds.  The worker cannot poll while it
-    executes, so these reports are its liveness across device init,
-    cold compiles and the encode itself (admin WORKER_DEAD_AFTER)."""
+def _encode_progress(report, lo: float = 0.3, width: float = 0.3,
+                     every: float = 2.0):
+    """write_parity_files progress -> `report(progress, message)` from
+    `lo` to `lo + width` of the job, at most one report per `every`
+    seconds.  The worker cannot poll while it executes, so these
+    reports are its liveness across device init, cold compiles and the
+    encode itself (admin WORKER_DEAD_AFTER)."""
     last = 0.0
 
-    def report(done: int, total: int) -> None:
+    def note(done: int, total: int) -> None:
         nonlocal last
         now = time.monotonic()
         if now - last >= every or done >= total:
             last = now
-            worker.report_progress(
-                job_id, 0.3 + 0.3 * done / max(total, 1),
-                f"encoding {done >> 20}/{total >> 20} MiB")
+            report(lo + width * done / max(total, 1),
+                   f"encoding {done >> 20}/{total >> 20} MiB")
+    return note
+
+
+def _forward_progress(worker, job_id: str):
+    """`report(progress, message)` for a job whose stages report from
+    several threads: each report carries the highest progress reported
+    so far and is sent under a lock, so that two cannot pass each
+    other on the way and the admin never sees the job's progress
+    fall."""
+    lock = threading.Lock()
+    high = 0.0
+
+    def report(progress: float, message: str) -> None:
+        nonlocal high
+        with lock:
+            high = max(high, progress)
+            worker.report_progress(job_id, high, message)
     return report
-
-
-def _encode_volumes(bases: "list[str]", ctx: ECContext, progress=None
-                    ) -> "dict[str, list[DatShardView]]":
-    """A batch job's encode: each pulled volume in turn through the
-    single job's pipeline (ec_encoder.write_parity_files: the parity
-    written alone, a window at a time, put split over every device the
-    worker holds where its words divide them).  Returns each base's
-    data-shard views; `progress(done, total)` counts the bytes of all
-    the volumes, so a job's progress never runs backwards."""
-    sizes = [os.path.getsize(base + ".dat") for base in bases]
-    total = sum(sizes)
-    views = {}
-    for i, base in enumerate(bases):
-        before = sum(sizes[:i])
-        note = None if progress is None else (
-            lambda done, _size, before=before: progress(before + done,
-                                                        total))
-        views[base] = ec_encoder.write_parity_files(base, ctx,
-                                                    progress=note)
-    return views
 
 
 def _window_devices(bases: "list[str]", ctx: ECContext) -> int:

@@ -135,8 +135,8 @@ def test_named_sharding_staged_encode_matches_shard_map(mesh,
 
 def test_a_batch_jobs_encode_splits_each_window_over_the_mesh(
         tmp_path, monkeypatch):
-    """A batch job's encode (erasure_coding._encode_volumes, what the
-    tpu_ec worker's execute_batch runs) leaves, for volumes of
+    """A batch job's encode (ec_encoder.write_parity_files a volume, as
+    the tpu_ec worker's execute_batch runs it) leaves, for volumes of
     DIFFERENT sizes (per-volume tails), parity files and data-shard
     views that are byte for byte write_ec_files' fourteen files, and
     puts every window split over all eight devices."""
@@ -171,7 +171,8 @@ def test_a_batch_jobs_encode_splits_each_window_over_the_mesh(
 
     ctx = ECContext(backend="jax")
     launches = staging.snapshot()["launches"]
-    views = ec_handler._encode_volumes(bases, ctx)
+    views = {base: ec_encoder.write_parity_files(base, ctx)
+             for base in bases}
     # 2 + 1 + 1 windows, each put split over the 8 devices
     assert staging.snapshot()["launches"] - launches == len(puts) == 4
     assert all(len(sh.device_set) == 8 and
