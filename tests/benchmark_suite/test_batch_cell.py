@@ -9,6 +9,7 @@ import os
 
 import pytest
 
+from bench_tree import spec, spec_tree  # noqa: F401 — fixtures
 from benchmark import job_trace, run, trace_reduce as tr
 
 CELL = "ec10_4_batch.encode_4chip"
@@ -23,11 +24,6 @@ PLANES = [f"/device:TPU:{i}" for i in range(4)]
 
 def reader(name):
     return run.metric_reader(BENCH, name)
-
-
-@pytest.fixture(scope="module")
-def spec():
-    return run.load_spec()
 
 
 # -- the group ------------------------------------------------------------
@@ -50,9 +46,10 @@ def test_a_group_is_the_configurations_count_under_the_key_named(
         assert run.group_of(cfg, jobs) == group
 
 
-def test_only_the_batch_cells_traffic_names_a_group(spec):
+def test_only_the_batch_cells_traffic_names_a_group(spec_tree):
+    spec = spec_tree.spec
     for w in spec["workloads"]:
-        got = run.cell_files(spec, w["name"])
+        got = run.cell_files(spec, w["name"], spec_tree.root)
         group = run.group_of(got["cfg"], got["traffic"]["jobs"])
         assert group == (4 if w["name"] == CELL else 1)
         rehearsed = dict(got["cfg"], **got["cfg"]["rehearse"])
@@ -302,12 +299,13 @@ BATCH_LAYER = {   # name: (unit, better, source, layer)
 
 
 @pytest.mark.parametrize("name", sorted(BATCH_LAYER))
-def test_the_batch_cells_per_layer_entries(spec, name):
+def test_the_batch_cells_per_layer_entries(spec_tree, name):
+    spec = spec_tree.spec
     m = {e["name"]: e for e in spec["per_layer"]}[name]
     assert (m["unit"], m["better"], m["source"], m["layer"]) == \
         BATCH_LAYER[name]
     assert m["workloads"] == [CELL] and m["moves"] == "ec_GBps"
-    assert callable(reader(name))
+    assert callable(run.metric_reader(spec_tree.bench, name))
     assert m in run.metrics_of(spec, "per_layer", CELL)
     # a layer PERF.md 3 and the other cells' entries already name
     assert m["layer"] in {e["layer"] for e in spec["per_layer"]
@@ -323,8 +321,10 @@ def test_the_batch_cell_has_those_ten_and_the_twin_its_own(spec):
                    for m in run.metrics_of(spec, "per_layer", TWIN))
 
 
-def test_the_configuration_is_the_twins_in_groups_of_four(spec):
-    got, twin = run.cell_files(spec, CELL), run.cell_files(spec, TWIN)
+def test_the_configuration_is_the_twins_in_groups_of_four(spec_tree):
+    spec, root = spec_tree.spec, spec_tree.root
+    got, twin = run.cell_files(spec, CELL, root), run.cell_files(
+        spec, TWIN, root)
     cfg, base = got["cfg"], twin["cfg"]
     for key in ("data_shards", "parity_shards", "needles_per_volume",
                 "needle_bytes", "volume_size_limit_mb", "volume_servers",

@@ -10,6 +10,7 @@ import os
 
 import pytest
 
+from bench_tree import spec_tree  # noqa: F401 — a fixture
 from benchmark import job_trace, run
 
 BENCH = os.path.join(run.REPO, "benchmark")
@@ -181,13 +182,18 @@ def test_the_line_on_the_parents_program_says_there_is_none(capfd):
 
 
 @pytest.mark.parametrize("name", sorted(READERS))
-def test_the_entries_are_the_encode_cells_and_come_last(name):
-    spec = run.load_spec()
+def test_the_entries_are_the_encode_cells_and_come_last(spec_tree, name):
+    """Each entry as PR 40 appended it, and the four together in its
+    order after every entry that was there before them; entries a later
+    PR appends after them (the plus-one tree's) break nothing."""
+    spec = spec_tree.spec
     (m,) = [e for e in spec["per_layer"] if e["name"] == name]
     assert m == {"name": name, "unit": "s", "better": "lower",
                  "source": "program_span", "layer": READERS[name][2],
                  "moves": "ec_GBps", "workloads": [CELL]}
     assert m in run.metrics_of(spec, "per_layer", CELL)
-    assert [e["name"] for e in spec["per_layer"][-4:]] == \
-        ["pull_socket_s", "pull_file_s", "recv_socket_s", "recv_file_s"]
-    assert callable(reader(name))
+    names = [e["name"] for e in spec["per_layer"]]
+    first = names.index("pull_socket_s")
+    # right after PR 35's `push_dat_share`, the last entry before them
+    assert names[first - 1:first + 4] == ["push_dat_share", *READERS]
+    assert callable(run.metric_reader(spec_tree.bench, name))

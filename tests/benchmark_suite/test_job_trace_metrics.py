@@ -9,6 +9,7 @@ import os
 
 import pytest
 
+from bench_tree import spec_tree  # noqa: F401 — a fixture
 from benchmark import job_trace, run, trace_reduce
 
 CELL = "ec10_4_vol1g.encode"
@@ -37,8 +38,9 @@ def reader(name):
 
 # -- the entries ----------------------------------------------------------
 
-def test_the_new_entries_stand_last_each_with_reader_cells_and_moves():
-    spec = run.load_spec()
+def test_the_new_entries_stand_last_each_with_reader_cells_and_moves(
+        spec_tree):
+    spec = spec_tree.spec
     # found by name and in their order: every later PR appends entries
     tail = [m for m in spec["per_layer"] if m["name"] in NEW]
     assert [m["name"] for m in tail] == list(NEW)
@@ -52,9 +54,9 @@ def test_the_new_entries_stand_last_each_with_reader_cells_and_moves():
             (unit, better, source, layer)
         assert m["workloads"] == [CELL] and m["moves"] == "ec_GBps"
         assert m["moves"] in e2e and m["layer"] in layers
-        assert os.path.exists(os.path.join(BENCH, "metrics",
+        assert os.path.exists(os.path.join(spec_tree.bench, "metrics",
                                            m["name"] + ".py"))
-        assert callable(reader(m["name"]))
+        assert callable(run.metric_reader(spec_tree.bench, m["name"]))
 
 
 @pytest.mark.parametrize("name", sorted(NEW))

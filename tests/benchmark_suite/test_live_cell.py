@@ -12,6 +12,7 @@ import sys
 
 import pytest
 
+from bench_tree import spec_tree  # noqa: F401 — a fixture
 from benchmark import faults, job_trace, role_metrics, run
 
 CELL = "ec10_4_live.encode_under_read"
@@ -116,8 +117,9 @@ def named(spec: dict, key: str) -> dict:
     return dict(zip(names, spec[key]))
 
 
-def test_the_cells_entries_are_all_there_and_use_no_name_of_the_guards():
-    spec = run.load_spec()
+def test_the_cells_entries_are_all_there_and_use_no_name_of_the_guards(
+        spec_tree):
+    spec, bench = spec_tree.spec, spec_tree.bench
     per_layer = named(spec, "per_layer")
     e2e = {m["name"] for m in run.metrics_of(spec, "end_to_end", CELL)}
     assert e2e == {"ec_GBps", "setup_s"}
@@ -128,8 +130,8 @@ def test_the_cells_entries_are_all_there_and_use_no_name_of_the_guards():
             (unit, better, source, layer)
         assert CELL in m["workloads"] and TWIN not in m["workloads"]
         assert m["moves"] == "ec_GBps" and m["layer"] in layers
-        assert os.path.exists(os.path.join(BENCH, "metrics", file + ".py"))
-        assert callable(reader(name))
+        assert os.path.exists(os.path.join(bench, "metrics", file + ".py"))
+        assert callable(run.metric_reader(bench, name))
     assert {m["name"] for m in run.metrics_of(spec, "per_layer", CELL)} \
         >= set(NEW)
     cfg = named(spec, "configs")["ec10_4_live"]
@@ -145,11 +147,11 @@ def test_the_cells_entries_are_all_there_and_use_no_name_of_the_guards():
     assert not (set(NEW) | {CELL, "ec10_4_live"}) & guard
 
 
-def test_the_twins_entries_stand_as_they_were():
+def test_the_twins_entries_stand_as_they_were(spec_tree):
     """What the idle cluster's cell reported it still reports, under
     the same names, units and readers; and this cell reports each of
     them under `.live`, but for those left to the twin."""
-    spec = run.load_spec()
+    spec = spec_tree.spec
     per_layer = named(spec, "per_layer")
     for name, (unit, better, source, layer) in OLDER.items():
         m = per_layer[name]
@@ -158,7 +160,8 @@ def test_the_twins_entries_stand_as_they_were():
         assert TWIN in m["workloads"] and CELL not in m["workloads"]
         assert m["moves"] == "ec_GBps"
         stem = name[:-len(".enc")] if name.endswith(".enc") else name
-        assert os.path.exists(os.path.join(BENCH, "metrics", stem + ".py"))
+        assert os.path.exists(os.path.join(spec_tree.bench, "metrics",
+                                           stem + ".py"))
         assert stem + ".live" in NEW or stem in LEFT_TO_THE_TWIN, name
     assert {m["name"] for m in run.metrics_of(spec, "per_layer", TWIN)} \
         >= set(OLDER)
@@ -166,12 +169,12 @@ def test_the_twins_entries_stand_as_they_were():
     assert {TWIN, CELL} <= set(rate["workloads"]) and rate["bound"] == 0.1
 
 
-def test_the_configuration_is_the_twins_with_readers():
+def test_the_configuration_is_the_twins_with_readers(spec_tree):
     """Same cluster, same scheme, same job volumes as `ec10_4_vol1g`:
     the two cells differ in the readers alone."""
-    spec = run.load_spec()
-    got = run.cell_files(spec, CELL)
-    twin = run.cell_files(spec, TWIN)
+    spec = spec_tree.spec
+    got = run.cell_files(spec, CELL, spec_tree.root)
+    twin = run.cell_files(spec, TWIN, spec_tree.root)
     for key in ("data_shards", "parity_shards", "volume_size_limit_mb",
                 "needles_per_volume", "needle_bytes", "volume_servers",
                 "shard_spread", "backend"):

@@ -7,6 +7,7 @@ import os
 
 import pytest
 
+from bench_tree import spec_tree  # noqa: F401 — a fixture
 from benchmark import job_trace, run
 
 BENCH = os.path.join(run.REPO, "benchmark")
@@ -71,13 +72,13 @@ def test_the_recording_of_an_older_program_reads_nought():
     assert reader()(rec["ctx"]) == 0.0
 
 
-def test_the_entry_is_the_idle_clusters_cells():
-    spec = run.load_spec()
+def test_the_entry_is_the_idle_clusters_cells(spec_tree):
+    spec = spec_tree.spec
     (m,) = [e for e in spec["per_layer"] if e["name"] == "push_dat_share"]
     assert m == {"name": "push_dat_share", "unit": "share",
                  "better": "higher", "source": "program_span",
                  "layer": "maintenance plane", "moves": "ec_GBps",
                  "workloads": [CELL]}
     assert m in run.metrics_of(spec, "per_layer", CELL)
-    assert os.path.exists(os.path.join(BENCH, "metrics",
+    assert os.path.exists(os.path.join(spec_tree.bench, "metrics",
                                        "push_dat_share.py"))

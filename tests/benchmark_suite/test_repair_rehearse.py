@@ -15,7 +15,15 @@ import pytest
 from bench_tree import bench_tree  # noqa: F401 — a fixture
 from benchmark import faults, run
 
-CELL = "ec10_4_rebuild.rebuild"
+
+def repair_names(tag=""):
+    """The repair cell's configuration, traffic and cell names, each
+    with `tag` appended where a tree already holds those without."""
+    config, traffic = "ec10_4_rebuild" + tag, "rebuild" + tag
+    return config, traffic, f"{config}.{traffic}"
+
+
+CELL = repair_names()[2]
 SIX = {"jobs_failed", "chain_dry_s", "shard_placement_faults",
        "sources_left", "parity_mismatch_bytes", "needles_wrong"}
 REPAIR = re.compile(r"  job (\w+) vol (\d+): [\d.]+s ok=True locate=[\d.]+ "
@@ -23,26 +31,27 @@ REPAIR = re.compile(r"  job (\w+) vol (\d+): [\d.]+s ok=True locate=[\d.]+ "
                     r"\d+: rebuilt shards \[0, 1\]")
 
 
-def repair_cell(tree, rotation=3):
+def repair_cell(tree, rotation=3, tag=""):
     """The repair cell's files and entries in the tree: the encode
     configuration with two shards lost, a traffic of repairs."""
+    config, traffic, cell = repair_names(tag)
     cfg = tree.read("benchmark/configs/ec10_4_vol1g.json")
-    tree.put("benchmark/configs/ec10_4_rebuild.json",
-             dict(cfg, name="ec10_4_rebuild", lost_shards=[0, 1]))
+    tree.put(f"benchmark/configs/{config}.json",
+             dict(cfg, name=config, lost_shards=[0, 1]))
     jobs = {"kind": "rebuild", "role": "foreground",
             "order": "back_to_back", "rotation": rotation}
-    tree.put("benchmark/traffic/rebuild.json", {
+    tree.put(f"benchmark/traffic/{traffic}.json", {
         "what": "ec.rebuild jobs back to back over a rotation of volumes",
         "reads": None, "jobs": jobs, "verify": {"needles_per_volume": 8},
         "rehearse": {"jobs": jobs}})
     spec = tree.spec
-    spec["configs"].append(dict(spec["configs"][0], name="ec10_4_rebuild",
-                                file="benchmark/configs/ec10_4_rebuild.json"))
-    spec["workloads"].append({"name": CELL, "config": "ec10_4_rebuild",
-                              "traffic": "rebuild", "chips": 1,
+    spec["configs"].append(dict(spec["configs"][0], name=config,
+                                file=f"benchmark/configs/{config}.json"))
+    spec["workloads"].append({"name": cell, "config": config,
+                              "traffic": traffic, "chips": 1,
                               "why": "repairs back to back"})
     ec = next(m for m in spec["end_to_end"] if m["name"] == "ec_GBps")
-    ec["workloads"].append(CELL)
+    ec["workloads"].append(cell)
     tree.write()
     return tree
 

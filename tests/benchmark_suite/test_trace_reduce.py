@@ -6,6 +6,7 @@ import os
 
 import pytest
 
+from bench_tree import spec_tree  # noqa: F401 — a fixture
 from benchmark import run, trace_reduce as tr
 
 PLANE = "/device:TPU:0"
@@ -112,7 +113,7 @@ def test_an_unknown_device_kind_is_an_error(kind):
         tr.roofline_share(1e9, 1.0, kind)
 
 
-def test_a_reader_that_finds_nothing_returns_nothing(rec):
+def test_a_reader_that_finds_nothing_returns_nothing(rec, spec_tree):
     unsynced = {"devices": rec["events"]["devices"], "sync": []}
     assert tr.busy_by_device(unsynced, rec["open"], rec["close"]) == {}
     assert tr.busy_seconds({}) is None
@@ -122,9 +123,8 @@ def test_a_reader_that_finds_nothing_returns_nothing(rec):
            "reads": None, "volume_counters": None,
            "cfg": {"data_shards": 10, "parity_shards": 4},
            "device": {"kind": "TPU v5 lite"}}
-    for m in run.load_spec()["per_layer"]:
-        read = run.metric_reader(os.path.join(run.REPO, "benchmark"),
-                                 m["name"])
+    for m in spec_tree.spec["per_layer"]:
+        read = run.metric_reader(spec_tree.bench, m["name"])
         assert read(ctx) is None, m["name"]
 
 
